@@ -29,6 +29,8 @@ TOL = 1e-6
 
 def _solve_all():
     import jax
+    from repro.runtime.cache import enable_compile_cache
+    enable_compile_cache()
     jax.config.update("jax_enable_x64", True)
     from repro.core import (
         PDHGOptions, encode_exact, lanczos_svd, solve_jit)

@@ -5,9 +5,9 @@ CI runs this right after the smoke stream benchmark:
   1. **Schema validation** — the candidate record must be
      ``bench_stream/v7``: every serving path (dense batched /
      per-instance, crossbar batched / per-instance, the
-     mixed-precision refined crossbar solve, the three sparse
-     backends — default ELL, nnz-bucketed BCOO, ELL + fused
-     multi-iteration megakernel — and the densified sparse baseline,
+     mixed-precision refined crossbar solve, the two sparse
+     backends — default ELL and nnz-bucketed BCOO — and the densified
+     sparse baseline,
      async + sync dispatch, per-pod routed cluster serving, the
      adaptive step rule on the imbalanced acceptance stream, and the
      norm-reuse seeded second pass) present with finite numeric
@@ -73,7 +73,6 @@ REQUIRED_PATHS = (
     "sparse_batched_dense",
     "sparse_ell",
     "sparse_bcoo",
-    "sparse_ell_mega",
     "exact_batched_async",
     "exact_batched_sync",
     "exact_routed",
@@ -94,8 +93,7 @@ REFINEMENT_FIELDS = ("merit_exact", "merit_unrefined", "merit_refined",
                      "digital_mvms", "rounds", "sigma_read", "tol")
 SPARSE_FIELDS = ("density", "host_stack_bytes_dense",
                  "host_stack_bytes_sparse", "host_mem_improvement",
-                 "speedup_warm", "speedup_warm_bcoo",
-                 "speedup_warm_ell_mega")
+                 "speedup_warm", "speedup_warm_bcoo")
 CLUSTER_FIELDS = ("n_pods", "routing", "per_pod", "rerouted_buckets",
                   "max_rel_disagreement_vs_unrouted")
 PER_POD_FIELDS = ("n_buckets", "n_instances", "flops_cost", "flops_share",

@@ -14,6 +14,8 @@ OUT_DIR = os.path.join("experiments", "fig2")
 
 def run(max_iters: int = 30000):
     import jax
+    from repro.runtime.cache import enable_compile_cache
+    enable_compile_cache()
     jax.config.update("jax_enable_x64", True)
     from repro.core import PDHGOptions, solve
     from repro.crossbar import EPIRAM, RTX6000, TAOX_HFOX, Ledger

@@ -22,10 +22,9 @@ steady-state serving cost (the number that matters for throughput).
 Besides the full record, every run emits ``BENCH_stream.json`` at the
 repo root (schema ``bench_stream/v7``: per-path warm/cold seconds +
 device-MVM totals + per-instance ``iterations_to_tol`` distributions
-(median/p90) — including the three sparse backends (``sparse_ell``
-= the default row-blocked ELL pipeline, ``sparse_bcoo`` = nnz-bucketed
-COO, ``sparse_ell_mega`` = ELL with the fused multi-iteration
-megakernel), the async-vs-sync dispatch split, the per-pod ROUTED
+(median/p90) — including the two sparse backends (``sparse_ell``
+= the default ELL pipeline, ``sparse_bcoo`` = nnz-bucketed
+COO), the async-vs-sync dispatch split, the per-pod ROUTED
 cluster path, the ``exact_adaptive`` step-rule path on a scale-
 imbalanced acceptance stream, the ``exact_norm_reuse`` seeded
 second pass and the ``crossbar_refined`` mixed-precision refinement
@@ -166,15 +165,12 @@ def bench_sparse(lps, opts):
     The dense baseline pads every instance into its (B, m_pad, n_pad)
     bucket stack — exactly what serving sparse traffic without the
     sparse path costs; ``host_stack_bytes`` records what each path
-    actually materialized on the host.  Three sparse variants run:
+    actually materialized on the host.  Two sparse variants run:
 
       - ``sparse_*``      the default pipeline (= the ELL backend; the
                           steady-state serving number the guard gates)
       - ``bcoo_*``        the nnz-bucketed BCOO backend (memory-optimal,
                           scatter-bound on CPU)
-      - ``ell_mega_*``    the ELL backend with the fused multi-iteration
-                          megakernel (``check_every`` PDHG steps per
-                          launch, residual check hoisted out)
     """
     import dataclasses
 
@@ -202,9 +198,6 @@ def bench_sparse(lps, opts):
     solver_b = BatchSolver(dataclasses.replace(opts, sparse_kernel="bcoo"))
     bcoo_results = timed(solver_b, lps, "bcoo", timings)
 
-    solver_m = BatchSolver(dataclasses.replace(opts, megakernel=True))
-    mega_results = timed(solver_m, lps, "ell_mega", timings)
-
     gaps = [abs(r.obj - lp.obj_opt) / max(abs(lp.obj_opt), 1e-12)
             for lp, r in zip(lps, results)]
     mem_dense = dense_stats["dense_stack_bytes"]
@@ -215,8 +208,6 @@ def bench_sparse(lps, opts):
         / max(timings["sparse_warm_s"], 1e-12),
         "speedup_warm_bcoo": timings["dense_warm_s"]
         / max(timings["bcoo_warm_s"], 1e-12),
-        "speedup_warm_ell_mega": timings["dense_warm_s"]
-        / max(timings["ell_mega_warm_s"], 1e-12),
         "density": float(np.mean([lp.K.density for lp in lps])),
         "nnz_total": int(sum(lp.K.nnz for lp in lps)),
         "host_stack_bytes_dense": int(mem_dense),
@@ -231,17 +222,12 @@ def bench_sparse(lps, opts):
         "max_rel_disagreement_bcoo_vs_ell": float(max(
             abs(br.obj - r.obj) / max(abs(r.obj), 1e-12)
             for br, r in zip(bcoo_results, results))),
-        "max_rel_disagreement_mega_vs_ell": float(max(
-            abs(mr.obj - r.obj) / max(abs(r.obj), 1e-12)
-            for mr, r in zip(mega_results, results))),
         "mvm_total_sparse": int(sum(r.mvm_calls for r in results)),
         "mvm_total_dense": int(sum(r.mvm_calls for r in dense_results)),
         "mvm_total_bcoo": int(sum(r.mvm_calls for r in bcoo_results)),
-        "mvm_total_ell_mega": int(sum(r.mvm_calls for r in mega_results)),
         "iters_sparse": _iter_stats(results),
         "iters_dense": _iter_stats(dense_results),
         "iters_bcoo": _iter_stats(bcoo_results),
-        "iters_ell_mega": _iter_stats(mega_results),
     }
 
 
@@ -573,6 +559,8 @@ def main(argv=None):
     args = ap.parse_args(argv)
 
     import jax
+    from repro.runtime.cache import enable_compile_cache
+    enable_compile_cache()
     jax.config.update("jax_enable_x64", True)
     from repro.core import PDHGOptions
     from repro.crossbar import DEVICES
@@ -691,11 +679,6 @@ def main(argv=None):
                 "warm_s": record["sparse"]["bcoo_warm_s"],
                 "mvm_total": record["sparse"]["mvm_total_bcoo"],
             },
-            "sparse_ell_mega": {
-                "cold_s": record["sparse"]["ell_mega_cold_s"],
-                "warm_s": record["sparse"]["ell_mega_warm_s"],
-                "mvm_total": record["sparse"]["mvm_total_ell_mega"],
-            },
             "exact_batched_async": {
                 "cold_s": record["async"]["async_cold_s"],
                 "warm_s": record["async"]["async_warm_s"],
@@ -786,8 +769,6 @@ def main(argv=None):
                 record["sparse"]["host_mem_improvement"],
             "speedup_warm": record["sparse"]["speedup_warm"],
             "speedup_warm_bcoo": record["sparse"]["speedup_warm_bcoo"],
-            "speedup_warm_ell_mega":
-                record["sparse"]["speedup_warm_ell_mega"],
         },
     }
     # v6: every path entry carries its iterations-to-tol distribution
@@ -800,7 +781,6 @@ def main(argv=None):
         "sparse_batched_dense": record["sparse"]["iters_dense"],
         "sparse_ell": record["sparse"]["iters_sparse"],
         "sparse_bcoo": record["sparse"]["iters_bcoo"],
-        "sparse_ell_mega": record["sparse"]["iters_ell_mega"],
         "exact_batched_async": record["async"]["iters_async"],
         "exact_batched_sync": record["async"]["iters_sync"],
         "exact_routed": record["cluster"]["iters_routed"],
@@ -834,8 +814,6 @@ def main(argv=None):
           f" ({r['speedup_warm']:.2f}x)"
           f" | bcoo warm {r['bcoo_warm_s']:.3f}s"
           f" ({r['speedup_warm_bcoo']:.2f}x)"
-          f" | ell+mega warm {r['ell_mega_warm_s']:.3f}s"
-          f" ({r['speedup_warm_ell_mega']:.2f}x)"
           f" | host stack {r['host_stack_bytes_dense']}B ->"
           f" {r['host_stack_bytes_sparse']}B"
           f" ({r['host_mem_improvement']:.1f}x smaller)"

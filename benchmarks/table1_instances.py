@@ -12,6 +12,8 @@ import time
 
 def run():
     import jax
+    from repro.runtime.cache import enable_compile_cache
+    enable_compile_cache()
     jax.config.update("jax_enable_x64", True)
     from repro.lp import TABLE1_SIZES, simplex, table1_instance
 

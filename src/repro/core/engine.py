@@ -26,7 +26,7 @@ Two orthogonal backend axes parameterize the engine:
     runs — reference ``jnp`` (one expression per update) or the fused
     Pallas kernels (``kernels.ops.primal_update`` / ``dual_update``, one
     VMEM pass per vector), selected by ``PDHGOptions.kernel`` with
-    interpret-mode auto-detection from ``kernels.ops._interpret_default``.
+    interpret-mode auto-detection from ``kernels.interpret``.
 
 Iteration state is carried in the *pre-extrapolated* form: ``x_bar`` for
 iteration k is computed at the END of iteration k-1 (fused into the
@@ -43,7 +43,7 @@ import jax
 import jax.numpy as jnp
 
 from .residuals import kkt_residuals
-from .symblock import MODE_AX, MODE_ATY, matmul_accel
+from .symblock import MODE_AX, MODE_ATY, MVM_PRECISION, matmul_accel, mv
 
 KERNELS = ("jnp", "pallas")
 SPARSE_KERNELS = ("ell", "bcoo")
@@ -129,13 +129,13 @@ def dense_operator(K_fwd, K_adj, sigma_read: float = 0.0) -> Operator:
     and carry independent programming error."""
 
     def fwd(v, key=None):
-        w = K_fwd @ v
+        w = mv(K_fwd, v)
         if sigma_read > 0.0:
             w = _read_noise(w, key, sigma_read)
         return w
 
     def adj(v, key=None):
-        w = K_adj @ v
+        w = mv(K_adj, v)
         if sigma_read > 0.0:
             w = _read_noise(w, key, sigma_read)
         return w
@@ -175,27 +175,22 @@ def sparse_operator(K_sp, sigma_read: float = 0.0) -> Operator:
 
 
 def sparse_ell_operator(data_f, cols_f, data_a, cols_a,
-                        sigma_read: float = 0.0,
-                        use_pallas: Optional[bool] = None) -> Operator:
-    """Row-blocked ELL backend (``kernels.sparse_mvm``): the forward MVM
-    contracts the ELL form of K (data_f/cols_f, (m, Wf)), the adjoint a
-    separately stored ELL of K^T (data_a/cols_a, (n, Wa)) — both are
-    gather + axis-1 reductions, no scatter anywhere in the iteration.
-    The read-noise hook matches ``dense_operator`` exactly.
-
-    ``use_pallas=None`` auto-selects the vectorized jnp gather path on
-    CPU and the Pallas kernel on accelerators; pass True to force the
-    Pallas kernel (interpreted on CPU) for parity validation."""
+                        sigma_read: float = 0.0) -> Operator:
+    """ELL backend (``kernels.sparse_mvm``): the forward MVM contracts
+    the ELL form of K (data_f/cols_f, (m, Wf)), the adjoint a separately
+    stored ELL of K^T (data_a/cols_a, (n, Wa)) — both are XLA gathers +
+    axis-1 reductions, no scatter anywhere in the iteration.  The
+    read-noise hook matches ``dense_operator`` exactly."""
     from ..kernels import sparse_mvm as _ell  # deferred: keep core light
 
     def fwd(v, key=None):
-        w = _ell.ell_matvec(data_f, cols_f, v, use_pallas=use_pallas)
+        w = _ell.ell_matvec(data_f, cols_f, v)
         if sigma_read > 0.0:
             w = _read_noise(w, key, sigma_read)
         return w
 
     def adj(v, key=None):
-        w = _ell.ell_matvec(data_a, cols_a, v, use_pallas=use_pallas)
+        w = _ell.ell_matvec(data_a, cols_a, v)
         if sigma_read > 0.0:
             w = _read_noise(w, key, sigma_read)
         return w
@@ -222,9 +217,12 @@ def crossbar_operator(g_pos, g_neg, scale, m: int, n: int,
     symmetric block M (Algorithm 2): both MVM modes are zero-padded reads
     of the same (R, C) conductance array, exactly the paper's access
     pattern.  Read noise is a per-row multiplicative sample folded into
-    the kernel's output gain."""
+    the kernel's output gain.  A compiled kernel refuses f64 conductances
+    here, at mount time."""
     from ..kernels import ops  # deferred: keep core import-light
+    from ..kernels.interpret import check_kernel_dtype
 
+    check_kernel_dtype(g_pos.dtype, interpret, "the crossbar MVM kernel")
     R, C = g_pos.shape
 
     def _mvm(v_full, key):
@@ -260,7 +258,7 @@ def sharded_operator(K_loc, row_axis, col_axis) -> Operator:
         w = jax.lax.dot_general(
             K_loc, v.astype(K_loc.dtype),
             dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=acc_dt,
+            precision=MVM_PRECISION, preferred_element_type=acc_dt,
         )
         return jax.lax.psum(w.astype(v.dtype), col_axis)
 
@@ -268,7 +266,7 @@ def sharded_operator(K_loc, row_axis, col_axis) -> Operator:
         w = jax.lax.dot_general(
             K_loc, v.astype(K_loc.dtype),
             dimension_numbers=(((0,), (0,)), ((), ())),
-            preferred_element_type=acc_dt,
+            precision=MVM_PRECISION, preferred_element_type=acc_dt,
         )
         return jax.lax.psum(w.astype(v.dtype), row_axis)
 
@@ -296,24 +294,6 @@ def make_fused_dense(K_fwd, K_adj, b, c, lb, ub, T, Sigma, gamma,
     return fuse
 
 
-def make_fused_ell(data_f, cols_f, data_a, cols_a, b, c, lb, ub, T,
-                   Sigma, gamma, interpret=None) -> Callable:
-    """``Operator.fuse`` hook for the ELL backend (same contract as
-    ``make_fused_dense``, operands in ELL form)."""
-    from ..kernels import pdhg_megakernel as _mega  # deferred
-
-    def fuse(state: PDHGState, n_steps: int):
-        (x, x_prev, x_bar, y, tau, sigma, xs, ys) = _mega.fused_ell_steps(
-            data_f, cols_f, data_a, cols_a, b, c, lb, ub, T, Sigma,
-            state.x, state.x_prev, state.x_bar, state.y,
-            state.tau, state.sigma,
-            n_steps=int(n_steps), gamma=float(gamma), interpret=interpret)
-        return (PDHGState(x=x, x_prev=x_prev, x_bar=x_bar, y=y,
-                          tau=tau, sigma=sigma), xs, ys)
-
-    return fuse
-
-
 # ------------------------------------------------------ update backends ---
 
 def _primal_jnp(x, kty, c, T, lb, ub, tau, theta):
@@ -328,15 +308,19 @@ def _dual_jnp(y, kxbar, b, Sigma, sigma):
 JNP_UPDATES = Updates(_primal_jnp, _dual_jnp, "jnp")
 
 
-def make_updates(kernel: str = "jnp", interpret=None) -> Updates:
+def make_updates(kernel: str, dtype, interpret=None) -> Updates:
     """Update-backend factory keyed by ``PDHGOptions.kernel``.
 
-    ``interpret=None`` auto-detects per ``kernels.ops._interpret_default``
-    (interpreted on CPU, compiled Mosaic on real TPU)."""
+    ``interpret=None`` auto-detects per ``kernels.interpret``
+    (interpreted on CPU, compiled Mosaic on real TPU).  ``dtype`` is the
+    solve dtype: compiled kernels refuse a 64-bit one here."""
     if kernel == "jnp":
         return JNP_UPDATES
     if kernel == "pallas":
         from ..kernels import ops  # deferred: keep core import-light
+        from ..kernels.interpret import check_kernel_dtype
+
+        check_kernel_dtype(dtype, interpret, "the fused update kernel")
 
         def primal(x, kty, c, T, lb, ub, tau, theta):
             return ops.primal_update(x, kty, c, T, lb, ub, tau, theta,
@@ -677,7 +661,7 @@ def solve_core(K_fwd, K_adj, b, c, lb, ub, T, Sigma, rho, key, static, *,
     and ``kernel`` selects the update backend (jnp | pallas).
 
     ``operator`` swaps the MVM backend (e.g. the differential-pair
-    crossbar kernel or the row-blocked ELL operator) in place of the
+    crossbar kernel or the ELL operator) in place of the
     default dense one — ``K_fwd``/``K_adj`` may then be ``None``; the
     step-size initialization, init draws, and option plumbing stay HERE
     either way (problem dims come from ``b``/``c``).  ``K_fwd`` may be
@@ -690,7 +674,7 @@ def solve_core(K_fwd, K_adj, b, c, lb, ub, T, Sigma, rho, key, static, *,
     gate, default True), ``sparse_kernel`` (executable-cache
     discriminator for the sparse backend — the stacking layer picks the
     operator), ``megakernel`` (fuse each check window into one launch;
-    auto-mounted on the dense backend at ``sigma_read == 0``),
+    mounted on the noiseless dense backend and refused on every other),
     ``step_rule`` (one of ``STEP_RULES``, default ``"fixed"`` — see
     ``pdhg_loop``).  Entries 13/14 (``refine_rounds``/``refine_tol``)
     belong to the digital refinement shell around this core
@@ -722,12 +706,17 @@ def solve_core(K_fwd, K_adj, b, c, lb, ub, T, Sigma, rho, key, static, *,
             operator = sparse_operator(K_fwd, sigma_read)
         else:
             operator = dense_operator(K_fwd, K_adj, sigma_read)
-    if (megakernel and operator.fuse is None and sigma_read == 0.0
-            and operator.name == "dense"):
+    if megakernel and operator.fuse is None:
+        if operator.name != "dense" or sigma_read > 0.0:
+            raise ValueError(
+                f"megakernel=True fuses the noiseless dense operator only; "
+                f"this solve mounts the {operator.name!r} operator"
+                f"{' with read noise' if sigma_read > 0.0 else ''} — set "
+                "PDHGOptions.megakernel=False")
         operator = operator._replace(fuse=make_fused_dense(
             K_fwd, K_adj, b, c, lb, ub, T, Sigma, gamma))
     return pdhg_loop(
-        operator, make_updates(kernel),
+        operator, make_updates(kernel, b.dtype),
         b, c, lb, ub, T, Sigma, x0, y0, tau0, sigma0, key,
         max_iters=max_iters, tol=tol, gamma=gamma, check_every=check_every,
         restart_beta=restart_beta, restart=restart,

@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .symblock import MODE_FULL, Accel, matmul_accel
+from .symblock import MODE_FULL, Accel, dot, matmul_accel, mv
 
 # norm estimators selectable by ``PDHGOptions.norm_backend`` on the
 # jitted prep paths; both cost ONE symmetric-block MVM per iteration, so
@@ -73,11 +73,11 @@ def lanczos_svd(
         else:
             w = matmul_accel(accel, v, MODE_FULL)
         w = w - beta * v_prev
-        alpha = float(jnp.vdot(v, w))
+        alpha = float(dot(v, w))
         w = w - alpha * v
         if reorthogonalize:
             for q in basis:
-                w = w - jnp.vdot(q, w) * q
+                w = w - dot(q, w) * q
         beta_next = float(jnp.linalg.norm(w))
         alphas.append(alpha)
         betas.append(beta_next)
@@ -134,7 +134,7 @@ def lanczos_svd_jit_mv(matvec, dim: int, dtype, k_max: int = 32,
         v_prev, v, beta = carry
         w = matvec(v)
         w = w - beta * v_prev
-        alpha = jnp.vdot(v, w)
+        alpha = dot(v, w)
         w = w - alpha * v
         beta_next = jnp.linalg.norm(w)
         v_next = jnp.where(beta_next > 1e-30, w / beta_next, w)
@@ -155,7 +155,7 @@ def lanczos_svd_jit(M: jnp.ndarray, k_max: int = 32, key=None) -> jnp.ndarray:
     No early exit (fixed cost) — used inside jitted solver pipelines and
     the distributed dry-run.
     """
-    return lanczos_svd_jit_mv(lambda v: M @ v, M.shape[0], M.dtype,
+    return lanczos_svd_jit_mv(lambda v: mv(M, v), M.shape[0], M.dtype,
                               k_max=k_max, key=key)
 
 
@@ -201,7 +201,7 @@ def power_iteration(
     v = v / jnp.linalg.norm(v)
 
     def body(v, _):
-        w = K.T @ (K @ v)
+        w = mv(K.T, mv(K, v))
         nw = jnp.linalg.norm(w)
         return w / nw, nw
 

@@ -46,6 +46,7 @@ from .symblock import (
     build_sym_block,
     encode_exact,
     encode_noisy,
+    mv,
     scaled_accel,
 )
 
@@ -71,7 +72,7 @@ class PDHGOptions:
     norm_override: Optional[float] = None  # skip Lanczos (reuse across runs)
     kernel: str = "jnp"            # update backend: "jnp" | "pallas" (fused)
     sparse_kernel: str = "ell"     # sparse operator backend: "ell"
-    #                                (row-blocked ELL gather kernel; the
+    #                                (ELL XLA gather; the
     #                                wall-clock path) | "bcoo" (COO/BCOO
     #                                scatter; the memory-optimal path)
     megakernel: bool = False       # fuse each check_every window into ONE
@@ -234,7 +235,7 @@ def solve(
     # The per-iteration math is the engine's — this driver only owns the
     # Python-level control flow (history, callbacks, infeasibility exit).
     op = engine.accel_operator(accel)
-    upd = engine.make_updates(opts.kernel)
+    upd = engine.make_updates(opts.kernel, scaled.K.dtype)
     state = engine.init_state(x, y, tau, sigma, opts.gamma)
     adapt_anchor = (state.x, state.y)   # restart anchor for omega updates
     del x, y, tau, sigma
@@ -480,7 +481,7 @@ def solve_jit(
         Keff = jnp.sqrt(Sigma)[:, None] * Kf * jnp.sqrt(T)[None, :]
         M = build_sym_block(Keff)
         if opts.norm_backend == "power":
-            rho = power_iteration_mv(lambda v: M @ v, M.shape[0], M.dtype,
+            rho = power_iteration_mv(lambda v: mv(M, v), M.shape[0], M.dtype,
                                      iters=opts.lanczos_iters)
         else:
             rho = lanczos_svd_jit(M, k_max=opts.lanczos_iters)
@@ -500,7 +501,7 @@ def solve_jit(
     x_orig = np.asarray(scaled.unscale_x(x))
     y_orig = np.asarray(scaled.unscale_y(y))
     res = kkt_residuals(
-        x, x, y, scaled.c, scaled.b, scaled.K @ x, scaled.K.T @ y,
+        x, x, y, scaled.c, scaled.b, mv(scaled.K, x), mv(scaled.K.T, y),
         lb=scaled.lb, ub=scaled.ub,
     )
     it_i = int(it)

@@ -19,6 +19,8 @@ import dataclasses
 
 import jax.numpy as jnp
 
+from .symblock import dot
+
 
 @dataclasses.dataclass
 class KKTResiduals:
@@ -79,14 +81,14 @@ def kkt_residuals(
     r_iter = jnp.linalg.norm(jnp.maximum(x_prev - x, 0.0)) / (
         1.0 + jnp.linalg.norm(x)
     )
-    pobj = jnp.vdot(c, x)
+    pobj = dot(c, x)
     # Bounds-aware dual objective: b^T y + lb^T lam_lo - ub^T lam_hi.
     # (The paper prints |c^T x - K^T y|; with x >= 0 / no finite ub this is
     # the classical b^T y gap — the general form is required for the box-
     # bounded Table-1 relaxations.)
-    dobj = jnp.vdot(b, y)
+    dobj = dot(b, y)
     if lb_fin is not None:
-        dobj = dobj + jnp.vdot(lb_fin, lam_lo) - jnp.vdot(ub_fin, lam_hi)
+        dobj = dobj + dot(lb_fin, lam_lo) - dot(ub_fin, lam_hi)
     r_gap = jnp.abs(pobj - dobj) / (1.0 + jnp.abs(pobj) + jnp.abs(dobj))
     return KKTResiduals(r_pri=r_pri, r_dual=r_dual, r_iter=r_iter, r_gap=r_gap)
 

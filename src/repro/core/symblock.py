@@ -27,6 +27,22 @@ MODE_FULL = "full"
 MODE_AX = "A@x"
 MODE_ATY = "AT@y"
 
+#: Precision of every solver MVM and dot, the Pallas kernels' included.
+#: A TPU multiplies f32 operands in one bf16 pass by default, which
+#: floors an f32 solve's KKT merit near 1e-3; HIGHEST multiplies at the
+#: operand dtype (CPUs and f64 always do).
+MVM_PRECISION = jax.lax.Precision.HIGHEST
+
+
+def mv(A, v) -> jnp.ndarray:
+    """``A @ v`` at ``MVM_PRECISION``."""
+    return jnp.matmul(A, v, precision=MVM_PRECISION)
+
+
+def dot(u, v) -> jnp.ndarray:
+    """``u . v`` at ``MVM_PRECISION``."""
+    return jnp.vdot(u, v, precision=MVM_PRECISION)
+
 
 def build_sym_block(K) -> jnp.ndarray:
     """Algorithm 1 (BUILDSYMBLOCK), host step: M from K (m x n)."""
@@ -65,7 +81,7 @@ def encode_exact(K, dtype=None) -> Accel:
     M = build_sym_block(K)
 
     def mvm(v, key=None):
-        return M @ v
+        return mv(M, v)
 
     return Accel(mvm_full=mvm, m=m, n=n, name="exact")
 
@@ -81,7 +97,7 @@ def encode_noisy(K, noise_apply, dtype=None) -> Accel:
     M = build_sym_block(K)
 
     def mvm(v, key=None):
-        w = M @ v
+        w = mv(M, v)
         if key is None:
             return w
         return noise_apply(key, w)

@@ -28,7 +28,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..core.symblock import Accel, build_sym_block
+from ..core.symblock import Accel, build_sym_block, mv
 from .device import DeviceModel, EPIRAM
 from .encode import EncodedMatrix, encode_matrix
 from .energy import Ledger
@@ -84,7 +84,7 @@ class CrossbarArray:
             noise = dev.sigma_read * jax.random.normal(key, (R,), vp.dtype)
             w = kops.crossbar_mvm(enc.g_pos, enc.g_neg, vp, enc.scale, noise)
         else:
-            w = (enc.g_pos - enc.g_neg) @ vp * enc.scale
+            w = mv(enc.g_pos - enc.g_neg, vp) * enc.scale
             w = w * (1.0 + dev.sigma_read
                      * jax.random.normal(key, w.shape, w.dtype))
         # ledger: all tiles fire in parallel -> one read latency quantum;

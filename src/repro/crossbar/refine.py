@@ -54,7 +54,7 @@ from ..core import engine
 from ..core import pdhg as pdhg_mod
 from ..core.lanczos import lanczos_svd_jit, power_iteration_mv
 from ..core.residuals import kkt_residuals
-from ..core.symblock import build_sym_block
+from ..core.symblock import build_sym_block, mv
 from ..lp.problem import StandardLP
 from .device import DeviceModel, EPIRAM
 from .encode import encode_matrix
@@ -99,8 +99,8 @@ def refined_core(K_dig_fwd, K_dig_adj, K_fwd, K_adj, b, c, lb, ub, T,
         return x, y, jnp.reshape(it0, (1,)), merit0
 
     its = [it0]
-    Kx = K_dig_fwd @ x
-    KTy = K_dig_adj @ y
+    Kx = mv(K_dig_fwd, x)
+    KTy = mv(K_dig_adj, y)
     merit = digital_merit(x, y, b, c, lb, ub, Kx, KTy)
     for _ in range(rounds):
         key, kr = jax.random.split(key)
@@ -118,8 +118,8 @@ def refined_core(K_dig_fwd, K_dig_adj, K_fwd, K_adj, b, c, lb, ub, T,
         its.append(it_r)
         x_c = jnp.clip(x + s * dx, lb, ub)
         y_c = y + s * dy
-        Kx_c = K_dig_fwd @ x_c
-        KTy_c = K_dig_adj @ y_c
+        Kx_c = mv(K_dig_fwd, x_c)
+        KTy_c = mv(K_dig_adj, y_c)
         merit_c = digital_merit(x_c, y_c, b, c, lb, ub, Kx_c, KTy_c)
         # safeguarded adoption: only keep an exact improvement, and stop
         # moving once the target tolerance is met
@@ -173,7 +173,7 @@ def solve_crossbar_refined(
         Keff = (jnp.sqrt(Sigma)[:, None] * K_fwd * jnp.sqrt(T)[None, :])
         Msym = build_sym_block(Keff)
         if opts.norm_backend == "power":
-            est = power_iteration_mv(lambda v: Msym @ v, Msym.shape[0],
+            est = power_iteration_mv(lambda v: mv(Msym, v), Msym.shape[0],
                                      Msym.dtype, iters=opts.lanczos_iters)
         else:
             est = lanczos_svd_jit(Msym, k_max=opts.lanczos_iters)
@@ -197,7 +197,7 @@ def solve_crossbar_refined(
     x_orig = np.asarray(scaled.unscale_x(x))
     y_orig = np.asarray(scaled.unscale_y(y))
     res = kkt_residuals(
-        x, x, y, scaled.c, scaled.b, scaled.K @ x, scaled.K.T @ y,
+        x, x, y, scaled.c, scaled.b, mv(scaled.K, x), mv(scaled.K.T, y),
         lb=scaled.lb, ub=scaled.ub)
     merit_f = float(merit)
     if not np.isfinite(merit_f):

@@ -42,8 +42,8 @@ from ..core import engine
 from ..core import pdhg as pdhg_mod
 from ..core.pdhg import PDHGOptions, PDHGResult
 from ..core.residuals import kkt_residuals
+from ..core.symblock import build_sym_block, dot, mv
 from ..lp.problem import StandardLP
-from ..runtime import compat
 from .sharding import axis_size, col_axes, pad_to_multiple, row_axes
 
 
@@ -72,10 +72,10 @@ def _dist_kkt_max(x, x_prev, y, c, b, Kx, KTy, lb, ub, Rax, Cax):
     r_dual = jnp.sqrt(sum_c(_l2sq(reduced - lam))) / (1.0 + nrm_c)
     r_iter = jnp.sqrt(sum_c(_l2sq(jnp.maximum(x_prev - x, 0.0)))) / (
         1.0 + jnp.sqrt(sum_c(_l2sq(x))))
-    pobj = sum_c(jnp.vdot(c, x))
-    dobj = sum_r(jnp.vdot(b, y)) + sum_c(
-        jnp.vdot(jnp.where(has_lb, lb, 0.0), lam_lo)
-        - jnp.vdot(jnp.where(has_ub, ub, 0.0), lam_hi))
+    pobj = sum_c(dot(c, x))
+    dobj = sum_r(dot(b, y)) + sum_c(
+        dot(jnp.where(has_lb, lb, 0.0), lam_lo)
+        - dot(jnp.where(has_ub, ub, 0.0), lam_hi))
     r_gap = jnp.abs(pobj - dobj) / (1.0 + jnp.abs(pobj) + jnp.abs(dobj))
     return jnp.maximum(jnp.maximum(r_pri, r_dual), jnp.maximum(r_iter, r_gap))
 
@@ -155,7 +155,7 @@ def make_dist_step(mesh: Mesh, n_inner: int = 1, gamma: float = 0.0):
         return state.x, state.x_bar, state.y, state.tau, state.sigma
 
     vec_r, vec_c = P(Rax), P(Cax)
-    return compat.shard_map(
+    return jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(P(Rax, Cax), vec_r, vec_c, vec_c, vec_c, vec_c, vec_r,
@@ -178,14 +178,13 @@ def solve_dist(
     else:
         from ..core.lanczos import (
             NORM_BACKENDS, lanczos_svd_jit, power_iteration_mv)
-        from ..core.symblock import build_sym_block
         if opts.norm_backend not in NORM_BACKENDS:
             raise ValueError(f"unknown norm_backend {opts.norm_backend!r}; "
                              f"expected one of {NORM_BACKENDS}")
         Keff = jnp.sqrt(Sigma)[:, None] * scaled.K * jnp.sqrt(T)[None, :]
         M = build_sym_block(Keff)
         if opts.norm_backend == "power":
-            rho = float(power_iteration_mv(lambda v: M @ v, M.shape[0],
+            rho = float(power_iteration_mv(lambda v: mv(M, v), M.shape[0],
                                            M.dtype,
                                            iters=opts.lanczos_iters))
         else:
@@ -238,7 +237,7 @@ def solve_dist(
         )
 
     vec_r, vec_c = P(Rax), P(Cax)
-    solve_fn = jax.jit(compat.shard_map(
+    solve_fn = jax.jit(jax.shard_map(
         local_solve,
         mesh=mesh,
         in_specs=(P(Rax, Cax), vec_r, vec_c, vec_c, vec_c, vec_c, vec_r),

@@ -2,8 +2,10 @@
 #   crossbar_mvm  — the analog MVM (the operation the paper accelerates),
 #                   as a tiled differential-pair MXU matmul.
 #   pdhg_update   — fused primal/dual vector updates (single VMEM pass).
-# Validated in interpret=True mode on CPU against ref.py oracles; every
-# entry point auto-detects interpret mode through kernels.interpret.
+# Validated in interpret=True mode on CPU against ref.py oracles and
+# compiled for a described v5e in tests/test_tpu_compile.py; every entry
+# point auto-detects interpret mode through kernels.interpret, and
+# compiled kernels take f32 only (kernels.interpret.check_kernel_dtype).
 # Solvers reach these through core.engine's operator/update backends
 # (PDHGOptions.kernel = "pallas").
 from . import crossbar_mvm, interpret, ops, pdhg_update, ref

@@ -31,7 +31,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ..core.symblock import MVM_PRECISION
 from .interpret import resolve_interpret
+from .spec import block_spec
 
 # 128 = MXU tile edge; each block packs a 2x2 grid of 64x64 crossbars.
 TILE_R = 128
@@ -47,10 +49,12 @@ def _mvm_kernel(gp_ref, gn_ref, v_ref, gain_ref, out_ref, *, n_col_tiles):
 
     g = gp_ref[...] - gn_ref[...]                     # (TR, TC) in VMEM
     # accumulate at least f32 (MXU native), never BELOW the tile dtype —
-    # x64 interpret-mode validation must not round through f32
+    # x64 interpret-mode validation must not round through f32 — and
+    # multiply at the tile dtype, not one bf16 pass
     part = jax.lax.dot_general(
         g, v_ref[...],
         dimension_numbers=(((1,), (0,)), ((), ())),
+        precision=MVM_PRECISION,
         preferred_element_type=jnp.promote_types(g.dtype, jnp.float32),
     )                                                  # (TR, 1)
     out_ref[...] += part.astype(out_ref.dtype)
@@ -77,12 +81,12 @@ def crossbar_mvm_padded(g_pos, g_neg, v, gain, *,
         kernel,
         grid=(n_row_tiles, n_col_tiles),
         in_specs=[
-            pl.BlockSpec((TILE_R, TILE_C), lambda i, j: (i, j)),   # G+
-            pl.BlockSpec((TILE_R, TILE_C), lambda i, j: (i, j)),   # G-
-            pl.BlockSpec((TILE_C, 1), lambda i, j: (j, 0)),        # v
-            pl.BlockSpec((TILE_R, 1), lambda i, j: (i, 0)),        # gain
+            block_spec((TILE_R, TILE_C), lambda i, j: (i, j)),   # G+
+            block_spec((TILE_R, TILE_C), lambda i, j: (i, j)),   # G-
+            block_spec((TILE_C, 1), lambda i, j: (j, 0)),        # v
+            block_spec((TILE_R, 1), lambda i, j: (i, 0)),        # gain
         ],
-        out_specs=pl.BlockSpec((TILE_R, 1), lambda i, j: (i, 0)),
+        out_specs=block_spec((TILE_R, 1), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((R, 1), g_pos.dtype),
         interpret=resolve_interpret(interpret),
     )(g_pos, g_neg, v, gain)

@@ -9,6 +9,7 @@ silently runs interpreted.
 from __future__ import annotations
 
 import jax
+import jax.numpy as jnp
 
 
 def interpret_default() -> bool:
@@ -18,3 +19,18 @@ def interpret_default() -> bool:
 def resolve_interpret(interpret) -> bool:
     """``None`` -> backend auto-detection; anything else passes through."""
     return interpret_default() if interpret is None else bool(interpret)
+
+
+def check_kernel_dtype(dtype, interpret, kernel: str) -> None:
+    """Refuse 64-bit operands for a compiled kernel.
+
+    Mosaic rejects f64 inside a TPU kernel (``X64 element types`` in
+    ``tpu_custom_call``); interpreted kernels take any dtype.  The solve
+    dtype is ``PDHGOptions.dtype``, so that is the option to change.
+    """
+    dt = jnp.dtype(dtype)
+    if dt.itemsize > 4 and not resolve_interpret(interpret):
+        raise ValueError(
+            f"{kernel} is a compiled Pallas kernel and TPUs take no "
+            f"{dt.name} inside one; set PDHGOptions.dtype=np.float32 or "
+            "use kernel='jnp'")

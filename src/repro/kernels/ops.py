@@ -1,8 +1,8 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-Handle padding to tile boundaries, column-vector reshapes, and the
-interpret-mode switch (interpret=True on CPU — the container's validation
-mode; compiled Mosaic on real TPU).
+Handle padding to tile boundaries and column-vector reshapes.
+``interpret=None`` passes through to the kernels, which resolve it in
+``kernels.interpret`` (interpreted on CPU, compiled Mosaic on TPU).
 """
 from __future__ import annotations
 
@@ -11,7 +11,6 @@ import jax.numpy as jnp
 
 from . import crossbar_mvm as _xbar
 from . import pdhg_update as _upd
-from .interpret import interpret_default as _interpret_default
 
 
 def _pad_to(a, mult, axis):
@@ -29,8 +28,6 @@ def crossbar_mvm(g_pos, g_neg, v, scale, noise, interpret=None):
 
     noise: per-row multiplicative read-noise sample, shape (R,).
     """
-    if interpret is None:
-        interpret = _interpret_default()
     R, C = g_pos.shape
     gp = _pad_to(_pad_to(g_pos, _xbar.TILE_R, 0), _xbar.TILE_C, 1)
     gn = _pad_to(_pad_to(g_neg, _xbar.TILE_R, 0), _xbar.TILE_C, 1)
@@ -42,8 +39,6 @@ def crossbar_mvm(g_pos, g_neg, v, scale, noise, interpret=None):
 
 
 def primal_update(x, kty, c, T, lb, ub, tau, theta, interpret=None):
-    if interpret is None:
-        interpret = _interpret_default()
     n = x.shape[0]
     cols = [_pad_to(a.reshape(-1, 1), _upd.BLOCK, 0)
             for a in (x, kty, c, T, lb, ub)]
@@ -56,8 +51,6 @@ def primal_update(x, kty, c, T, lb, ub, tau, theta, interpret=None):
 
 
 def dual_update(y, kxbar, b, Sigma, sigma, interpret=None):
-    if interpret is None:
-        interpret = _interpret_default()
     m = y.shape[0]
     cols = [_pad_to(a.reshape(-1, 1), _upd.BLOCK, 0)
             for a in (y, kxbar, b, Sigma)]

@@ -13,10 +13,11 @@ check points on the same iterates.
 
 Noiseless only (``sigma_read == 0``): per-MVM read-noise keys can't be
 split inside the kernel, and the engine only mounts the fused path when
-no noise is configured.  Two operand layouts share one step loop:
-
-    dense — K (m, n) and K^T (n, m) as VMEM blocks, MXU matmuls
-    ell   — forward + adjoint ELL (data, cols) pairs, row gathers
+no noise is configured.  Dense operands only: K (m, n) and K^T (n, m)
+are whole VMEM blocks feeding MXU matmuls.  With no grid, everything
+the window touches lives in VMEM at once, so ``check_vmem`` refuses any
+operand set past ``VMEM_BUDGET`` before Mosaic is asked to compile it
+(interpreted kernels have no VMEM and take any size).
 
 Vectors travel as (d, 1) columns and scalars as (1, 1) blocks, the
 kernel-package convention.  tau/sigma enter as (1, 1) runtime operands
@@ -41,7 +42,36 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .interpret import resolve_interpret
+from ..core.symblock import MVM_PRECISION
+from .interpret import check_kernel_dtype, resolve_interpret
+
+#: VMEM the fused window may plan for.  Calibrated by compiling the
+#: kernel for v5e at f32: every shape whose ``vmem_estimate`` is within
+#: this compiled (512x1024, 1024x512), while 768x1024, 1024x1024,
+#: 512x2048 and 128x4096 ran out of VMEM.
+VMEM_BUDGET = 16 << 20
+#: Each (d, 1) vector column is lane-padded to (d, 128) in VMEM, and the
+#: fused loop keeps about this many of them live per vector length.
+_LIVE_COLUMNS = 16
+_LANES = 128
+
+
+def vmem_estimate(m: int, n: int, dtype) -> int:
+    """Bytes of VMEM the fused dense window needs for an (m, n) K: both
+    operator blocks plus the lane-padded vector columns."""
+    isz = jnp.dtype(dtype).itemsize
+    return 2 * m * n * isz + _LIVE_COLUMNS * _LANES * (m + n) * isz
+
+
+def check_vmem(m: int, n: int, dtype) -> None:
+    """Refuse an operand set the fused window cannot hold in VMEM."""
+    need = vmem_estimate(m, n, dtype)
+    if need > VMEM_BUDGET:
+        raise ValueError(
+            f"megakernel=True cannot fuse a {m}x{n} {jnp.dtype(dtype).name} "
+            f"bucket: its window needs ~{need / 2**20:.1f} MiB of VMEM, "
+            f"over the kernel's {VMEM_BUDGET / 2**20:.0f} MiB budget; "
+            "set PDHGOptions.megakernel=False for buckets this large")
 
 
 def _run_steps(fwd, adj, b, c, lb, ub, T, Sigma, gamma, n_steps,
@@ -81,31 +111,11 @@ def _dense_kernel(K_ref, Ka_ref, b_ref, c_ref, lb_ref, ub_ref, T_ref,
     def mv(M, v):
         return jax.lax.dot_general(
             M, v, dimension_numbers=(((1,), (0,)), ((), ())),
+            precision=MVM_PRECISION,
             preferred_element_type=acc_dt).astype(v.dtype)
 
     results = _run_steps(
         lambda v: mv(K, v), lambda v: mv(Ka, v),
-        b_ref[...], c_ref[...], lb_ref[...], ub_ref[...],
-        T_ref[...], S_ref[...], gamma, n_steps,
-        x_ref[...], xp_ref[...], xb_ref[...], y_ref[...],
-        tau_ref[...], sig_ref[...])
-    _write(outs, results)
-
-
-def _ell_kernel(df_ref, cf_ref, da_ref, ca_ref, b_ref, c_ref, lb_ref,
-                ub_ref, T_ref, S_ref, x_ref, xp_ref, xb_ref, y_ref,
-                tau_ref, sig_ref, *outs, n_steps, gamma):
-    df, cf = df_ref[...], cf_ref[...]
-    da, ca = da_ref[...], ca_ref[...]
-    acc_dt = jnp.promote_types(df.dtype, jnp.float32)
-
-    def mv(d, cols, v):
-        g = jnp.take(v[:, 0], cols, axis=0)
-        return jnp.sum((d * g).astype(acc_dt),
-                       axis=1).reshape(-1, 1).astype(v.dtype)
-
-    results = _run_steps(
-        lambda v: mv(df, cf, v), lambda v: mv(da, ca, v),
         b_ref[...], c_ref[...], lb_ref[...], ub_ref[...],
         T_ref[...], S_ref[...], gamma, n_steps,
         x_ref[...], xp_ref[...], xb_ref[...], y_ref[...],
@@ -152,27 +162,14 @@ def fused_dense_steps(K, K_adj, b, c, lb, ub, T, Sigma,
     """
     m, n = K.shape
     dt = K.dtype
+    check_kernel_dtype(dt, interpret, "the dense megakernel")
+    interpret = resolve_interpret(interpret)
+    if not interpret:           # interpreted kernels have no VMEM
+        check_vmem(m, n, dt)
     vecs, state = _cols(b, c, lb, ub, T, Sigma, x, x_prev, x_bar, y,
                         tau, sigma, dt)
     kernel = functools.partial(_dense_kernel, n_steps=int(n_steps),
                                gamma=float(gamma))
     out = _fused_call(kernel, [K, K_adj] + vecs, state, m, n, dt,
-                      resolve_interpret(interpret))
-    return _unpack(out, m, n)
-
-
-def fused_ell_steps(data_f, cols_f, data_a, cols_a, b, c, lb, ub, T,
-                    Sigma, x, x_prev, x_bar, y, tau, sigma, *,
-                    n_steps: int, gamma: float, interpret=None):
-    """k fused ELL PDHG half-steps; forward ELL of K (m, Wf) plus the
-    separately stored ELL of K^T (n, Wa).  Same returns as the dense
-    variant."""
-    m, n = data_f.shape[0], data_a.shape[0]
-    dt = data_f.dtype
-    vecs, state = _cols(b, c, lb, ub, T, Sigma, x, x_prev, x_bar, y,
-                        tau, sigma, dt)
-    kernel = functools.partial(_ell_kernel, n_steps=int(n_steps),
-                               gamma=float(gamma))
-    out = _fused_call(kernel, [data_f, cols_f, data_a, cols_a] + vecs,
-                      state, m, n, dt, resolve_interpret(interpret))
+                      interpret)
     return _unpack(out, m, n)

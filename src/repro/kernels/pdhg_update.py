@@ -27,6 +27,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from .interpret import resolve_interpret
+from .spec import block_spec
 
 BLOCK = 256
 
@@ -61,8 +62,8 @@ def primal_update_padded(x, kty, c, T, lb, ub, tau, theta, *,
     N = x.shape[0]
     assert N % BLOCK == 0
     grid = (N // BLOCK,)
-    vec = pl.BlockSpec((BLOCK, 1), lambda i: (i, 0))
-    scl = pl.BlockSpec((1, 1), lambda i: (0, 0))
+    vec = block_spec((BLOCK, 1), lambda i: (i, 0))
+    scl = block_spec((1, 1), lambda i: (0, 0))
     return pl.pallas_call(
         _primal_kernel,
         grid=grid,
@@ -83,8 +84,8 @@ def dual_update_padded(y, kxbar, b, Sigma, sigma, *,
     M = y.shape[0]
     assert M % BLOCK == 0
     grid = (M // BLOCK,)
-    vec = pl.BlockSpec((BLOCK, 1), lambda i: (i, 0))
-    scl = pl.BlockSpec((1, 1), lambda i: (0, 0))
+    vec = block_spec((BLOCK, 1), lambda i: (i, 0))
+    scl = block_spec((1, 1), lambda i: (0, 0))
     return pl.pallas_call(
         _dual_kernel,
         grid=grid,

@@ -1,4 +1,4 @@
-"""Row-blocked ELL sparse MVM kernel (Pallas) + host-side COO->ELL.
+"""ELL sparse MVM (an XLA gather) + host-side COO->ELL conversion.
 
 The sparse COO path is memory-optimal but loses on wall clock: every
 MVM is a scatter-add over (nnz,) gathers, which XLA CPU serializes, and
@@ -16,32 +16,19 @@ so one MVM is a dense gather + axis-1 reduction,
 with no scatter anywhere.  Padding entries carry data == 0, so whatever
 ``cols`` says for them (index 0 by convention) contributes nothing —
 exactly the inertness contract of ``stack_problems_sparse``'s (0, 0)
-padding.  The row dimension blocks in ``ROW_BLOCK`` chunks aligned with
-the crossbar tile edge (``crossbar_mvm.TILE_R``), so an ELL operator
-occupies the same logical row tiling as the programmed array it models.
+padding.
 
-Two execution paths, one rule (``kernels.interpret``): on CPU the
-vectorized gather/segment-sum jnp expression IS the kernel (running the
-Pallas kernel interpreted would only add overhead); on an accelerator
-backend the row-blocked Pallas kernel runs compiled, the input vector
-resident in VMEM across all row blocks.  ``use_pallas=True`` forces the
-Pallas kernel (interpreted on CPU) for parity testing.
+One execution path on every backend: ``ell_matvec`` is a plain XLA
+gather and axis-1 sum.  (A row-blocked Pallas form of the same gather
+does not compile for TPU: Mosaic accepts only 2-D gathers.)
 """
 from __future__ import annotations
 
-import functools
 from typing import Optional, Tuple
 
-import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import pallas as pl
 
-from .interpret import resolve_interpret
-
-# Row-block edge: matches crossbar_mvm.TILE_R so ELL row blocks and
-# crossbar tiles describe the same physical row partitioning.
-ROW_BLOCK = 128
 # Smallest ELL width bucket (power-of-two bucketing, like nnz_bucket).
 MIN_ELL_WIDTH = 4
 
@@ -104,83 +91,11 @@ def ell_from_coo(data, row, col, shape: Tuple[int, int],
     return ell_data, ell_cols
 
 
-# ------------------------------------------------------------- reference ---
+# ----------------------------------------------------------------- matvec ---
 
-def ell_matvec_ref(data, cols, v):
-    """Vectorized gather/segment-sum ELL matvec — the CPU/interpret
-    path.  One (m, W) gather + one axis-1 reduction; no scatter."""
+def ell_matvec(data, cols, v):
+    """``w = ELL(data, cols) @ v``: one (m, W) gather + one axis-1
+    reduction; no scatter."""
     if data.shape[1] == 0:
         return jnp.zeros(data.shape[0], v.dtype)
     return jnp.sum(data * jnp.take(v, cols, axis=0), axis=1)
-
-
-# --------------------------------------------------------- Pallas kernel ---
-
-def _ell_kernel(d_ref, c_ref, v_ref, out_ref):
-    d = d_ref[...]                                   # (ROW_BLOCK, W)
-    c = c_ref[...]
-    v = v_ref[...][:, 0]                             # (n,) resident in VMEM
-    g = jnp.take(v, c, axis=0)                       # row-block gather
-    # accumulate at least f32, never BELOW the data dtype (matches
-    # crossbar_mvm: x64 interpret validation must not round through f32)
-    acc_dt = jnp.promote_types(d.dtype, jnp.float32)
-    w = jnp.sum((d * g).astype(acc_dt), axis=1)
-    out_ref[...] = w.reshape(-1, 1).astype(out_ref.dtype)
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def ell_matvec_padded(data, cols, v, *, interpret: bool | None = None):
-    """Row-blocked Pallas ELL matvec on row-aligned inputs.
-
-    data/cols: (R, W) with R a multiple of ``ROW_BLOCK``; v: (n, 1).
-    Returns (R, 1).  The full input vector is a VMEM-resident block for
-    every grid step ("broadcast the input voltages"), each grid step
-    owns one row block — the sparse analogue of ``crossbar_mvm``'s
-    row-tile accumulation, with the column loop replaced by the gather.
-    """
-    R, W = data.shape
-    assert R % ROW_BLOCK == 0, (R, ROW_BLOCK)
-    n = v.shape[0]
-    return pl.pallas_call(
-        _ell_kernel,
-        grid=(R // ROW_BLOCK,),
-        in_specs=[
-            pl.BlockSpec((ROW_BLOCK, W), lambda i: (i, 0)),   # data
-            pl.BlockSpec((ROW_BLOCK, W), lambda i: (i, 0)),   # cols
-            pl.BlockSpec((n, 1), lambda i: (0, 0)),           # v (full)
-        ],
-        out_specs=pl.BlockSpec((ROW_BLOCK, 1), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((R, 1), data.dtype),
-        interpret=resolve_interpret(interpret),
-    )(data, cols, v)
-
-
-# ------------------------------------------------------------ public API ---
-
-def _pad_rows(a, mult):
-    size = a.shape[0]
-    target = ((size + mult - 1) // mult) * mult
-    if target == size:
-        return a
-    return jnp.pad(a, ((0, target - size), (0, 0)))
-
-
-def ell_matvec(data, cols, v, *, interpret=None,
-               use_pallas: Optional[bool] = None):
-    """``w = ELL(data, cols) @ v`` with arbitrary (m, W).
-
-    ``use_pallas=None`` auto-selects: the vectorized jnp gather path on
-    CPU (where Pallas would run interpreted anyway), the row-blocked
-    Pallas kernel on accelerator backends.  ``use_pallas=True`` forces
-    the Pallas kernel — interpreted on CPU — for parity validation.
-    """
-    if use_pallas is None:
-        use_pallas = not resolve_interpret(interpret)
-    if not use_pallas or data.shape[1] == 0:
-        return ell_matvec_ref(data, cols, v)
-    m = data.shape[0]
-    dp = _pad_rows(data, ROW_BLOCK)
-    cp = _pad_rows(cols, ROW_BLOCK)
-    out = ell_matvec_padded(dp, cp, v.reshape(-1, 1),
-                            interpret=resolve_interpret(interpret))
-    return out[:m, 0]

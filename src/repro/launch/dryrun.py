@@ -1,4 +1,4 @@
-from repro.runtime.compat import request_cpu_devices
+from repro.runtime.mesh import request_cpu_devices
 assert request_cpu_devices(512), \
     "JAX backend initialized before repro.launch.dryrun import"
 
@@ -42,7 +42,6 @@ from ..configs import (
     get_config,
     input_specs,
 )
-from ..runtime import compat
 from ..runtime.mesh import make_production_mesh
 from ..launch import hlo as hlo_mod
 from ..models import lm as lm_mod
@@ -303,7 +302,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, out_dir: str,
     tag = f"{arch}_{shape_name}_{mesh_name}{tag_suffix}"
     path = os.path.join(out_dir, f"{tag}.json")
     mesh = make_production_mesh(multi_pod=multi_pod)
-    compat.set_mesh(mesh)
+    jax.set_mesh(mesh)
     try:
         if arch in LP_CONFIGS:
             result = lower_lp_cell(arch, mesh)

@@ -24,6 +24,7 @@ from __future__ import annotations
 import argparse
 
 import jax
+import numpy as np
 
 from ..core.pdhg import PDHGOptions, solve_jit
 from ..crossbar import (
@@ -40,6 +41,7 @@ from ..lp import (
     table1_instance,
 )
 from ..runtime import BatchSolver
+from ..runtime.cache import enable_compile_cache
 from ..runtime.mesh import make_local_mesh
 
 
@@ -62,6 +64,7 @@ def load_instance(spec: str, seed: int = 0):
 
 
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--instance", default="gen-ip002")
     ap.add_argument("--instances", default=None,
@@ -141,6 +144,10 @@ def main(argv=None):
                          "pair replication with median decode — tolerates "
                          "stuck cells/drift at k-fold write+read energy, "
                          "ledgered separately under the *_ecc fields")
+    ap.add_argument("--dtype", default="float64",
+                    choices=["float64", "float32"],
+                    help="solve dtype (PDHGOptions.dtype); compiled Pallas "
+                         "kernels on TPU take float32 only")
     ap.add_argument("--tol", type=float, default=1e-6)
     ap.add_argument("--max-iters", type=int, default=40000)
     ap.add_argument("--seed", type=int, default=0)
@@ -184,7 +191,8 @@ def main(argv=None):
     jax.config.update("jax_enable_x64", True)
     opts = PDHGOptions(max_iters=args.max_iters, tol=args.tol,
                        check_every=100, seed=args.seed,
-                       kernel=args.kernel, step_rule=args.step_rule,
+                       dtype=np.dtype(args.dtype), kernel=args.kernel,
+                       step_rule=args.step_rule,
                        gamma=args.gamma, norm_backend=args.norm_backend,
                        refine_rounds=args.refine_rounds,
                        refine_tol=args.refine_tol)

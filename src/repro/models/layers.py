@@ -18,7 +18,7 @@ import numpy as np
 # Ambient-mesh-aware sharding annotations live in the runtime layer so
 # they work on every supported JAX version (0.4.x lacks the explicit-
 # sharding APIs these used to call directly).
-from ..runtime.compat import batch_axes, constrain
+from ..runtime.mesh import batch_axes, constrain
 
 
 def rms_norm(x, gain, eps: float = 1e-6):

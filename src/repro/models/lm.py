@@ -21,7 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from ..runtime import compat
+from ..runtime import mesh as rmesh
 from . import mla as mla_mod
 from . import moe as moe_mod
 from . import rwkv6 as rwkv_mod
@@ -42,9 +42,9 @@ def _dtype(cfg: ModelConfig):
 
 
 # with_sharding_constraint / batch-axis resolution against the ambient
-# mesh, portable across JAX versions (see runtime.compat).
-_batch_axes = compat.batch_axes
-_constrain = compat.constrain
+# mesh (see runtime.mesh).
+_batch_axes = rmesh.batch_axes
+_constrain = rmesh.constrain
 
 
 def constrain_tokens(x):

@@ -3,13 +3,13 @@
 Single entry point for everything that touches JAX's (version-volatile)
 device and sharding machinery:
 
-  * ``runtime.compat``  — feature-detected shims over the JAX APIs that
-    moved between 0.4.x and >=0.6 (``AxisType``, ``get_abstract_mesh``,
-    ``set_mesh``/``use_mesh``, top-level ``shard_map``), plus the shared
-    sharding-annotation helpers (``constrain``/``batch_axes``).
   * ``runtime.mesh``    — one ``make_mesh`` API for every mesh in the
     repo (tests, local solves, 16x16 / 2x16x16 production dry-runs) with
-    a CPU multi-device fallback for tests.
+    a CPU multi-device fallback for tests, plus the sharding-annotation
+    helpers (``constrain``/``batch_axes``) over the ambient mesh that
+    ``jax.set_mesh`` installs.
+  * ``runtime.cache``   — the persistent compilation cache every entry
+    point turns on first.
   * ``runtime.batch``   — shape-bucketed batch solving of heterogeneous
     LP streams with a compiled-executable cache per bucket.
   * ``runtime.sanitize`` — compile-count guard (warm streams assert
@@ -19,27 +19,19 @@ device and sharding machinery:
     ``jax.distributed`` bring-up with a single-process fallback,
     deterministic per-pod bucket routing, and the
     ``ClusterBatchSolver`` routed-stream scheduler.
-
-No module outside ``runtime.compat`` may reference the volatile
-``jax.sharding`` attributes directly.
 """
-from . import batch, compat, mesh, sanitize
+from . import batch, cache, mesh, sanitize
 # cluster pulls in repro.distributed (fault-tolerant transport); import
 # it last so the partially initialized package already exposes the
-# submodules that chain re-enters (compat via distributed.pdhg_dist)
+# submodules that chain re-enters (mesh via distributed.pdhg_dist)
 from . import cluster
 from .batch import BatchSolver, solve_stream
+from .cache import enable_compile_cache
 from .cluster import ClusterBatchSolver, init_cluster
-from .compat import (
-    batch_axes,
-    constrain,
-    get_abstract_mesh,
-    set_mesh,
-    shard_map,
-    use_mesh,
-)
 from .sanitize import CompileGuard, RecompileError, no_implicit_transfers
 from .mesh import (
+    batch_axes,
+    constrain,
     make_cluster_mesh,
     make_local_mesh,
     make_mesh,
@@ -53,10 +45,10 @@ __all__ = [
     "RecompileError",
     "batch",
     "batch_axes",
+    "cache",
     "cluster",
-    "compat",
     "constrain",
-    "get_abstract_mesh",
+    "enable_compile_cache",
     "init_cluster",
     "no_implicit_transfers",
     "sanitize",
@@ -65,8 +57,5 @@ __all__ = [
     "make_mesh",
     "make_production_mesh",
     "mesh",
-    "set_mesh",
-    "shard_map",
     "solve_stream",
-    "use_mesh",
 ]

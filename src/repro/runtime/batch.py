@@ -30,7 +30,7 @@ Past toy sizes, two more concerns take over (ROADMAP item 2):
   * **Sparse streams.**  A ``StandardLP`` whose K is a ``SparseCOO``
     routes through a dedicated sparse bucket pipeline selected by
     ``PDHGOptions.sparse_kernel``.  The default ``"ell"`` backend
-    converts COO to row-blocked ELL — forward (B, m, Wf) AND adjoint
+    converts COO to ELL — forward (B, m, Wf) AND adjoint
     (B, n, Wa) layouts, widths power-of-two bucketed like ``nnz_bucket``
     — so Ruiz equilibration, Pock–Chambolle diagonals, Lanczos and both
     solve MVMs are gathers + axis-1 reductions with no scatter anywhere
@@ -215,7 +215,7 @@ def stack_problems_ell(lps: Sequence[StandardLP],
                        n: Optional[int] = None,
                        wf: Optional[int] = None,
                        wa: Optional[int] = None) -> tuple:
-    """Stack sparse StandardLPs in row-blocked ELL form.
+    """Stack sparse StandardLPs in ELL form.
 
     Returns ``(data_f (B, m, wf), cols_f (B, m, wf) int32,
     data_a (B, n, wa), cols_a (B, n, wa) int32, b, c, lb, ub)``.
@@ -312,7 +312,7 @@ def _estimate_norm_mv(mv, dim: int, dtype, opts: PDHGOptions,
 
 
 def _prep_one(K, b, c, lb, ub, rho_seed=None, *, opts: PDHGOptions):
-    from ..core.symblock import build_sym_block
+    from ..core.symblock import build_sym_block, mv
 
     (Ks, bs, cs, lbs, ubs, T, Sigma, D1, D2) = prep_scale(
         K, b, c, lb, ub, opts)
@@ -321,7 +321,7 @@ def _prep_one(K, b, c, lb, ub, rho_seed=None, *, opts: PDHGOptions):
     else:
         Keff = jnp.sqrt(Sigma)[:, None] * Ks * jnp.sqrt(T)[None, :]
         M = build_sym_block(Keff)
-        rho = _estimate_norm_mv(lambda v: M @ v, M.shape[0], M.dtype,
+        rho = _estimate_norm_mv(lambda v: mv(M, v), M.shape[0], M.dtype,
                                 opts, rho_seed)
     return (Ks, bs, cs, lbs, ubs, T, Sigma, rho, D1, D2)
 
@@ -516,8 +516,8 @@ def make_ell_bucket_pipeline(opts: PDHGOptions, sigma_read: float = 0.0,
     Inputs are the ``stack_problems_ell`` layout plus per-instance keys.
     The operator-norm estimate runs a matvec-only Lanczos with two ELL
     gathers per iteration; the solve mounts ``engine.sparse_ell_operator``
-    (``opts.megakernel`` additionally fuses each check window into one
-    ``kernels.pdhg_megakernel`` launch).  Like the COO pipeline, no
+    (which has no fused window: ``opts.megakernel`` is refused).  Like
+    the COO pipeline, no
     dense (m, n) array ever exists on host or device — but unlike it,
     no iteration-path op is a scatter, which is what makes sparse win
     on wall clock and not just memory.  Returns an extra trailing
@@ -549,9 +549,6 @@ def make_ell_bucket_pipeline(opts: PDHGOptions, sigma_read: float = 0.0,
                                         rho_seed)
             rho = engine.lemma2_margin(rho_raw, sigma_read)
         op = engine.sparse_ell_operator(sf, cf, sa, ca, sigma_read)
-        if opts.megakernel and sigma_read == 0.0:
-            op = op._replace(fuse=engine.make_fused_ell(
-                sf, cf, sa, ca, bs, cs, lbs, ubs, T, Sigma, opts.gamma))
         x, y, it, merit = engine.solve_core(
             None, None, bs, cs, lbs, ubs, T, Sigma, rho, key, static,
             operator=op)
@@ -823,6 +820,11 @@ class BatchSolver:
     def cache_info(self) -> dict:
         return {"hits": self.cache_hits, "misses": self.cache_misses,
                 "entries": len(self._cache)}
+
+    def hlo_texts(self) -> List[str]:
+        """Compiled HLO text of every cached executable (shows, e.g.,
+        whether a Pallas kernel compiled in as ``tpu_custom_call``)."""
+        return [c.as_text() for c in self._cache.values()]
 
     # -- cross-instance norm cache ------------------------------------
 

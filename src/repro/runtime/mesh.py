@@ -9,17 +9,20 @@ Absorbs the logic that used to live in ``launch/mesh.py`` (production
     make_local_mesh()                               # all local devices
 
 Device-count errors point at the CPU multi-device fallback
-(``compat.request_cpu_devices`` / XLA_FLAGS) instead of XLA's opaque
-reshape failure.
+(``request_cpu_devices`` / XLA_FLAGS) instead of XLA's opaque reshape
+failure.  Every mesh is built with ``AxisType.Auto`` axes (GSPMD
+propagation), which is what the shard_map solvers and the
+``constrain`` annotations below are written for.
 """
 from __future__ import annotations
 
 import math
+import os
+import re
 from typing import Dict, Optional, Sequence, Tuple, Union
 
 import jax
-
-from . import compat
+from jax.sharding import PartitionSpec as P
 
 AxesSpec = Union[Dict[str, int], Sequence[int]]
 
@@ -52,10 +55,11 @@ def make_mesh(axes: AxesSpec, names: Optional[Sequence[str]] = None, *,
             + (f" on backend {backend!r}" if backend else "")
             + "; for CPU tests set XLA_FLAGS="
             f"--xla_force_host_platform_device_count={needed} before the "
-            "first device query (repro.runtime.compat.request_cpu_devices)")
+            "first device query (repro.runtime.mesh.request_cpu_devices)")
     if devices is not None:
         devices = list(devices)[:needed]
-    return compat.make_mesh(shape, axis_names, devices=devices)
+    auto = (jax.sharding.AxisType.Auto,) * len(axis_names)
+    return jax.make_mesh(shape, axis_names, axis_types=auto, devices=devices)
 
 
 def _default_pod_count() -> int:
@@ -123,3 +127,63 @@ def make_local_mesh(axis_names: Tuple[str, str] = ("data", "model"), *,
         rows -= 1
     cols = n_dev // rows
     return make_mesh((rows, cols), axis_names, backend=backend)
+
+
+# ------------------------------------------------- sharding annotations ---
+
+def mesh_axis_names() -> Tuple[str, ...]:
+    """Axis names of the ambient mesh (``jax.set_mesh``); () without one."""
+    return tuple(jax.sharding.get_abstract_mesh().axis_names)
+
+
+def clean_spec(spec, names) -> P:
+    """Drop spec axes absent from ``names`` (e.g. 'pod' on single-pod)."""
+    clean = []
+    for s in spec:
+        if s is None:
+            clean.append(None)
+        elif isinstance(s, tuple):
+            t = tuple(a for a in s if a in names)
+            clean.append(t if t else None)
+        else:
+            clean.append(s if s in names else None)
+    return P(*clean)
+
+
+def constrain(x, *spec):
+    """``with_sharding_constraint`` against the ambient mesh; a no-op
+    without one.  Axes absent from the mesh are dropped; non-divisible
+    dims are padded internally by GSPMD (e.g. 40 heads on a 16-way
+    axis)."""
+    names = mesh_axis_names()
+    if not names:
+        return x
+    return jax.lax.with_sharding_constraint(x, clean_spec(spec, names))
+
+
+def batch_axes() -> Tuple[str, ...]:
+    """The data-parallel axes present on the ambient mesh."""
+    return tuple(a for a in ("pod", "data") if a in mesh_axis_names())
+
+
+# -------------------------------------------------- CPU device fan-out ---
+
+def request_cpu_devices(n: int) -> bool:
+    """Ask XLA for ``n`` host-platform (CPU) devices.
+
+    Must run before the first device query in the process (the flag is
+    read at backend initialization).  Returns False when the backend is
+    already up, in which case the caller should re-exec in a subprocess.
+    """
+    from jax._src import xla_bridge
+
+    flag = f"--xla_force_host_platform_device_count={n}"
+    cur = os.environ.get("XLA_FLAGS", "")
+    if "--xla_force_host_platform_device_count" in cur:
+        # rewrite a pre-existing (possibly different) count in place
+        cur = re.sub(r"--xla_force_host_platform_device_count=\d+",
+                     flag, cur)
+        os.environ["XLA_FLAGS"] = cur
+    else:
+        os.environ["XLA_FLAGS"] = (cur + " " + flag).strip()
+    return not xla_bridge.backends_are_initialized()

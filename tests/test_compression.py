@@ -21,7 +21,6 @@ from repro.distributed.compression import (
     quantize_int8,
 )
 from repro.lp import random_standard_lp
-from repro.runtime import compat
 from repro.runtime.mesh import make_mesh
 
 
@@ -73,11 +72,11 @@ def test_stochastic_round_is_unbiased():
 def _psum_fn(bits, with_key=False):
     mesh = make_mesh({"data": 1})
     if with_key:
-        return compat.shard_map(
+        return jax.shard_map(
             lambda x, k: compressed_psum(x, "data", key=k, bits=bits),
             mesh=mesh, in_specs=(P(), P()), out_specs=P(),
             check_vma=False)
-    return compat.shard_map(
+    return jax.shard_map(
         lambda x: compressed_psum(x, "data", bits=bits),
         mesh=mesh, in_specs=(P(),), out_specs=P(), check_vma=False)
 
@@ -102,7 +101,7 @@ def test_compressed_psum_parity_with_exact_collective():
     psum to float32 round-off — compression is lossless in the limit."""
     x = _x(seed=2)
     mesh = make_mesh({"data": 1})
-    exact = compat.shard_map(lambda v: jax.lax.psum(v, "data"),
+    exact = jax.shard_map(lambda v: jax.lax.psum(v, "data"),
                              mesh=mesh, in_specs=(P(),), out_specs=P(),
                              check_vma=False)(x)
     compressed = _psum_fn(24)(x)

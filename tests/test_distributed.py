@@ -141,8 +141,8 @@ def test_quantize_roundtrip():
 
 
 MULTIDEV_SCRIPT = textwrap.dedent("""
-    from repro.runtime import compat
-    assert compat.request_cpu_devices(8), "backend initialized too early"
+    from repro.runtime.mesh import request_cpu_devices
+    assert request_cpu_devices(8), "backend initialized too early"
     import jax
     jax.config.update("jax_enable_x64", True)
     import numpy as np

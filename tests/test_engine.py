@@ -287,10 +287,10 @@ def test_padded_kernel_wrappers_autodetect_interpret():
     from repro.kernels import pdhg_update as upd
 
     for fn in (xbar.crossbar_mvm_padded, upd.primal_update_padded,
-               upd.dual_update_padded):
+               upd.dual_update_padded, ops.crossbar_mvm, ops.primal_update,
+               ops.dual_update):
         default = inspect.signature(fn).parameters["interpret"].default
         assert default is None, fn
-    assert ops._interpret_default() is interpret_default()
     assert interpret_default() == (jax.default_backend() == "cpu")
     # and the auto-detected default actually runs on this backend
     col = jnp.ones((upd.BLOCK, 1), jnp.float32)
@@ -330,6 +330,108 @@ def test_launch_solve_kernel_flag(x64, capsys):
                     "--instances", "rand:6x10,rand:8x12",
                     "--max-iters", "2000", "--tol", "1e-4"])
     assert all(r.converged for r in results)
+
+
+def _dot_precisions(jaxpr):
+    """``precision`` of every dot_general in a jaxpr, sub-jaxprs (loop
+    bodies, pallas_call kernels) included."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            found.append(eqn.params["precision"])
+        for val in eqn.params.values():
+            for sub in val if isinstance(val, (list, tuple)) else [val]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    found += _dot_precisions(sub)
+    return found
+
+
+HIGHEST = (jax.lax.Precision.HIGHEST, jax.lax.Precision.HIGHEST)
+
+
+def test_launch_solve_f32_matmuls_at_full_precision(x64, monkeypatch):
+    """The CLI's f32 solve multiplies at ``HIGHEST`` precision (a TPU
+    would otherwise multiply f32 in one bf16 pass) through the library's
+    own MVMs: the process-wide default precision is left alone."""
+    from repro.launch import solve as cli
+
+    real_core = engine.solve_core
+    seen = []
+
+    def spy_core(*args, **kw):
+        seen.append((jax.config.jax_default_matmul_precision,
+                     jax.make_jaxpr(real_core, static_argnums=(10,))(
+                         *args, **kw)))
+        return real_core(*args, **kw)
+
+    monkeypatch.setattr(engine, "solve_core", spy_core)
+    res = cli.main(["--instance", "rand:6x10", "--backend", "exact",
+                    "--dtype", "float32", "--max-iters", "2000",
+                    "--tol", "1e-4"])
+    assert res.status == "optimal" and res.x.dtype == np.float32
+    [(default, jaxpr)] = seen
+    assert default is None
+    precs = _dot_precisions(jaxpr.jaxpr)
+    assert len(precs) >= 2 and all(p == HIGHEST for p in precs)
+
+
+@pytest.mark.parametrize("path", ["accel", "lanczos", "sharded",
+                                  "crossbar_kernel", "megakernel"])
+def test_solver_mvms_multiply_at_highest_precision(path):
+    """Every operator backend's MVM, Pallas kernels included, carries
+    ``HIGHEST`` precision itself, whatever the caller's default."""
+    from repro.core.lanczos import lanczos_svd_jit
+    from repro.kernels import pdhg_megakernel as mega
+
+    m, n = 8, 16
+    K = jnp.ones((m, n), jnp.float32)
+    vm, vn = jnp.ones((m,), jnp.float32), jnp.ones((n,), jnp.float32)
+    if path == "accel":
+        fn, args = encode_exact(K).mvm_full, (jnp.ones((m + n,)),)
+    elif path == "lanczos":
+        fn, args = lanczos_svd_jit, (jnp.ones((m + n, m + n)),)
+    elif path == "sharded":
+        op = engine.sharded_operator(K, "rows", "cols")
+        fn = jax.vmap(lambda x, y: (op.fwd(x), op.adj(y)),
+                      axis_name="cols", spmd_axis_name=None)
+        fn = jax.vmap(fn, axis_name="rows")
+        args = (vn[None, None], vm[None, None])
+    elif path == "crossbar_kernel":
+        g = jnp.ones((m + n, m + n), jnp.float32)
+        op = engine.crossbar_operator(g, g, 1.0, m, n, interpret=True)
+        fn, args = (lambda x: op.fwd(x)), (vn,)
+    else:
+        def fn(x, y):
+            return mega.fused_dense_steps(
+                K, K.T, vm, vn, vn, vn, vn, vm, x, x, x, y, 1.0, 1.0,
+                n_steps=2, gamma=0.0, interpret=True)
+        args = (vn, vm)
+    precs = _dot_precisions(jax.make_jaxpr(fn)(*args).jaxpr)
+    assert precs and all(p == HIGHEST for p in precs), precs
+
+
+def test_megakernel_vmem_budget_binds_compiled_only():
+    """Interpreted kernels have no VMEM: a bucket past the budget runs
+    interpreted and is refused, with a message, only when compiled."""
+    from repro.kernels import pdhg_megakernel as mega
+
+    m, n = 1024, 1024
+    assert mega.vmem_estimate(m, n, np.float32) > mega.VMEM_BUDGET
+    K = jax.ShapeDtypeStruct((m, n), jnp.float32)
+    vm = jax.ShapeDtypeStruct((m,), jnp.float32)
+    vn = jax.ShapeDtypeStruct((n,), jnp.float32)
+    sc = jax.ShapeDtypeStruct((), jnp.float32)
+
+    def fused(interpret):
+        return jax.eval_shape(
+            lambda *a: mega.fused_dense_steps(
+                *a, n_steps=2, gamma=0.0, interpret=interpret),
+            K, K, vm, vn, vn, vn, vn, vm, vn, vn, vn, vm, sc, sc)
+
+    assert fused(True)[0].shape == (n,)
+    with pytest.raises(ValueError, match="VMEM"):
+        fused(False)
 
 
 # ------------------------------------- restart flag + megakernel mode ---

@@ -1,8 +1,4 @@
-"""Runtime portability layer: compat shims, mesh API, bucketed batching.
-
-The compat tests must pass on BOTH JAX generations (0.4.x and the
-explicit-sharding >=0.6 line) — they assert behaviour, not which branch
-of the shim was taken."""
+"""Runtime layer: mesh API and sharding helpers, bucketed batching."""
 import subprocess
 import sys
 import textwrap
@@ -14,52 +10,48 @@ import pytest
 
 from repro.core import PDHGOptions, solve_jit
 from repro.lp import random_standard_lp
-from repro.runtime import BatchSolver, compat, solve_stream
+from repro.runtime import BatchSolver, mesh as rmesh, solve_stream
 from repro.runtime.batch import bucket_dims, pad_problem, stack_problems
 from repro.runtime.mesh import make_local_mesh, make_mesh
 
 OPTS = PDHGOptions(max_iters=20000, tol=1e-6, check_every=64)
 
 
-# ------------------------------------------------------------ compat ---
+# ------------------------------------------------- sharding helpers ---
 
 def test_compat_shims_resolve_on_installed_jax():
-    """Every shim is callable on whatever JAX this container has."""
-    # mesh construction never needs the (possibly absent) AxisType
-    mesh = compat.make_mesh((1,), ("data",))
+    """Meshes are built with Auto axes, and without an ambient mesh the
+    helpers report none."""
+    mesh = make_mesh((1,), ("data",))
     assert tuple(mesh.axis_names) == ("data",)
-    # ambient-mesh query degrades to "no mesh", never AttributeError
-    amb = compat.get_abstract_mesh()
-    assert amb is None or hasattr(amb, "axis_names")
-    # feature flags are booleans and coherent: new-API names either all
-    # exist (new JAX) or the fallbacks must be importable (old JAX)
-    if not compat.HAS_TOPLEVEL_SHARD_MAP:
-        from jax.experimental.shard_map import shard_map  # noqa: F401
-    assert isinstance(compat.HAS_AXIS_TYPE, bool)
+    assert mesh.axis_types == (jax.sharding.AxisType.Auto,)
+    assert jax.sharding.get_abstract_mesh().empty
+    assert rmesh.mesh_axis_names() == ()
+    assert rmesh.batch_axes() == ()
 
 
 def test_compat_constrain_no_mesh_is_identity():
     x = jnp.ones((4, 8))
-    out = compat.constrain(x, "data", None)
+    out = rmesh.constrain(x, "data", None)
     assert out is x or np.array_equal(np.asarray(out), np.asarray(x))
 
 
 def test_compat_use_mesh_scopes_ambient_mesh():
     mesh = make_mesh({"data": 1})
-    with compat.use_mesh(mesh):
-        assert compat.mesh_axis_names() == ("data",)
-        assert compat.batch_axes() == ("data",)
+    with jax.set_mesh(mesh):
+        assert rmesh.mesh_axis_names() == ("data",)
+        assert rmesh.batch_axes() == ("data",)
         # constraining against the ambient mesh works inside jit
-        y = jax.jit(lambda v: compat.constrain(v, "data") * 2)(jnp.ones(4))
+        y = jax.jit(lambda v: rmesh.constrain(v, "data") * 2)(jnp.ones(4))
         np.testing.assert_array_equal(np.asarray(y), 2 * np.ones(4))
-    assert "data" not in compat.mesh_axis_names()
+    assert "data" not in rmesh.mesh_axis_names()
 
 
 def test_compat_shard_map_psum():
     mesh = make_mesh({"data": 1})
     from jax.sharding import PartitionSpec as P
 
-    f = compat.shard_map(
+    f = jax.shard_map(
         lambda x: jax.lax.psum(x, "data"), mesh=mesh,
         in_specs=P("data"), out_specs=P(), check_vma=False)
     out = f(jnp.arange(4.0))
@@ -135,8 +127,8 @@ def test_make_mesh_multidevice_subprocess():
     from conftest import repo_root, subprocess_env
 
     script = textwrap.dedent("""
-        from repro.runtime import compat
-        assert compat.request_cpu_devices(8)
+        from repro.runtime.mesh import request_cpu_devices
+        assert request_cpu_devices(8)
         import jax
         from repro.runtime.mesh import make_mesh
         mesh = make_mesh({"pod": 2, "data": 2, "model": 2})
@@ -149,6 +141,34 @@ def test_make_mesh_multidevice_subprocess():
         [sys.executable, "-c", script], env=subprocess_env(),
         cwd=repo_root(), capture_output=True, text=True, timeout=300)
     assert "MESH PASS" in proc.stdout, proc.stdout + proc.stderr
+
+
+# ----------------------------------------------------- compile cache ---
+
+@pytest.mark.parametrize("env_dir", [None, "elsewhere"])
+def test_enable_compile_cache_one_fixed_directory(monkeypatch, tmp_path,
+                                                  env_dir):
+    """JAX_COMPILATION_CACHE_DIR wins untouched; otherwise the cache
+    goes to the one fixed directory inside the checkout."""
+    from repro.runtime import cache
+
+    old = jax.config.jax_compilation_cache_dir
+    sentinel = str(tmp_path / "untouched")
+    jax.config.update("jax_compilation_cache_dir", sentinel)
+    try:
+        if env_dir is None:
+            monkeypatch.delenv(cache.ENV_VAR, raising=False)
+            want = str(cache.CHECKOUT_CACHE_DIR)
+            assert cache.enable_compile_cache() == want
+            assert jax.config.jax_compilation_cache_dir == want
+            assert cache.CHECKOUT_CACHE_DIR.parent == \
+                cache.Path(__file__).resolve().parents[1]
+        else:
+            monkeypatch.setenv(cache.ENV_VAR, str(tmp_path / env_dir))
+            assert cache.enable_compile_cache() == str(tmp_path / env_dir)
+            assert jax.config.jax_compilation_cache_dir == sentinel
+    finally:
+        jax.config.update("jax_compilation_cache_dir", old)
 
 
 # ------------------------------------------------------------- batch ---

@@ -352,22 +352,22 @@ def test_ell_from_coo_drops_explicit_zeros_and_pads_empty_rows(x64):
         np.array([2000.0, 0.0, 3.0]))
 
 
-def test_ell_pallas_kernel_matches_reference(x64, rng):
-    """The row-blocked Pallas kernel (interpret mode on CPU) and the
-    gather/segment-sum reference produce the same product, including on
-    row counts that are not a multiple of the 128-row block."""
+def test_ell_matvec_is_one_xla_gather(x64, rng):
+    """ELL has one path on every backend: an XLA gather + axis-1 sum,
+    with no Pallas kernel behind it, exact on row counts that are not a
+    multiple of any tile."""
     from repro.kernels.sparse_mvm import ell_from_coo, ell_matvec
 
     K = rng.normal(size=(150, 40)) * (rng.random((150, 40)) < 0.1)
     sp = SparseCOO.from_dense(K)
     data, cols = ell_from_coo(sp.data, sp.row, sp.col, sp.shape)
-    v = rng.normal(size=40)
-    ref = np.asarray(ell_matvec(jnp.asarray(data), jnp.asarray(cols),
-                                jnp.asarray(v)))
-    pal = np.asarray(ell_matvec(jnp.asarray(data), jnp.asarray(cols),
-                                jnp.asarray(v), use_pallas=True))
-    np.testing.assert_allclose(pal, ref, rtol=1e-12, atol=1e-12)
-    np.testing.assert_allclose(ref, K @ v, rtol=1e-10, atol=1e-10)
+    args = (jnp.asarray(data), jnp.asarray(cols),
+            jnp.asarray(rng.normal(size=40)))
+    jaxpr = str(jax.make_jaxpr(ell_matvec)(*args))
+    assert "gather[" in jaxpr and "pallas_call" not in jaxpr
+    np.testing.assert_allclose(np.asarray(ell_matvec(*args)),
+                               K @ np.asarray(args[2]), rtol=1e-10,
+                               atol=1e-10)
 
 
 def test_ell_width_bucket_pow2_floor():
@@ -413,16 +413,15 @@ def test_ell_and_bcoo_stream_parity(x64):
         np.testing.assert_allclose(re_.y, rb.y, rtol=1e-7, atol=1e-9)
 
 
-def test_ell_megakernel_stream_parity(x64):
-    """megakernel=True on the ELL pipeline fuses each check_every window
-    into one launch; iterates must match the per-step ELL serve."""
-    lps = sparse_lp_stream(4, density=0.08, seed=5)
-    r_ell = BatchSolver(OPTS).solve_stream(lps)
-    r_meg = BatchSolver(dataclasses.replace(
-        OPTS, megakernel=True)).solve_stream(lps)
-    for re_, rm in zip(r_ell, r_meg):
-        assert rm.iterations == re_.iterations
-        np.testing.assert_allclose(rm.x, re_.x, rtol=1e-8, atol=1e-10)
+def test_ell_megakernel_refused(x64):
+    """The fused window exists for the dense operator only: asking for
+    it on a sparse stream is refused by name, never served unfused."""
+    lps = sparse_lp_stream(2, density=0.08, seed=5)
+    for kernel in ("ell", "bcoo"):
+        opts = dataclasses.replace(OPTS, megakernel=True,
+                                   sparse_kernel=kernel)
+        with pytest.raises(ValueError, match="dense operator only"):
+            BatchSolver(opts).solve_stream(lps)
 
 
 def test_ell_bucket_signature_carries_both_widths(x64):

@@ -64,13 +64,18 @@ def test_pipeline_crossbar_host_loop(x64):
 
 
 def test_pipeline_crossbar_jit_both_devices(x64):
+    """Programming error and read noise are random draws, so one seed's
+    gap is one sample: the band holds for the median over five seeds."""
     lp = random_standard_lp(16, 28, seed=2)
     for dev in (EPIRAM, TAOX_HFOX):
-        rep = solve_crossbar_jit(
-            lp, PDHGOptions(max_iters=15000, tol=1e-5, check_every=100,
-                            lanczos_iters=32), device=dev)
-        rel = abs(rep.result.obj - lp.obj_opt) / abs(lp.obj_opt)
-        assert rel < 5e-2, (dev.name, rel)   # paper Table-2 gap band
+        rels = []
+        for seed in range(5):
+            rep = solve_crossbar_jit(
+                lp, PDHGOptions(max_iters=15000, tol=1e-5, check_every=100,
+                                lanczos_iters=32, seed=seed), device=dev)
+            rels.append(abs(rep.result.obj - lp.obj_opt) / abs(lp.obj_opt))
+        # paper Table-2 gap band
+        assert np.median(rels) < 5e-2, (dev.name, rels)
 
 
 def test_assignment_lp_integral_solution(x64):

@@ -159,9 +159,9 @@ class Config:
           "power_iteration_mv")),
         ("repro/kernels/ops.py",
          ("crossbar_mvm", "primal_update", "dual_update")),
-        ("repro/kernels/sparse_mvm.py", ("ell_matvec", "ell_matvec_ref")),
+        ("repro/kernels/sparse_mvm.py", ("ell_matvec",)),
         ("repro/kernels/pdhg_megakernel.py",
-         ("fused_dense_steps", "fused_ell_steps", "_run_steps")),
+         ("fused_dense_steps", "_run_steps")),
         ("repro/kernels/ref.py",
          ("crossbar_mvm_ref", "primal_update_ref", "dual_update_ref")),
         ("repro/crossbar/solver.py", ("make_crossbar_bucket_pipeline",)),

@@ -103,7 +103,7 @@ class PathSpec:
 
 def supported_paths() -> List[PathSpec]:
     """The full combo matrix.  Constraints mirror the engine's:
-    megakernel exists for dense/ell only (the fusable operand layouts),
+    megakernel exists for dense only (the one fusable operand layout),
     the distributed path always uses jnp updates, and the restart=False
     variant is audited once per backend on the canonical combo (restart
     is orthogonal to kernel/step_rule in the trace — it only toggles
@@ -111,7 +111,7 @@ def supported_paths() -> List[PathSpec]:
     paths: List[PathSpec] = []
     for backend in ("dense", "ell", "bcoo", "crossbar", "sharded"):
         kernels = ("jnp",) if backend == "sharded" else ("jnp", "pallas")
-        megas = (False, True) if backend in ("dense", "ell") else (False,)
+        megas = (False, True) if backend == "dense" else (False,)
         for kernel in kernels:
             for rule in ("fixed", "adaptive", "strongly_convex"):
                 for mega in megas:
@@ -167,12 +167,7 @@ def _make_operator(spec: PathSpec, prob, engine):
         da, ca = ell_from_coo(vals, cols, rows, (n, m))
         df, da = jnp.asarray(df, dt), jnp.asarray(da, dt)
         cf, ca = jnp.asarray(cf), jnp.asarray(ca)
-        op = engine.sparse_ell_operator(df, cf, da, ca)
-        if spec.megakernel:      # mounted as runtime/batch.py mounts it
-            op = op._replace(fuse=engine.make_fused_ell(
-                df, cf, da, ca, prob["b"], prob["c"], prob["lb"],
-                prob["ub"], prob["T"], prob["Sigma"], spec.gamma))
-        return op
+        return engine.sparse_ell_operator(df, cf, da, ca)
     if spec.backend == "crossbar":
         gp = jnp.maximum(prob["K"], 0.0)
         gn = jnp.maximum(-prob["K"], 0.0)
@@ -230,7 +225,6 @@ def _trace_sharded(spec: PathSpec, prob):
 
     from repro.core import engine
     from repro.distributed.sharding import col_axes, row_axes
-    from repro.runtime import compat
 
     mesh = Mesh(np.array(jax.devices()[:1]).reshape(1, 1),
                 ("data", "model"))
@@ -250,7 +244,7 @@ def _trace_sharded(spec: PathSpec, prob):
             restart=spec.restart, step_rule=spec.step_rule,
             xsum_fn=xsum, ysum_fn=ysum)
 
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         local_solve, mesh=mesh,
         in_specs=(P(rax, cax), P(rax), P(cax), P(cax), P(cax), P(cax),
                   P(rax)),
@@ -520,7 +514,7 @@ def check_dtype(spec_name: str, jaxpr) -> List[Finding]:
 # host-sync / host-callback primitives that must never run per-iteration
 EFFECT_DENYLIST = frozenset({
     "pure_callback", "debug_callback", "io_callback", "infeed",
-    "outfeed", "device_put", "copy_to_host_async",
+    "outfeed", "device_put", "copy_to_host_async", "debug_print",
 })
 
 

@@ -65,6 +65,19 @@ ADAPT_SMOOTH = 0.5         # exp(s*log(target) + (1-s)*log(old))
 ADAPT_OMEGA_CLIP = 1024.0  # omega confined to [omega0/1024, omega0*1024]
 _ADAPT_TINY = 1e-30        # degenerate-movement / div-by-zero guard
 
+# Device scopes (``jax.named_scope``): every op traced inside one carries
+# the name in its ``op_name`` metadata, so a profiler trace can split a
+# bucket program's device time into scaling, norm estimate, PDHG window
+# and check/restart block.  Metadata only: the compiled ops are the same,
+# and JAX's persistent compilation cache keys them without it, so an
+# executable it hands back may carry the scopes of another version of
+# this code; profile with ``jax_compilation_cache_include_metadata_in_key``
+# on, or with a cache of its own.
+PREP_SCOPE = "repro.prep"      # Ruiz + diagonal preconditioning
+NORM_SCOPE = "repro.norm"      # Keff / symmetric block + norm estimate
+WINDOW_SCOPE = "repro.window"  # the check_every PDHG steps of one body
+CHECK_SCOPE = "repro.check"    # residual MVMs, restart, step adaptation
+
 
 # ---------------------------------------------------------------- state ---
 
@@ -569,67 +582,69 @@ def pdhg_loop(op: Operator, upd: Updates, b, c, lb, ub, T, Sigma,
              ax, ay, aKx, aKTy, aok, rx, ry) = loop
         else:
             state, it, merit, xs, ys, cnt, m_restart, rk = loop
-        if op.fuse is not None:
-            # megakernel window: one fused launch, no per-step keys
-            # (fused backends are noiseless, so none are consumed)
-            state, dxs, dys = op.fuse(state, check_every)
-            xs, ys = xs + dxs, ys + dys
-            cnt = cnt + jnp.asarray(check_every, cnt.dtype)
-        else:
-            state, xs, ys, cnt, rk = jax.lax.fori_loop(
-                0, check_every, half_iter, (state, xs, ys, cnt, rk))
-        rk, k3, k4 = jax.random.split(rk, 3)
-        Kx = op.fwd(state.x, k3)
-        KTy = op.adj(state.y, k4)
-        merit = residual_fn(state.x, state.x_prev, state.y, Kx, KTy)
-        Kx_c, KTy_c = Kx, KTy
-        if restart:
-            x_avg = xs / jnp.maximum(cnt, 1.0)
-            y_avg = ys / jnp.maximum(cnt, 1.0)
-            rk, k5, k6 = jax.random.split(rk, 3)
-            Kxa = op.fwd(x_avg, k5)
-            KTya = op.adj(y_avg, k6)
-            merit_avg = residual_fn(x_avg, x_avg, y_avg, Kxa, KTya)
-            do_restart = merit_avg < restart_beta * m_restart
-            use_avg = jnp.logical_or(
-                jnp.logical_and(do_restart, merit_avg < merit),
-                merit_avg <= tol,  # adopt the average if it satisfies tol
-            )
-            pick = lambda a, cur: jnp.where(use_avg, a, cur)  # noqa: E731
-            state = state._replace(
-                x=pick(x_avg, state.x), x_prev=pick(x_avg, state.x_prev),
-                x_bar=pick(x_avg, state.x_bar), y=pick(y_avg, state.y))
-            m_restart = jnp.where(do_restart,
-                                  jnp.minimum(merit_avg, merit), m_restart)
-            xs = jnp.where(do_restart, jnp.zeros_like(xs), xs)
-            ys = jnp.where(do_restart, jnp.zeros_like(ys), ys)
-            cnt = jnp.where(do_restart, 0.0, cnt)
-            # the carried merit must be the merit of the iterate actually
-            # CARRIED: min(merit, merit_avg) used to adopt the averaged
-            # iterate's (lower) merit even when the state kept the
-            # current iterate, so exits reported a residual the returned
-            # solution does not satisfy.
-            merit = jnp.where(use_avg, merit_avg, merit)
+        with jax.named_scope(WINDOW_SCOPE):
+            if op.fuse is not None:
+                # megakernel window: one fused launch, no per-step keys
+                # (fused backends are noiseless, so none are consumed)
+                state, dxs, dys = op.fuse(state, check_every)
+                xs, ys = xs + dxs, ys + dys
+                cnt = cnt + jnp.asarray(check_every, cnt.dtype)
+            else:
+                state, xs, ys, cnt, rk = jax.lax.fori_loop(
+                    0, check_every, half_iter, (state, xs, ys, cnt, rk))
+        with jax.named_scope(CHECK_SCOPE):
+            rk, k3, k4 = jax.random.split(rk, 3)
+            Kx = op.fwd(state.x, k3)
+            KTy = op.adj(state.y, k4)
+            merit = residual_fn(state.x, state.x_prev, state.y, Kx, KTy)
+            Kx_c, KTy_c = Kx, KTy
+            if restart:
+                x_avg = xs / jnp.maximum(cnt, 1.0)
+                y_avg = ys / jnp.maximum(cnt, 1.0)
+                rk, k5, k6 = jax.random.split(rk, 3)
+                Kxa = op.fwd(x_avg, k5)
+                KTya = op.adj(y_avg, k6)
+                merit_avg = residual_fn(x_avg, x_avg, y_avg, Kxa, KTya)
+                do_restart = merit_avg < restart_beta * m_restart
+                use_avg = jnp.logical_or(
+                    jnp.logical_and(do_restart, merit_avg < merit),
+                    merit_avg <= tol,  # adopt the average if it satisfies tol
+                )
+                pick = lambda a, cur: jnp.where(use_avg, a, cur)  # noqa: E731
+                state = state._replace(
+                    x=pick(x_avg, state.x), x_prev=pick(x_avg, state.x_prev),
+                    x_bar=pick(x_avg, state.x_bar), y=pick(y_avg, state.y))
+                m_restart = jnp.where(do_restart,
+                                      jnp.minimum(merit_avg, merit), m_restart)
+                xs = jnp.where(do_restart, jnp.zeros_like(xs), xs)
+                ys = jnp.where(do_restart, jnp.zeros_like(ys), ys)
+                cnt = jnp.where(do_restart, 0.0, cnt)
+                # the carried merit must be the merit of the iterate actually
+                # CARRIED: min(merit, merit_avg) used to adopt the averaged
+                # iterate's (lower) merit even when the state kept the
+                # current iterate, so exits reported a residual the returned
+                # solution does not satisfy.
+                merit = jnp.where(use_avg, merit_avg, merit)
+                if adaptive:
+                    # operator images of the iterate actually carried — by
+                    # linearity, no extra MVMs beyond the check's
+                    Kx_c, KTy_c = pick(Kxa, Kx), pick(KTya, KTy)
+                    tau_n, sigma_n = adaptive_omega_update(
+                        state.tau, state.sigma, state.x - rx, state.y - ry,
+                        T, Sigma, w_lo, w_hi, do_restart, xsum, ysum)
+                    state = state._replace(tau=tau_n, sigma=sigma_n)
+                    rx = jnp.where(do_restart, state.x, rx)
+                    ry = jnp.where(do_restart, state.y, ry)
             if adaptive:
-                # operator images of the iterate actually carried — by
-                # linearity, no extra MVMs beyond the check's
-                Kx_c, KTy_c = pick(Kxa, Kx), pick(KTya, KTy)
-                tau_n, sigma_n = adaptive_omega_update(
-                    state.tau, state.sigma, state.x - rx, state.y - ry,
-                    T, Sigma, w_lo, w_hi, do_restart, xsum, ysum)
+                tau_n, sigma_n = adaptive_shrink(
+                    state.tau, state.sigma, eta,
+                    state.x - ax, state.y - ay, Kx_c - aKx, KTy_c - aKTy,
+                    T, Sigma, aok, xsum, ysum)
                 state = state._replace(tau=tau_n, sigma=sigma_n)
-                rx = jnp.where(do_restart, state.x, rx)
-                ry = jnp.where(do_restart, state.y, ry)
-        if adaptive:
-            tau_n, sigma_n = adaptive_shrink(
-                state.tau, state.sigma, eta,
-                state.x - ax, state.y - ay, Kx_c - aKx, KTy_c - aKTy,
-                T, Sigma, aok, xsum, ysum)
-            state = state._replace(tau=tau_n, sigma=sigma_n)
-            return (state, it + check_every, merit, xs, ys, cnt,
-                    m_restart, rk, state.x, state.y, Kx_c, KTy_c,
-                    jnp.asarray(True), rx, ry)
-        return (state, it + check_every, merit, xs, ys, cnt, m_restart, rk)
+                return (state, it + check_every, merit, xs, ys, cnt,
+                        m_restart, rk, state.x, state.y, Kx_c, KTy_c,
+                        jnp.asarray(True), rx, ry)
+            return (state, it + check_every, merit, xs, ys, cnt, m_restart, rk)
 
     def cond(loop):
         it, merit = loop[1], loop[2]

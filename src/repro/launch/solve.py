@@ -41,6 +41,7 @@ from ..lp import (
     table1_instance,
 )
 from ..runtime import BatchSolver
+from ..runtime.batch import STREAM_PHASES
 from ..runtime.cache import enable_compile_cache
 from ..runtime.mesh import make_local_mesh
 
@@ -260,9 +261,8 @@ def main(argv=None):
                 line += f" (known optimum {lp.obj_opt:.6f}, rel err {rel:.2e})"
             print(line)
         st = solver.last_stream_stats
-        print(f"stream: buckets={st['n_buckets']} "
-              f"dispatch={st['dispatch_s']:.3f}s "
-              f"collect={st['collect_s']:.3f}s "
+        phases = " ".join(f"{p}={st[p + '_s']:.3f}s" for p in STREAM_PHASES)
+        print(f"stream: buckets={st['n_buckets']} {phases} "
               f"host_stack_bytes=dense:{st['dense_stack_bytes']}"
               f"/sparse:{st['sparse_stack_bytes']}")
         if "routing" in st:

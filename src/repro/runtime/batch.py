@@ -49,6 +49,7 @@ Past toy sizes, two more concerns take over (ROADMAP item 2):
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import hashlib
@@ -89,6 +90,12 @@ DONATE_MIN_BYTES = 32 << 20
 # estimate run this many power-iteration refinement MVMs instead of the
 # full ``opts.lanczos_iters``-step estimate
 NORM_REFINE_ITERS = 8
+# host spans of one ``solve_stream`` call: the root span, and one child
+# ``repro.stream.<phase>`` per phase, timed into ``<phase>_s`` of
+# ``last_stream_stats``
+STREAM_SPAN = "repro.stream"
+STREAM_PHASES = ("group", "stack", "upload", "compile", "dispatch", "wait",
+                 "collect")
 
 
 # ------------------------------------------------------------- bucketing ---
@@ -270,6 +277,7 @@ def _single_solve(K, b, c, lb, ub, T, Sigma, rho, key, static):
         K, K.T, b, c, lb, ub, T, Sigma, rho, key, static)
 
 
+@jax.named_scope(engine.PREP_SCOPE)
 def prep_scale(K, b, c, lb, ub, opts: PDHGOptions):
     """Ruiz + diagonal preconditioning (Algorithm 4 step 0), vmappable.
 
@@ -319,10 +327,11 @@ def _prep_one(K, b, c, lb, ub, rho_seed=None, *, opts: PDHGOptions):
     if opts.norm_override is not None:
         rho = jnp.asarray(opts.norm_override, Ks.dtype)
     else:
-        Keff = jnp.sqrt(Sigma)[:, None] * Ks * jnp.sqrt(T)[None, :]
-        M = build_sym_block(Keff)
-        rho = _estimate_norm_mv(lambda v: mv(M, v), M.shape[0], M.dtype,
-                                opts, rho_seed)
+        with jax.named_scope(engine.NORM_SCOPE):
+            Keff = jnp.sqrt(Sigma)[:, None] * Ks * jnp.sqrt(T)[None, :]
+            M = build_sym_block(Keff)
+            rho = _estimate_norm_mv(lambda v: mv(M, v), M.shape[0],
+                                    M.dtype, opts, rho_seed)
     return (Ks, bs, cs, lbs, ubs, T, Sigma, rho, D1, D2)
 
 
@@ -377,6 +386,7 @@ def _coo_matvec(data, row, col, v, out_dim: int):
     return jnp.zeros(out_dim, v.dtype).at[row].add(data * v[col])
 
 
+@jax.named_scope(engine.PREP_SCOPE)
 def _prep_one_sparse(data, idx, b, c, lb, ub, opts: PDHGOptions):
     """Sparse Ruiz + Pock–Chambolle diagonals on COO nonzeros.
 
@@ -438,17 +448,18 @@ def make_sparse_bucket_pipeline(opts: PDHGOptions, sigma_read: float = 0.0,
             rho_raw = jnp.asarray(opts.norm_override, kd.dtype)
             rho = rho_raw
         else:
-            row, col = ki[:, 0], ki[:, 1]
-            deff = d * jnp.sqrt(Sigma)[row] * jnp.sqrt(T)[col]
+            with jax.named_scope(engine.NORM_SCOPE):
+                row, col = ki[:, 0], ki[:, 1]
+                deff = d * jnp.sqrt(Sigma)[row] * jnp.sqrt(T)[col]
 
-            def mv(v):         # symmetric block M' of Keff, matvec-only
-                top = _coo_matvec(deff, row, col, v[m:], m)
-                bot = _coo_matvec(deff, col, row, v[:m], n)
-                return jnp.concatenate([top, bot])
+                def mv(v):     # symmetric block M' of Keff, matvec-only
+                    top = _coo_matvec(deff, row, col, v[m:], m)
+                    bot = _coo_matvec(deff, col, row, v[:m], n)
+                    return jnp.concatenate([top, bot])
 
-            rho_raw = _estimate_norm_mv(mv, m + n, kd.dtype, opts,
-                                        rho_seed)
-            rho = engine.lemma2_margin(rho_raw, sigma_read)
+                rho_raw = _estimate_norm_mv(mv, m + n, kd.dtype, opts,
+                                            rho_seed)
+                rho = engine.lemma2_margin(rho_raw, sigma_read)
         K_sp = jsparse.BCOO((d, ki), shape=(m, n))
         x, y, it, merit = engine.solve_core(
             K_sp, None, bs, cs, lbs, ubs, T, Sigma, rho, key, static)
@@ -475,6 +486,7 @@ def _row_reduce(a, reduce_fn):
     return reduce_fn(a, axis=1)
 
 
+@jax.named_scope(engine.PREP_SCOPE)
 def _prep_one_ell(df, cf, da, ca, b, c, lb, ub, opts: PDHGOptions):
     """Sparse Ruiz + Pock–Chambolle diagonals on ELL nonzeros.
 
@@ -536,18 +548,19 @@ def make_ell_bucket_pipeline(opts: PDHGOptions, sigma_read: float = 0.0,
             rho_raw = jnp.asarray(opts.norm_override, df.dtype)
             rho = rho_raw
         else:
-            rtS, rtT = jnp.sqrt(Sigma), jnp.sqrt(T)
-            deff_f = sf * rtS[:, None] * rtT[cf]
-            deff_a = sa * rtT[:, None] * rtS[ca]
+            with jax.named_scope(engine.NORM_SCOPE):
+                rtS, rtT = jnp.sqrt(Sigma), jnp.sqrt(T)
+                deff_f = sf * rtS[:, None] * rtT[cf]
+                deff_a = sa * rtT[:, None] * rtS[ca]
 
-            def mv(v):         # symmetric block M' of Keff, matvec-only
-                top = ell_matvec(deff_f, cf, v[m:])
-                bot = ell_matvec(deff_a, ca, v[:m])
-                return jnp.concatenate([top, bot])
+                def mv(v):     # symmetric block M' of Keff, matvec-only
+                    top = ell_matvec(deff_f, cf, v[m:])
+                    bot = ell_matvec(deff_a, ca, v[:m])
+                    return jnp.concatenate([top, bot])
 
-            rho_raw = _estimate_norm_mv(mv, m + n, df.dtype, opts,
-                                        rho_seed)
-            rho = engine.lemma2_margin(rho_raw, sigma_read)
+                rho_raw = _estimate_norm_mv(mv, m + n, df.dtype, opts,
+                                            rho_seed)
+                rho = engine.lemma2_margin(rho_raw, sigma_read)
         op = engine.sparse_ell_operator(sf, cf, sa, ca, sigma_read)
         x, y, it, merit = engine.solve_core(
             None, None, bs, cs, lbs, ubs, T, Sigma, rho, key, static,
@@ -602,6 +615,34 @@ def _donation_supported() -> bool:
         return False
 
 
+@contextlib.contextmanager
+def _phase(stats: dict, name: str, **args):
+    """One host phase of ``solve_stream``: the profiler span
+    ``repro.stream.<name>`` (with ``args`` as its metadata) and the same
+    interval's host-clock seconds added to ``stats["<name>_s"]``.  Yields
+    the span, so metadata known only at its end can be set on it."""
+    with jax.profiler.TraceAnnotation(f"{STREAM_SPAN}.{name}",
+                                      **args) as span:
+        t0 = time.perf_counter()
+        try:
+            yield span
+        finally:
+            stats[name + "_s"] += time.perf_counter() - t0
+
+
+def bucket_tag(key) -> str:
+    """Stable string id of a bucket key ``((m_pad, n_pad), sparse sig)``
+    (span metadata, filenames, routing tables)."""
+    (mb, nb), sig = key
+    if sig is None:
+        kind = "dense"
+    elif isinstance(sig, tuple):            # ("ell", wf, wa)
+        kind = f"ell{sig[1]}x{sig[2]}"
+    else:                                   # bare int nnz bucket
+        kind = f"nnz{sig}"
+    return f"{mb}x{nb}-{kind}"
+
+
 def _outputs_ready(out) -> bool:
     """True when every device buffer of a dispatched result is ready
     (computation finished) — drives completion-order collection."""
@@ -635,11 +676,30 @@ class BatchSolver:
     blocking per-bucket dispatch, e.g. to bound device memory on tiny
     hosts); ``donate_min_bytes`` is the stacked-operator size beyond
     which the input buffer is donated to the executable.
-    ``last_stream_stats`` records, per ``solve_stream`` call, the host
-    bytes each stacking path materialized, dispatch/collect timings, and
-    ``compiles`` — the number of XLA compilations the call triggered
-    (``runtime.sanitize``; a warm pass over a bucket mix served before
-    must report 0).  ``transfer_sanitize=True`` additionally runs every
+    ``last_stream_stats`` describes the last ``solve_stream`` call:
+
+      * ``n_buckets``, ``n_local_buckets``: buckets grouped, and served
+        by this process;
+      * ``dense_stack_bytes``, ``sparse_stack_bytes``: host bytes each
+        stacking path materialized;
+      * ``donated_buckets``, ``norm_seeded_buckets``: buckets that
+        donated their operator buffer, or ran the seeded norm refinement;
+      * ``compiles``: XLA compilations the call triggered
+        (``runtime.sanitize``; a warm pass over a bucket mix served
+        before must report 0);
+      * host-clock seconds per phase, each the summed duration of its
+        profiler span ``repro.stream.<phase>`` (children of the call's
+        ``repro.stream`` span, so a profiler trace puts them on the
+        device's timeline): ``group_s`` (bucketing, with the ELL width
+        scan), ``stack_s`` (padding and stacking, COO->ELL included),
+        ``upload_s`` (PRNG keys, host->device copies, norm seeds),
+        ``compile_s`` (cache misses only), ``dispatch_s`` (enqueueing
+        the executables), ``wait_s`` (blocking until a bucket's device
+        work is done) and ``collect_s`` (device->host copies and the
+        per-instance results).  The phases do not overlap;
+        ``runtime.cluster.ClusterBatchSolver`` adds its routing keys.
+
+    ``transfer_sanitize=True`` additionally runs every
     executable under ``sanitize.no_implicit_transfers()``, so an
     accidental per-call host<->device transfer raises instead of
     silently serializing dispatch.
@@ -740,15 +800,17 @@ class BatchSolver:
                 (tuple(self.mesh.axis_names),
                  tuple(self.mesh.devices.shape), self.batch_axes))
 
-    def _compile(self, key, pipeline, args, donate: bool):
+    def _compile(self, key, pipeline, args, donate: bool, stats: dict,
+                 bucket: str):
         hit = self._cache.get(key)
         if hit is not None:
             self.cache_hits += 1
             return hit
         self.cache_misses += 1
         donate_argnums = (0,) if donate else ()
-        compiled = jax.jit(pipeline, donate_argnums=donate_argnums) \
-            .lower(*args).compile()
+        with _phase(stats, "compile", bucket=bucket):
+            compiled = jax.jit(pipeline, donate_argnums=donate_argnums) \
+                .lower(*args).compile()
         self._cache[key] = compiled
         return compiled
 
@@ -765,8 +827,8 @@ class BatchSolver:
         """
         return jax.random.PRNGKey(0)  # jaxlint: disable=R2
 
-    def _executable(self, mb: int, nb: int, B: int, dtype, *,
-                    donate: bool = False, seeded: bool = False):
+    def _executable(self, mb: int, nb: int, B: int, dtype, *, stats: dict,
+                    bucket: str, donate: bool = False, seeded: bool = False):
         sig = ("dense", mb, nb) + (("normseed",) if seeded else ())
         key = self._cache_key(sig, B, dtype, donate)
         k0 = self._key_template()
@@ -778,11 +840,11 @@ class BatchSolver:
             args = args + (self._sds((B,), dtype),)
         return self._compile(key, self._make_pipeline(norm_seeded=seeded)
                              if seeded else self._make_pipeline(),
-                             args, donate)
+                             args, donate, stats, bucket)
 
     def _executable_sparse(self, mb: int, nb: int, nnz: int, B: int,
-                           dtype, *, donate: bool = False,
-                           seeded: bool = False):
+                           dtype, *, stats: dict, bucket: str,
+                           donate: bool = False, seeded: bool = False):
         sig = ("sparse", mb, nb, nnz) + (("normseed",) if seeded else ())
         key = self._cache_key(sig, B, dtype, donate)
         k0 = self._key_template()
@@ -796,11 +858,11 @@ class BatchSolver:
         return self._compile(key,
                              self._make_sparse_pipeline(norm_seeded=seeded)
                              if seeded else self._make_sparse_pipeline(),
-                             args, donate)
+                             args, donate, stats, bucket)
 
     def _executable_ell(self, mb: int, nb: int, wf: int, wa: int, B: int,
-                        dtype, *, donate: bool = False,
-                        seeded: bool = False):
+                        dtype, *, stats: dict, bucket: str,
+                        donate: bool = False, seeded: bool = False):
         sig = ("ell", mb, nb, wf, wa) + (("normseed",) if seeded else ())
         key = self._cache_key(sig, B, dtype, donate)
         k0 = self._key_template()
@@ -815,7 +877,7 @@ class BatchSolver:
             args = args + (self._sds((B,), dtype),)
         return self._compile(key, self._make_ell_pipeline(norm_seeded=seeded)
                              if seeded else self._make_ell_pipeline(),
-                             args, donate)
+                             args, donate, stats, bucket)
 
     def cache_info(self) -> dict:
         return {"hits": self.cache_hits, "misses": self.cache_misses,
@@ -914,58 +976,69 @@ class BatchSolver:
         a bare int nnz bucket for the COO/BCOO backend, or
         ``("ell", wf, wa)`` width buckets for the ELL backend.  Returns
         the (asynchronously dispatched) device outputs — the call never
-        blocks on the solve itself.
+        blocks on the solve itself.  Runs the ``stack``, ``upload``,
+        ``compile`` (cache misses only) and ``dispatch`` phases.
         """
         B = self._padded_batch(len(group))
-        # norm-reuse serving: a bucket is seeded only when EVERY member's
-        # fingerprint already has a cached estimate (filler slots reuse
-        # the first member's seed — their results are dropped anyway)
-        rho_seeds = None
-        if self.norm_reuse and self.opts.norm_override is None:
-            cached = [self._norm_cache.get(self._norm_fingerprint(lp))
-                      for lp in group]
-            if all(v is not None for v in cached):
-                # dtype-convert on host: jnp.asarray of a ready numpy
-                # array is a pure transfer, so a first seeded pass never
-                # triggers an eager convert compile (warm streams must
-                # stay at zero)
-                rho_seeds = jnp.asarray(np.asarray(
-                    cached + [cached[0]] * (B - len(group)),
-                    jax.dtypes.canonicalize_dtype(dtype)))
-        seeded = rho_seeds is not None
+        tag = bucket_tag(((mb, nb), sig))
         # batch padding repeats the first instance; extras are dropped
-        filler = [group[0]] * (B - len(group))
-        keys = self._instance_keys(idxs, n_total, B)
-        if isinstance(sig, tuple):                       # ("ell", wf, wa)
-            _, wf, wa = sig
-            stacked = stack_problems_ell(group + filler, m=mb, n=nb,
-                                         wf=wf, wa=wa)
-            stats["sparse_stack_bytes"] += sum(a.nbytes for a in stacked)
-            arrays = [jnp.asarray(a, jnp.int32) if i in (1, 3)
-                      else jnp.asarray(a, dtype)
+        n_fill = B - len(group)
+        with _phase(stats, "stack", bucket=tag, lanes=B,
+                    instances=len(group)) as span:
+            if isinstance(sig, tuple):                   # ("ell", wf, wa)
+                _, wf, wa = sig
+                stacked = stack_problems_ell(group + [group[0]] * n_fill,
+                                             m=mb, n=nb, wf=wf, wa=wa)
+                int_arrays = (1, 3)
+                exe_fn = functools.partial(self._executable_ell, mb, nb, wf,
+                                           wa, B, dtype)
+            elif sig is not None:                        # bare int nnz
+                stacked = stack_problems_sparse(group + [group[0]] * n_fill,
+                                                m=mb, n=nb, nnz=sig)
+                int_arrays = (1,)
+                exe_fn = functools.partial(self._executable_sparse, mb, nb,
+                                           sig, B, dtype)
+            else:
+                dense = [lp.densified() for lp in group]
+                stacked = stack_problems(dense + [dense[0]] * n_fill,
+                                         m=mb, n=nb)
+                int_arrays = ()
+                exe_fn = functools.partial(self._executable, mb, nb, B,
+                                           dtype)
+            nbytes = sum(a.nbytes for a in stacked)
+            stats["sparse_stack_bytes" if sig is not None
+                  else "dense_stack_bytes"] += nbytes
+            span.set_metadata(bytes=nbytes)
+        with _phase(stats, "upload", bucket=tag):
+            keys = self._instance_keys(idxs, n_total, B)
+            arrays = [jnp.asarray(a, jnp.int32 if i in int_arrays else dtype)
                       for i, a in enumerate(stacked)]
-            donate = self._donate(arrays[0].nbytes)
-            exe_fn = functools.partial(self._executable_ell, mb, nb, wf,
-                                       wa, B, dtype, donate=donate)
-        elif sig is not None:                            # bare int nnz
-            stacked = stack_problems_sparse(group + filler, m=mb, n=nb,
-                                            nnz=sig)
-            stats["sparse_stack_bytes"] += sum(a.nbytes for a in stacked)
-            arrays = ([jnp.asarray(stacked[0], dtype),
-                       jnp.asarray(stacked[1], jnp.int32)]
-                      + [jnp.asarray(a, dtype) for a in stacked[2:]])
-            donate = self._donate(arrays[0].nbytes)
-            exe_fn = functools.partial(self._executable_sparse, mb, nb,
-                                       sig, B, dtype, donate=donate)
-        else:
-            group = [lp.densified() for lp in group]
-            filler = [group[0]] * (B - len(group))
-            stacked = stack_problems(group + filler, m=mb, n=nb)
-            stats["dense_stack_bytes"] += sum(a.nbytes for a in stacked)
-            arrays = [jnp.asarray(a, dtype) for a in stacked]
-            donate = self._donate(arrays[0].nbytes)
-            exe_fn = functools.partial(self._executable, mb, nb, B, dtype,
-                                       donate=donate)
+            # norm-reuse serving: a bucket is seeded only when EVERY
+            # member's fingerprint already has a cached estimate (filler
+            # slots reuse the first member's seed — their results are
+            # dropped anyway)
+            rho_seeds = None
+            if self.norm_reuse and self.opts.norm_override is None:
+                cached = [self._norm_cache.get(self._norm_fingerprint(lp))
+                          for lp in group]
+                if all(v is not None for v in cached):
+                    # dtype-convert on host: jnp.asarray of a ready numpy
+                    # array is a pure transfer, so a first seeded pass
+                    # never triggers an eager convert compile (warm
+                    # streams must stay at zero)
+                    rho_seeds = jnp.asarray(np.asarray(
+                        cached + [cached[0]] * n_fill,
+                        jax.dtypes.canonicalize_dtype(dtype)))
+            sh = self._sharding()
+            if sh is not None:
+                arrays = [jax.device_put(a, sh) for a in arrays]
+                keys = jax.device_put(keys, sh)
+                if rho_seeds is not None:
+                    rho_seeds = jax.device_put(rho_seeds, sh)
+        seeded = rho_seeds is not None
+        donate = self._donate(arrays[0].nbytes)
+        exe_fn = functools.partial(exe_fn, stats=stats, bucket=tag,
+                                   donate=donate)
         exe = exe_fn(seeded=seeded)
         if self.norm_reuse and self.opts.norm_override is None \
                 and not seeded:
@@ -977,21 +1050,16 @@ class BatchSolver:
             self._seeded_idxs.update(idxs)
             stats["norm_seeded_buckets"] += 1
         stats["donated_buckets"] += int(donate)
-        sh = self._sharding()
-        if sh is not None:
-            arrays = [jax.device_put(a, sh) for a in arrays]
-            keys = jax.device_put(keys, sh)
-            if seeded:
-                rho_seeds = jax.device_put(rho_seeds, sh)
         call_args = ((*arrays, keys, rho_seeds) if seeded
                      else (*arrays, keys))
-        if self.transfer_sanitize:
-            # inputs are on device by now (the jnp.asarray stacking above
-            # is the one sanctioned upload); anything implicit past this
-            # point is a serving bug
-            with sanitize.no_implicit_transfers():
-                return exe(*call_args)
-        return exe(*call_args)
+        with _phase(stats, "dispatch", bucket=tag):
+            if self.transfer_sanitize:
+                # inputs are on device by now (the upload above is the
+                # one sanctioned transfer); anything implicit past this
+                # point is a serving bug
+                with sanitize.no_implicit_transfers():
+                    return exe(*call_args)
+            return exe(*call_args)
 
     def _sparse_signature(self, lp: StandardLP):
         """Sparse component of an instance's bucket key: the nnz bucket
@@ -1051,46 +1119,53 @@ class BatchSolver:
         """
         lps = list(lps)
         dtype = jnp.dtype(self.opts.dtype)
-        buckets = self._group_buckets(lps)
-        mine, remote = self._route(buckets)
-
         results: List[Optional[object]] = [None] * len(lps)
         self._seeded_idxs = set()
-        stats = {"n_buckets": len(buckets), "n_local_buckets": len(mine),
-                 "dense_stack_bytes": 0,
-                 "sparse_stack_bytes": 0, "donated_buckets": 0,
-                 "norm_seeded_buckets": 0,
-                 "dispatch_s": 0.0, "collect_s": 0.0, "compiles": 0}
+        stats = {f"{p}_s": 0.0 for p in STREAM_PHASES}
+        stats.update(dense_stack_bytes=0, sparse_stack_bytes=0,
+                     donated_buckets=0, norm_seeded_buckets=0, compiles=0)
         compiles0 = sanitize.compile_counts()["compiles"]
-        t0 = time.perf_counter()
-        pending = []
-        for ((mb, nb), sig), idxs in mine.items():
-            group = [lps[i] for i in idxs]
-            out = self._dispatch_bucket(group, idxs, len(lps), mb, nb, sig,
-                                        dtype, stats)
-            if self.async_dispatch:
-                pending.append((out, ((mb, nb), sig), idxs))
-            else:
-                jax.block_until_ready(out)
-                self._collect(out, (mb, nb), idxs, lps, results)
-                self._bucket_served(((mb, nb), sig), idxs, out)
-        stats["dispatch_s"] = time.perf_counter() - t0
-        t0 = time.perf_counter()
-        while pending:
-            # completion order: prefer a bucket whose buffers are ready;
-            # fall back to the oldest submission (blocking on it).
-            nxt = next((p for p in pending if _outputs_ready(p[0])),
-                       pending[0])
-            pending.remove(nxt)
-            out, key, idxs = nxt
-            self._collect(out, key[0], idxs, lps, results)
-            self._bucket_served(key, idxs, out)
-        stats["collect_s"] = time.perf_counter() - t0
-        self._gather_remote(remote, lps, results, stats)
+        with jax.profiler.TraceAnnotation(STREAM_SPAN,
+                                          instances=len(lps)) as root:
+            with _phase(stats, "group"):
+                buckets = self._group_buckets(lps)
+            root.set_metadata(buckets=len(buckets))
+            mine, remote = self._route(buckets)
+            stats.update(n_buckets=len(buckets), n_local_buckets=len(mine))
+            pending = []
+            for key, idxs in mine.items():
+                (mb, nb), sig = key
+                group = [lps[i] for i in idxs]
+                out = self._dispatch_bucket(group, idxs, len(lps), mb, nb,
+                                            sig, dtype, stats)
+                if self.async_dispatch:
+                    pending.append((out, key, idxs))
+                else:
+                    self._finish((out, key, idxs), lps, results, stats)
+            while pending:
+                # completion order: prefer a bucket whose buffers are
+                # ready; fall back to the oldest submission (blocking on
+                # it).
+                nxt = next((p for p in pending if _outputs_ready(p[0])),
+                           pending[0])
+                pending.remove(nxt)
+                self._finish(nxt, lps, results, stats)
+            self._gather_remote(remote, lps, results, stats)
         stats["compiles"] = (sanitize.compile_counts()["compiles"]
                              - compiles0)
         self.last_stream_stats = stats
         return results  # type: ignore[return-value]
+
+    def _finish(self, submitted, lps, results, stats) -> None:
+        """Wait for one dispatched bucket, then collect its results (the
+        ``wait`` and ``collect`` phases)."""
+        out, key, idxs = submitted
+        tag = bucket_tag(key)
+        with _phase(stats, "wait", bucket=tag):
+            jax.block_until_ready(out)
+        with _phase(stats, "collect", bucket=tag):
+            self._collect(out, key[0], idxs, lps, results)
+            self._bucket_served(key, idxs, out)
 
 
 def solve_stream(lps: Sequence[StandardLP],

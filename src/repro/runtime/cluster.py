@@ -49,7 +49,8 @@ import numpy as np
 
 from ..distributed.fault import SolverCheckpoint, load_checkpoint, \
     save_checkpoint
-from .batch import BatchSolver, nnz_bucket  # noqa: F401  (re-export)
+from .batch import BatchSolver, bucket_tag
+from .batch import nnz_bucket  # noqa: F401  (re-export)
 
 # env vars describing the cluster (REPRO_* preferred; the JAX_* spellings
 # some launchers export are honored as fallbacks)
@@ -171,18 +172,6 @@ def _reset_for_tests() -> None:
 
 
 # ------------------------------------------------------------- routing ---
-
-def bucket_tag(key: BucketKey) -> str:
-    """Stable string id of a bucket key (filenames, routing tables)."""
-    (mb, nb), sig = key
-    if sig is None:
-        kind = "dense"
-    elif isinstance(sig, tuple):            # ("ell", wf, wa)
-        kind = f"ell{sig[1]}x{sig[2]}"
-    else:                                   # bare int nnz bucket
-        kind = f"nnz{sig}"
-    return f"{mb}x{nb}-{kind}"
-
 
 def bucket_cost(key: BucketKey, queue_depth: int) -> int:
     """Deterministic serving cost: padded FLOPs per MVM x queue depth.

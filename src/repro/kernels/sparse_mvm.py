@@ -1,4 +1,5 @@
-"""ELL sparse MVM (an XLA gather) + host-side COO->ELL conversion.
+"""ELL sparse MVM (an XLA gather), host-side COO->ELL conversion, and
+the rule that turns a small ELL bucket into a dense operator.
 
 The sparse COO path is memory-optimal but loses on wall clock: every
 MVM is a scatter-add over (nnz,) gathers, which XLA CPU serializes, and
@@ -18,9 +19,15 @@ with no scatter anywhere.  Padding entries carry data == 0, so whatever
 exactly the inertness contract of ``stack_problems_sparse``'s (0, 0)
 padding.
 
-One execution path on every backend: ``ell_matvec`` is a plain XLA
-gather and axis-1 sum.  (A row-blocked Pallas form of the same gather
-does not compile for TPU: Mosaic accepts only 2-D gathers.)
+``ell_matvec`` is a plain XLA gather and axis-1 sum on every backend.
+(A row-blocked Pallas form of the same gather does not compile for TPU:
+Mosaic accepts only 2-D gathers.)  On a TPU that gather moves about
+0.1 G slots/s, three orders of magnitude below a dense matvec, so the
+ELL bucket program (``runtime.batch.make_ell_bucket_pipeline``) keeps
+the gather only where the dense form is too large: ``ell_goes_dense``
+decides from the bucket's static shapes, and a bucket it sends dense is
+scattered once per solve into its (m, n) K (``ell_to_dense``) and
+multiplied on the MXU from then on.
 """
 from __future__ import annotations
 
@@ -31,6 +38,16 @@ import numpy as np
 
 # Smallest ELL width bucket (power-of-two bucketing, like nnz_bucket).
 MIN_ELL_WIDTH = 4
+#: Dense elements one ELL slot is worth at equal time, by operand
+#: itemsize: a bucket's dense form wins while m*n <= R * (m*wf + n*wa).
+#: Below the break-even ``tools/ell_crossover.py`` measured on a TPU v5e
+#: from 256x512 up (float32 191-739, float64 11.5-24.8; PERF.md).  Below
+#: that size both forms cost a launch's fixed time, and dense is ahead.
+DENSE_ELEMENTS_PER_SLOT = {4: 64, 8: 8}
+#: Largest dense bucket operator, B*m*n*itemsize bytes: 1/32 of a v5e's
+#: 16 GiB of HBM.  The compiled bucket program holds about 3x that in
+#: float32 temporaries and 17x in float64, whose products are emulated.
+DENSE_OPERATOR_MAX_BYTES = 512 << 20
 
 
 # ------------------------------------------------------ host conversion ---
@@ -89,6 +106,27 @@ def ell_from_coo(data, row, col, shape: Tuple[int, int],
         ell_data[row, pos] = data
         ell_cols[row, pos] = col
     return ell_data, ell_cols
+
+
+# ------------------------------------------------------- operator choice ---
+
+def ell_goes_dense(B: int, m: int, n: int, wf: int, wa: int,
+                   itemsize: int) -> bool:
+    """Whether an ELL bucket of ``B`` lanes, shape (m, n) and width
+    buckets (wf, wa) multiplies by a dense K instead of gathering: its
+    dense form is cheaper per iteration than its slots and fits under
+    ``DENSE_OPERATOR_MAX_BYTES``.  A pure function of static shapes, so
+    every process decides alike and the executable cache key holds it."""
+    r = DENSE_ELEMENTS_PER_SLOT.get(int(itemsize), 0)
+    return (m * n <= r * (m * wf + n * wa)
+            and B * m * n * itemsize <= DENSE_OPERATOR_MAX_BYTES)
+
+
+def ell_to_dense(data, cols, n: int):
+    """The dense (m, n) matrix of one ELL layout: one scatter-add, in
+    which padding slots (data 0) add nothing."""
+    rows = jnp.arange(data.shape[0])[:, None]
+    return jnp.zeros((data.shape[0], n), data.dtype).at[rows, cols].add(data)
 
 
 # ----------------------------------------------------------------- matvec ---

@@ -81,8 +81,10 @@ def main(argv=None):
                     help="with --backend batch: serve the stream through "
                          "the sparse COO pipeline (instances loaded as "
                          "sprand: specs are sparse already; dense specs "
-                         "are converted).  Memory is proportional to "
-                         "nonzeros — no dense (B, m, n) stack exists")
+                         "are converted).  Host memory is proportional "
+                         "to nonzeros — no dense (B, m, n) stack exists "
+                         "on the host; small buckets multiply by a dense "
+                         "K built on the device")
     ap.add_argument("--sync", action="store_true",
                     help="with --backend batch: block per bucket instead "
                          "of the default submit-all-then-collect async "

@@ -32,13 +32,15 @@ Past toy sizes, two more concerns take over (ROADMAP item 2):
     ``PDHGOptions.sparse_kernel``.  The default ``"ell"`` backend
     converts COO to ELL — forward (B, m, Wf) AND adjoint
     (B, n, Wa) layouts, widths power-of-two bucketed like ``nnz_bucket``
-    — so Ruiz equilibration, Pock–Chambolle diagonals, Lanczos and both
-    solve MVMs are gathers + axis-1 reductions with no scatter anywhere
-    (the wall-clock path; ``kernels.sparse_mvm``).  ``"bcoo"`` keeps the
-    nnz-proportional COO stacking ((B, nnz) data + (B, nnz, 2) indices,
-    ``engine.sparse_operator`` scatter contractions) — the
-    memory-optimal path.  Neither ever materializes a dense
-    (B, m_pad, n_pad) stack.
+    — so Ruiz equilibration and Pock–Chambolle diagonals are axis-1
+    reductions with no scatter; a bucket whose dense form is cheap
+    (``kernels.sparse_mvm.ell_goes_dense``) is scattered once per solve
+    into a dense K on the device and multiplied on the MXU, any other
+    runs Lanczos and both solve MVMs as ELL gathers (the wall-clock
+    path).  ``"bcoo"`` keeps the nnz-proportional COO stacking
+    ((B, nnz) data + (B, nnz, 2) indices, ``engine.sparse_operator``
+    scatter contractions) — the memory-optimal path.  Neither ever
+    materializes a dense (B, m_pad, n_pad) stack on the host.
   * **Async serving.**  ``solve_stream`` submits EVERY bucket to its
     compiled executable first (JAX dispatch is asynchronous; the host
     never blocks between buckets) and only then collects results,
@@ -74,7 +76,9 @@ from ..core.pdhg import opts_static  # noqa: F401  (canonical home; re-export)
 from ..kernels.sparse_mvm import (
     coo_row_widths,
     ell_from_coo,
+    ell_goes_dense,
     ell_matvec,
+    ell_to_dense,
     ell_width_bucket,
 )
 from ..lp.problem import SparseCOO, StandardLP
@@ -525,55 +529,85 @@ def make_ell_bucket_pipeline(opts: PDHGOptions, sigma_read: float = 0.0,
                              norm_seeded: bool = False):
     """vmapped ELL prep + solve over a stacked ELL bucket.
 
-    Inputs are the ``stack_problems_ell`` layout plus per-instance keys.
-    The operator-norm estimate runs a matvec-only Lanczos with two ELL
-    gathers per iteration; the solve mounts ``engine.sparse_ell_operator``
-    (which has no fused window: ``opts.megakernel`` is refused).  Like
-    the COO pipeline, no
-    dense (m, n) array ever exists on host or device — but unlike it,
-    no iteration-path op is a scatter, which is what makes sparse win
-    on wall clock and not just memory.  Returns an extra trailing
-    ``rhos`` (raw per-instance norm estimates) like
-    ``make_bucket_pipeline``; ``norm_seeded`` swaps the full estimate
-    for the short cached-seed refinement.
+    Inputs are the ``stack_problems_ell`` layout plus per-instance keys;
+    prep (Ruiz, Pock-Chambolle diagonals) runs on the ELL values.  The
+    operator the solve multiplies by follows ``ell_goes_dense``, from
+    the bucket's static shapes:
+
+      * dense: the scaled forward ELL is scattered into an (m, n) K on
+        the device, once per solve, and every product after it (two per
+        norm-estimate step on Keff, the window, the check) is a dense
+        ``symblock.mv`` on that K, through ``engine.dense_operator``
+        like ``make_bucket_pipeline``'s;
+      * gather: two ELL gathers per norm-estimate step, and the solve
+        mounts ``engine.sparse_ell_operator``.
+
+    Either way no dense array exists on the host, and no iteration-path
+    op is a scatter.  ``opts.megakernel`` is refused on both.  Returns
+    an extra trailing ``rhos`` (raw per-instance norm estimates) like
+    ``make_bucket_pipeline``; ``norm_seeded`` swaps the full estimate for
+    the short cached-seed refinement.
     """
+    if opts.megakernel:
+        raise ValueError(
+            "megakernel=True fuses the noiseless dense operator only; "
+            "ELL buckets never mount it — set PDHGOptions.megakernel=False")
     static = opts_static(opts, sigma_read)
     _check_norm_backend(opts)
 
-    def one(df, cf, da, ca, b, c, lb, ub, key, rho_seed=None):
+    def one(df, cf, da, ca, b, c, lb, ub, key, rho_seed=None, *, dense):
+        from ..core.symblock import mv
+
         m, n = b.shape[0], c.shape[0]
         (sf, sa, bs, cs, lbs, ubs, T, Sigma, D1, D2) = _prep_one_ell(
             df, cf, da, ca, b, c, lb, ub, opts)
+        if dense:
+            with jax.named_scope(engine.PREP_SCOPE):
+                Ks = ell_to_dense(sf, cf, n)
         if opts.norm_override is not None:
             rho_raw = jnp.asarray(opts.norm_override, df.dtype)
             rho = rho_raw
         else:
             with jax.named_scope(engine.NORM_SCOPE):
                 rtS, rtT = jnp.sqrt(Sigma), jnp.sqrt(T)
-                deff_f = sf * rtS[:, None] * rtT[cf]
-                deff_a = sa * rtT[:, None] * rtS[ca]
+                if dense:
+                    Keff = rtS[:, None] * Ks * rtT[None, :]
+                    fwd = functools.partial(mv, Keff)
+                    adj = functools.partial(mv, Keff.T)
+                else:
+                    deff_f = sf * rtS[:, None] * rtT[cf]
+                    deff_a = sa * rtT[:, None] * rtS[ca]
+                    fwd = functools.partial(ell_matvec, deff_f, cf)
+                    adj = functools.partial(ell_matvec, deff_a, ca)
 
-                def mv(v):     # symmetric block M' of Keff, matvec-only
-                    top = ell_matvec(deff_f, cf, v[m:])
-                    bot = ell_matvec(deff_a, ca, v[:m])
-                    return jnp.concatenate([top, bot])
+                def sym_mv(v):  # symmetric block M' of Keff, matvec-only
+                    return jnp.concatenate([fwd(v[m:]), adj(v[:m])])
 
-                rho_raw = _estimate_norm_mv(mv, m + n, df.dtype, opts,
+                rho_raw = _estimate_norm_mv(sym_mv, m + n, df.dtype, opts,
                                             rho_seed)
                 rho = engine.lemma2_margin(rho_raw, sigma_read)
-        op = engine.sparse_ell_operator(sf, cf, sa, ca, sigma_read)
-        x, y, it, merit = engine.solve_core(
-            None, None, bs, cs, lbs, ubs, T, Sigma, rho, key, static,
-            operator=op)
+        if dense:
+            x, y, it, merit = _single_solve(Ks, bs, cs, lbs, ubs, T, Sigma,
+                                            rho, key, static)
+        else:
+            op = engine.sparse_ell_operator(sf, cf, sa, ca, sigma_read)
+            x, y, it, merit = engine.solve_core(
+                None, None, bs, cs, lbs, ubs, T, Sigma, rho, key, static,
+                operator=op)
         return D2 * x, D1 * y, it, merit, rho_raw
+
+    def _run(df, cf, da, ca, *rest):
+        (B, m, wf), (n, wa) = df.shape, da.shape[1:]
+        dense = ell_goes_dense(B, m, n, wf, wa, df.dtype.itemsize)
+        return jax.vmap(functools.partial(one, dense=dense))(
+            df, cf, da, ca, *rest)
 
     if norm_seeded:
         def pipeline(df, cf, da, ca, bs, cs, lbs, ubs, keys, rho_seeds):
-            return jax.vmap(one)(df, cf, da, ca, bs, cs, lbs, ubs, keys,
-                                 rho_seeds)
+            return _run(df, cf, da, ca, bs, cs, lbs, ubs, keys, rho_seeds)
     else:
         def pipeline(df, cf, da, ca, bs, cs, lbs, ubs, keys):
-            return jax.vmap(one)(df, cf, da, ca, bs, cs, lbs, ubs, keys)
+            return _run(df, cf, da, ca, bs, cs, lbs, ubs, keys)
 
     return pipeline
 
@@ -684,6 +718,10 @@ class BatchSolver:
         stacking path materialized;
       * ``donated_buckets``, ``norm_seeded_buckets``: buckets that
         donated their operator buffer, or ran the seeded norm refinement;
+      * ``dense_operator_buckets``: ELL buckets whose program multiplies
+        by a dense K scattered on the device (``ell_goes_dense``) instead
+        of gathering; the ``repro.stream.dispatch`` span of each bucket
+        names its operator (``dense``, ``ell`` or ``bcoo``);
       * ``compiles``: XLA compilations the call triggered
         (``runtime.sanitize``; a warm pass over a bucket mix served
         before must report 0);
@@ -992,12 +1030,17 @@ class BatchSolver:
                 int_arrays = (1, 3)
                 exe_fn = functools.partial(self._executable_ell, mb, nb, wf,
                                            wa, B, dtype)
+                # the ELL program's own choice (make_ell_bucket_pipeline)
+                operator = ("dense" if ell_goes_dense(
+                    B, mb, nb, wf, wa, jnp.dtype(dtype).itemsize) else "ell")
+                stats["dense_operator_buckets"] += int(operator == "dense")
             elif sig is not None:                        # bare int nnz
                 stacked = stack_problems_sparse(group + [group[0]] * n_fill,
                                                 m=mb, n=nb, nnz=sig)
                 int_arrays = (1,)
                 exe_fn = functools.partial(self._executable_sparse, mb, nb,
                                            sig, B, dtype)
+                operator = "bcoo"
             else:
                 dense = [lp.densified() for lp in group]
                 stacked = stack_problems(dense + [dense[0]] * n_fill,
@@ -1005,6 +1048,7 @@ class BatchSolver:
                 int_arrays = ()
                 exe_fn = functools.partial(self._executable, mb, nb, B,
                                            dtype)
+                operator = "dense"
             nbytes = sum(a.nbytes for a in stacked)
             stats["sparse_stack_bytes" if sig is not None
                   else "dense_stack_bytes"] += nbytes
@@ -1052,7 +1096,7 @@ class BatchSolver:
         stats["donated_buckets"] += int(donate)
         call_args = ((*arrays, keys, rho_seeds) if seeded
                      else (*arrays, keys))
-        with _phase(stats, "dispatch", bucket=tag):
+        with _phase(stats, "dispatch", bucket=tag, operator=operator):
             if self.transfer_sanitize:
                 # inputs are on device by now (the upload above is the
                 # one sanctioned transfer); anything implicit past this
@@ -1123,7 +1167,8 @@ class BatchSolver:
         self._seeded_idxs = set()
         stats = {f"{p}_s": 0.0 for p in STREAM_PHASES}
         stats.update(dense_stack_bytes=0, sparse_stack_bytes=0,
-                     donated_buckets=0, norm_seeded_buckets=0, compiles=0)
+                     donated_buckets=0, norm_seeded_buckets=0,
+                     dense_operator_buckets=0, compiles=0)
         compiles0 = sanitize.compile_counts()["compiles"]
         with jax.profiler.TraceAnnotation(STREAM_SPAN,
                                           instances=len(lps)) as root:

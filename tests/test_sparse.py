@@ -27,6 +27,22 @@ from repro.runtime.batch import (
 OPTS = PDHGOptions(max_iters=20000, tol=1e-5, check_every=64)
 
 
+@pytest.fixture(params=["dense", "gather"])
+def ell_operator(request, monkeypatch):
+    """The operator every ELL bucket multiplies by, forced through the
+    rule's constant: a dense K (no ratio of shapes stops it), or the
+    gather (no dtype goes dense)."""
+    from repro.kernels import sparse_mvm
+
+    ratio = {4: np.inf, 8: np.inf} if request.param == "dense" else {}
+    monkeypatch.setattr(sparse_mvm, "DENSE_ELEMENTS_PER_SLOT", ratio)
+    return request.param
+
+
+def _dense_buckets(solver):
+    return solver.last_stream_stats["dense_operator_buckets"]
+
+
 # ------------------------------------------------------------ SparseCOO ---
 
 def test_sparse_coo_matvec_and_transpose_match_dense(rng):
@@ -208,12 +224,17 @@ def test_sparse_stream_host_memory_at_least_4x_smaller(x64):
     assert mem_dense >= 4 * mem_sparse, (mem_dense, mem_sparse)
 
 
-def test_sparse_stream_matches_dense_stream(x64):
+def test_sparse_stream_matches_dense_stream(x64, ell_operator):
     """Sparse pipeline vs densified dense pipeline on the same stream:
-    same iteration counts and matching objectives (sigma_read=0)."""
+    same iteration counts and matching objectives (sigma_read=0), with
+    the ELL buckets on either operator."""
     lps = sparse_lp_stream(3, density=0.05, seed=0)
     opts = PDHGOptions(max_iters=4000, tol=1e-5, check_every=64)
-    rs = BatchSolver(opts).solve_stream(lps)
+    sparse = BatchSolver(opts)
+    rs = sparse.solve_stream(lps)
+    n_buckets = sparse.last_stream_stats["n_buckets"]
+    assert _dense_buckets(sparse) == (n_buckets if ell_operator == "dense"
+                                      else 0)
     rd = BatchSolver(opts).solve_stream([lp.densified() for lp in lps])
     for a, d in zip(rs, rd):
         assert a.iterations == d.iterations, (a.name, a.iterations,
@@ -398,12 +419,16 @@ def test_stack_problems_ell_layout(x64):
         np.testing.assert_allclose(got_a, K.T @ w, rtol=1e-12, atol=1e-12)
 
 
-def test_ell_and_bcoo_stream_parity(x64):
+def test_ell_and_bcoo_stream_parity(x64, ell_operator):
     """The acceptance contract of the kernel swap: at sigma_read=0 the
-    ELL pipeline and the BCOO pipeline serve the SAME stream to the same
-    iterates (fp tolerance) with identical iteration counts."""
+    ELL pipeline, on either operator, and the BCOO pipeline serve the
+    SAME stream to the same iterates (fp tolerance) with identical
+    iteration counts."""
     lps = sparse_lp_stream(6, density=0.08, seed=3)
-    r_ell = BatchSolver(OPTS).solve_stream(lps)            # default = ELL
+    ell = BatchSolver(OPTS)                                # default = ELL
+    r_ell = ell.solve_stream(lps)
+    assert _dense_buckets(ell) == (ell.last_stream_stats["n_buckets"]
+                                   if ell_operator == "dense" else 0)
     r_bcoo = BatchSolver(dataclasses.replace(
         OPTS, sparse_kernel="bcoo")).solve_stream(lps)
     for re_, rb in zip(r_ell, r_bcoo):
@@ -439,10 +464,11 @@ def test_ell_bucket_signature_carries_both_widths(x64):
     assert isinstance(bcoo._sparse_signature(lo), int)
 
 
-def test_degenerate_zero_nnz_instances_serve_cleanly(x64):
-    """An all-zero K (nnz=0) must flow through BOTH sparse backends —
-    width/nnz bucketing, stacking, preconditioning, solve — without NaNs
-    (rho=0 is guarded) and land on the box optimum."""
+def test_degenerate_zero_nnz_instances_serve_cleanly(x64, ell_operator):
+    """An all-zero K (nnz=0) must flow through BOTH sparse backends, and
+    the ELL one on either operator — width/nnz bucketing, stacking,
+    preconditioning, solve — without NaNs (rho=0 is guarded) and land on
+    the box optimum."""
     from repro.kernels.sparse_mvm import ell_from_coo
     from repro.runtime.batch import stack_problems_ell
 
@@ -456,8 +482,10 @@ def test_degenerate_zero_nnz_instances_serve_cleanly(x64):
 
     opts = dataclasses.replace(OPTS, max_iters=2000)
     for kernel in ("ell", "bcoo"):
-        r = BatchSolver(dataclasses.replace(
-            opts, sparse_kernel=kernel)).solve_stream([zk])[0]
+        solver = BatchSolver(dataclasses.replace(opts, sparse_kernel=kernel))
+        r = solver.solve_stream([zk])[0]
+        assert _dense_buckets(solver) == int(kernel == "ell"
+                                             and ell_operator == "dense")
         assert np.all(np.isfinite(r.x)) and np.all(np.isfinite(r.y))
         assert r.status in ("optimal", "iteration_limit")
         np.testing.assert_allclose(r.x, np.zeros(10), atol=1e-4)
@@ -466,3 +494,114 @@ def test_degenerate_zero_nnz_instances_serve_cleanly(x64):
     healthy = sparse_lp_stream(3, [(6, 10)], density=0.3, seed=9)
     results = BatchSolver(opts).solve_stream([zk] + healthy)
     assert all(np.all(np.isfinite(r.x)) for r in results)
+
+
+# ------------------------------------- the ELL bucket's operator choice ---
+
+# (lanes, m_pad, n_pad, wf, wa) of every ELL bucket the benchmark's
+# Table-1 rounds build (table1-coo traffic)
+TABLE1_ELL_BUCKETS = [(2, 32, 64, 32, 32), (1, 32, 64, 64, 32),
+                      (1, 32, 128, 64, 32), (1, 64, 128, 32, 64),
+                      (1, 256, 512, 32, 32), (1, 512, 1024, 64, 64)]
+
+
+def _ell_bucket(spec, lanes):
+    """(lanes, m_pad, n_pad, wf, wa) of the bucket a ``sprand:MxN:d``
+    instance lands in."""
+    m, n, density = spec
+    lp = sparse_random_standard_lp(m, n, density=density, seed=0)
+    solver = BatchSolver(OPTS)
+    (mb, nb), (_, wf, wa) = solver._bucket(m, n), solver._sparse_signature(lp)
+    return lanes, mb, nb, wf, wa
+
+
+@pytest.mark.parametrize("bucket", TABLE1_ELL_BUCKETS)
+def test_table1_ell_buckets_go_dense_in_float32(bucket):
+    from repro.kernels.sparse_mvm import ell_goes_dense
+
+    assert ell_goes_dense(*bucket, np.dtype(np.float32).itemsize)
+
+
+@pytest.mark.parametrize("itemsize", [4, 8])
+def test_large_ell_buckets_keep_the_gather(itemsize):
+    """The paper's sparse class, 65536x131072 at 1.5e-4 (34 GB dense in
+    float32), stays on the gather, and so does a bucket that is dense
+    enough but past the byte cap."""
+    from repro.kernels.sparse_mvm import (DENSE_OPERATOR_MAX_BYTES,
+                                          ell_goes_dense)
+
+    a5 = _ell_bucket((65536, 131072, 1.5e-4), 8)
+    assert a5[3:] == (64, 32)
+    assert not ell_goes_dense(*a5, itemsize)
+    # half as many slots as dense elements: dense on shape, and one
+    # lane fits, but enough lanes overflow the cap
+    m, n, w = 1024, 2048, 256
+    one = 1 * m * n * itemsize
+    assert one <= DENSE_OPERATOR_MAX_BYTES
+    assert ell_goes_dense(1, m, n, w, w, itemsize)
+    lanes = DENSE_OPERATOR_MAX_BYTES // one + 1
+    assert not ell_goes_dense(lanes, m, n, w, w, itemsize)
+
+
+def test_chip_smoke_sparse_bucket_keeps_the_gather():
+    """``chip_smoke.py``'s sparse phase, 8 x 4096x8192 at 2.4e-3 in the
+    library's default float64: 64 dense elements a slot, past what an
+    emulated float64 product is worth, so it gathers (PERF.md)."""
+    from repro.kernels.sparse_mvm import ell_goes_dense
+
+    bucket = _ell_bucket((4096, 8192, 2.4e-3), 8)
+    assert bucket == (8, 4096, 8192, 64, 32)
+    assert not ell_goes_dense(*bucket, np.dtype(np.float64).itemsize)
+
+
+def _while_body_ops(hlo: str):
+    """Instruction lines of every computation a ``while`` body of the
+    HLO module reaches (fusions, nested loops and calls included)."""
+    import re
+
+    comps, cur = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"^(?:ENTRY )?%(\S+) .*\{$", line)
+        if head:
+            cur = comps.setdefault(head.group(1), [])
+        elif cur is not None:
+            cur.append(line)
+    callee = re.compile(r"(?:calls|body|condition|to_apply)=%([\w.\-]+)")
+    todo = [m.group(1) for lines in comps.values() for line in lines
+            if " while(" in line
+            for m in re.finditer(r"body=%([\w.\-]+)", line)]
+    seen = set()
+    while todo:
+        name = todo.pop()
+        if name not in seen:
+            seen.add(name)
+            todo += [m.group(1) for line in comps[name]
+                     for m in callee.finditer(line)]
+    return [line for name in seen for line in comps[name]]
+
+
+def test_small_ell_bucket_program_has_no_gather_in_its_loops(ell_operator):
+    """A small float32 ELL bucket compiled for the CPU: on the dense
+    operator no ``while`` body gathers (the ELL prep's gathers run once,
+    before the loops), on the gather they do; either way every f32
+    product asks for HIGHEST."""
+    import re
+
+    lp = sparse_random_standard_lp(20, 34, density=0.2, seed=0)
+    f32 = lambda a: np.asarray(a, np.float32)  # noqa: E731
+    lp = dataclasses.replace(
+        lp, K=SparseCOO(f32(lp.K.data), lp.K.row, lp.K.col, lp.K.shape),
+        b=f32(lp.b), c=f32(lp.c), lb=f32(lp.lb), ub=f32(lp.ub))
+    solver = BatchSolver(PDHGOptions(max_iters=128, tol=1e-30,
+                                     check_every=64, lanczos_iters=8,
+                                     dtype=np.float32))
+    solver.solve_stream([lp])
+    hlo, = solver.hlo_texts()
+    body = _while_body_ops(hlo)
+    assert body
+    gathers = [line for line in body if re.search(r"\bgather\(", line)]
+    assert bool(gathers) == (ell_operator == "gather")
+    dots = [line for line in hlo.splitlines()
+            if re.search(r"= f32\[[^]]*\]\S* (dot|convolution)\(", line)]
+    assert dots and all("operand_precision={highest,highest}" in line
+               for line in dots)

@@ -125,3 +125,30 @@ def test_bucket_programs_carry_device_scopes(stream):
             " ".join(re.findall(r'op_name="([^"]*)"', text)))}
         assert scopes == {engine.PREP_SCOPE, engine.NORM_SCOPE,
                           engine.WINDOW_SCOPE, engine.CHECK_SCOPE}
+
+
+def test_dispatch_spans_name_each_bucket_operator(x64, tmp_path,
+                                                  monkeypatch):
+    """In a stream of dense and ELL buckets, ``dense_operator_buckets``
+    counts the ELL buckets the rule sends dense, and every dispatch span
+    names the operator its bucket's program multiplies by."""
+    from repro.kernels import sparse_mvm
+
+    # the byte cap between the two smaller ELL buckets and the largest
+    monkeypatch.setattr(sparse_mvm, "DENSE_OPERATOR_MAX_BYTES", 16 * 32 * 8)
+    lps = _lps("dense") + _lps("ell")
+    solver = BatchSolver(OPTS)
+    expected = {}
+    for key, idxs in solver._group_buckets(lps).items():
+        (mb, nb), sig = key
+        dense = sig is None or sparse_mvm.ell_goes_dense(
+            solver._padded_batch(len(idxs)), mb, nb, sig[1], sig[2], 8)
+        expected[bucket_tag(key)] = (sig is not None, "dense" if dense
+                                     else "ell")
+    assert sorted(v for v in expected.values() if v[0]) == [
+        (True, "dense"), (True, "dense"), (True, "ell")]
+    _, stats, spans = _traced_call(solver, lps, str(tmp_path))
+    assert stats["dense_operator_buckets"] == 2
+    assert {s[3]["bucket"]: s[3]["operator"]
+            for s in _named(spans, "dispatch")} == {
+        tag: op for tag, (_, op) in expected.items()}

@@ -100,6 +100,34 @@ def test_ell_matvec_compiles_as_xla_gather(one_chip, x64, dtype):
     assert "gather" in text and "tpu_custom_call" not in text
 
 
+def test_small_ell_bucket_compiles_to_a_dense_k(one_chip, x64):
+    """neos5's float32 ELL bucket of the Table-1 set, (512, 1024) at
+    widths (64, 64), compiles for the chip with the values scattered
+    into a dense K once, and any f32 product the compiler keeps (a
+    one-lane matvec becomes a multiply-reduce) at HIGHEST."""
+    import re
+
+    from repro.core import PDHGOptions
+    from repro.runtime.batch import make_ell_bucket_pipeline
+
+    B, m, n, wf, wa = 1, 512, 1024, 64, 64
+    sd = lambda s, d=jnp.float32: jax.ShapeDtypeStruct(  # noqa: E731
+        s, d, sharding=one_chip)
+    key = jax.random.PRNGKey(0)
+    exe = _compile(
+        make_ell_bucket_pipeline(PDHGOptions(dtype=np.float32,
+                                             check_every=100)),
+        sd((B, m, wf)), sd((B, m, wf), jnp.int32), sd((B, n, wa)),
+        sd((B, n, wa), jnp.int32), sd((B, m)), sd((B, n)), sd((B, n)),
+        sd((B, n)), sd((B, *key.shape), key.dtype))
+    text = exe.as_text()
+    assert len(re.findall(r"\bscatter\(", text)) == 1
+    dots = [line for line in text.splitlines()
+            if re.search(r"= f32\[[^]]*\]\S* (dot|convolution)\(", line)]
+    assert all("operand_precision={highest,highest}" in line
+               for line in dots)
+
+
 def test_compiled_kernels_refuse_f64(x64):
     """f64 into a compiled Pallas kernel is refused at mount time, by
     name of the option that sets the solve dtype."""

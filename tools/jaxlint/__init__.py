@@ -159,7 +159,7 @@ class Config:
           "power_iteration_mv")),
         ("repro/kernels/ops.py",
          ("crossbar_mvm", "primal_update", "dual_update")),
-        ("repro/kernels/sparse_mvm.py", ("ell_matvec",)),
+        ("repro/kernels/sparse_mvm.py", ("ell_matvec", "ell_to_dense")),
         ("repro/kernels/pdhg_megakernel.py",
          ("fused_dense_steps", "_run_steps")),
         ("repro/kernels/ref.py",

@@ -77,6 +77,9 @@ PREP_SCOPE = "repro.prep"      # Ruiz + diagonal preconditioning
 NORM_SCOPE = "repro.norm"      # Keff / symmetric block + norm estimate
 WINDOW_SCOPE = "repro.window"  # the check_every PDHG steps of one body
 CHECK_SCOPE = "repro.check"    # residual MVMs, restart, step adaptation
+ENCODE_SCOPE = "repro.encode"  # crossbar: tile-padded M, its programming
+REFINE_SCOPE = "repro.refine"  # crossbar: a refinement round's digital
+#                                residuals, rescaling and adoption
 
 
 # ---------------------------------------------------------------- state ---

@@ -101,11 +101,16 @@ class PDHGOptions:
     #                                (shifted b/c only — zero extra write
     #                                cycles), recovering digital-grade
     #                                accuracy from noisy analog reads
-    refine_tol: float = 0.0        # stop adopting corrections once the
-    #                                exact (digital) KKT merit is at or
-    #                                below this — avoids pumping read
-    #                                noise back into a converged iterate
-    #                                (0.0 = refine for all rounds)
+    refine_tol: float = 0.0        # with refine_rounds > 0, the
+    #                                answer's target: status is
+    #                                "optimal" only if the exact (digital)
+    #                                KKT merit is at or below it, and
+    #                                corrections stop being adopted once
+    #                                it is met (no read noise pumped back
+    #                                into a converged iterate); ``tol`` is
+    #                                then the inner stop of each analog
+    #                                solve.  0.0 = refine for all rounds,
+    #                                with ``tol`` as the target
 
 
 @dataclasses.dataclass

@@ -34,6 +34,17 @@ MODE_ATY = "AT@y"
 MVM_PRECISION = jax.lax.Precision.HIGHEST
 
 
+def kernel_precision():
+    """``MVM_PRECISION`` as a Mosaic kernel can lower it, read when the
+    kernel is traced (so a change of ``MVM_PRECISION`` reaches compiled
+    kernels too): Mosaic contracts at float32 (``HIGHEST``) or in one
+    bfloat16 pass (``DEFAULT``), so anything below ``HIGHEST`` is the
+    one pass."""
+    if MVM_PRECISION == jax.lax.Precision.HIGHEST:
+        return jax.lax.Precision.HIGHEST
+    return jax.lax.Precision.DEFAULT
+
+
 def mv(A, v) -> jnp.ndarray:
     """``A @ v`` at ``MVM_PRECISION``."""
     return jnp.matmul(A, v, precision=MVM_PRECISION)

@@ -99,35 +99,39 @@ def refined_core(K_dig_fwd, K_dig_adj, K_fwd, K_adj, b, c, lb, ub, T,
         return x, y, jnp.reshape(it0, (1,)), merit0
 
     its = [it0]
-    Kx = mv(K_dig_fwd, x)
-    KTy = mv(K_dig_adj, y)
-    merit = digital_merit(x, y, b, c, lb, ub, Kx, KTy)
+    with jax.named_scope(engine.REFINE_SCOPE):
+        Kx = mv(K_dig_fwd, x)
+        KTy = mv(K_dig_adj, y)
+        merit = digital_merit(x, y, b, c, lb, ub, Kx, KTy)
     for _ in range(rounds):
-        key, kr = jax.random.split(key)
-        rb = b - Kx
-        rc = c - KTy
-        # unit-scale the correction problem: relative analog noise means
-        # the absolute error floor of the inner solve tracks s downward
-        s = jnp.maximum(jnp.maximum(jnp.max(jnp.abs(rb)),
-                                    jnp.max(jnp.abs(rc))),
-                        jnp.asarray(_TINY, b.dtype))
+        with jax.named_scope(engine.REFINE_SCOPE):
+            key, kr = jax.random.split(key)
+            rb = b - Kx
+            rc = c - KTy
+            # unit-scale the correction problem: relative analog noise
+            # means the absolute error floor of the inner solve tracks s
+            # downward
+            s = jnp.maximum(jnp.maximum(jnp.max(jnp.abs(rb)),
+                                        jnp.max(jnp.abs(rc))),
+                            jnp.asarray(_TINY, b.dtype))
+            b_r, c_r, lb_r, ub_r = rb / s, rc / s, (lb - x) / s, (ub - x) / s
         dx, dy, it_r, _ = engine.solve_core(
-            K_fwd, K_adj, rb / s, rc / s, (lb - x) / s, (ub - x) / s,
-            T, Sigma, rho, kr, static, operator=operator,
-            x0=jnp.zeros_like(x), y0=jnp.zeros_like(y))
+            K_fwd, K_adj, b_r, c_r, lb_r, ub_r, T, Sigma, rho, kr, static,
+            operator=operator, x0=jnp.zeros_like(x), y0=jnp.zeros_like(y))
         its.append(it_r)
-        x_c = jnp.clip(x + s * dx, lb, ub)
-        y_c = y + s * dy
-        Kx_c = mv(K_dig_fwd, x_c)
-        KTy_c = mv(K_dig_adj, y_c)
-        merit_c = digital_merit(x_c, y_c, b, c, lb, ub, Kx_c, KTy_c)
-        # safeguarded adoption: only keep an exact improvement, and stop
-        # moving once the target tolerance is met
-        adopt = jnp.logical_and(merit_c < merit, merit > refine_tol)
-        pick = lambda cand, cur: jnp.where(adopt, cand, cur)  # noqa: E731
-        x, y = pick(x_c, x), pick(y_c, y)
-        Kx, KTy = pick(Kx_c, Kx), pick(KTy_c, KTy)
-        merit = jnp.where(adopt, merit_c, merit)
+        with jax.named_scope(engine.REFINE_SCOPE):
+            x_c = jnp.clip(x + s * dx, lb, ub)
+            y_c = y + s * dy
+            Kx_c = mv(K_dig_fwd, x_c)
+            KTy_c = mv(K_dig_adj, y_c)
+            merit_c = digital_merit(x_c, y_c, b, c, lb, ub, Kx_c, KTy_c)
+            # safeguarded adoption: only keep an exact improvement, and
+            # stop moving once the target tolerance is met
+            adopt = jnp.logical_and(merit_c < merit, merit > refine_tol)
+            x, y = jnp.where(adopt, x_c, x), jnp.where(adopt, y_c, y)
+            Kx = jnp.where(adopt, Kx_c, Kx)
+            KTy = jnp.where(adopt, KTy_c, KTy)
+            merit = jnp.where(adopt, merit_c, merit)
     return x, y, jnp.stack(its), merit
 
 
@@ -152,7 +156,11 @@ def solve_crossbar_refined(
     on the report as ``digital_mvms``).  Returns a
     ``CrossbarSolveReport``.
     """
-    from .solver import CrossbarSolveReport, _charge_reads  # deferred cycle
+    from .solver import (  # deferred cycle
+        CrossbarSolveReport,
+        _charge_reads,
+        answer_status,
+    )
 
     if key is None:
         key = jax.random.PRNGKey(opts.seed)
@@ -200,18 +208,12 @@ def solve_crossbar_refined(
         x, x, y, scaled.c, scaled.b, mv(scaled.K, x), mv(scaled.K.T, y),
         lb=scaled.lb, ub=scaled.ub)
     merit_f = float(merit)
-    if not np.isfinite(merit_f):
-        status = "diverged"
-    elif merit_f <= opts.tol:
-        status = "optimal"
-    else:
-        status = "iteration_limit"
     it_total = int(its_np.sum())
     result = pdhg_mod.PDHGResult(
-        status=status, x=x_orig, y=y_orig, obj=float(lp.c @ x_orig),
-        iterations=it_total, residuals=res, sigma_max=float(rho),
-        lanczos_iters=lanczos_mvms, mvm_calls=lanczos_mvms + pdhg_mvms,
-        merit=merit_f,
+        status=answer_status(merit_f, opts), x=x_orig, y=y_orig,
+        obj=float(lp.c @ x_orig), iterations=it_total, residuals=res,
+        sigma_max=float(rho), lanczos_iters=lanczos_mvms,
+        mvm_calls=lanczos_mvms + pdhg_mvms, merit=merit_f,
     )
     return CrossbarSolveReport(
         result=result, ledger=ledger, device=device,

@@ -69,10 +69,26 @@ class CrossbarSolveReport:
 
 def _charge_reads(ledger: Ledger, device: DeviceModel, n_mvms: int,
                   active_cells: float):
+    """Charge ``n_mvms`` analog reads: each draws read energy from every
+    active cell (``2 * pairs * fill * ecc``) and takes one read latency
+    (tiles fire in parallel)."""
     ledger.read_energy_j += (n_mvms * active_cells
                              * device.read_energy_per_cell_j)
     ledger.read_latency_s += n_mvms * device.read_latency_s
     ledger.mvm_count += n_mvms
+
+
+def answer_status(merit: float, opts: PDHGOptions) -> str:
+    """Status of a crossbar answer from its final merit.  With
+    refinement on and a ``refine_tol`` set, that is the answer's target
+    and ``merit`` is the exact digital KKT merit; ``tol`` is then only
+    the inner stop of each analog solve, which sits at the read-noise
+    floor.  Otherwise the target is ``tol``."""
+    refined = opts.refine_rounds > 0 and opts.refine_tol > 0.0
+    target = opts.refine_tol if refined else opts.tol
+    if not np.isfinite(merit):
+        return "diverged"       # NaN merit: blow-up, not a limit
+    return "optimal" if merit <= target else "iteration_limit"
 
 
 def solve_crossbar_jit(
@@ -162,27 +178,30 @@ def make_crossbar_bucket_pipeline(opts: PDHGOptions, device: DeviceModel):
         (Ks, bs, cs, lbs, ubs, T, Sigma, D1, D2) = prep_scale(
             K, b, c, lb, ub, opts)
         enc_key, solve_key = jax.random.split(key)
-        M = build_sym_block(Ks)
         m, n = K.shape
-        R, C = _array_dims(m, n, device)
-        Mp = jnp.zeros((R, C), M.dtype).at[:m + n, :m + n].set(M)
-        g_pos, g_neg, scale, nz = encode_core(
-            Mp, enc_key, device.g_levels, device.sigma_program,
-            ecc=device.ecc, ecc_decode=device.ecc_decode,
-            stuck_rate=device.stuck_rate, drift=device.drift)
-        M_prog = (g_pos - g_neg) * scale
-        K_fwd = M_prog[:m, m:m + n]
-        K_adj = M_prog[m:m + n, :m]
+        with jax.named_scope(engine.ENCODE_SCOPE):
+            M = build_sym_block(Ks)
+            R, C = _array_dims(m, n, device)
+            Mp = jnp.zeros((R, C), M.dtype).at[:m + n, :m + n].set(M)
+            g_pos, g_neg, scale, nz = encode_core(
+                Mp, enc_key, device.g_levels, device.sigma_program,
+                ecc=device.ecc, ecc_decode=device.ecc_decode,
+                stuck_rate=device.stuck_rate, drift=device.drift)
+            M_prog = (g_pos - g_neg) * scale
+            K_fwd = M_prog[:m, m:m + n]
+            K_adj = M_prog[m:m + n, :m]
         if opts.norm_override is not None:
             rho = jnp.asarray(opts.norm_override, K.dtype)
         else:
             # operator norm of the operator actually executed (Lemma 2
             # margin widened for the noisy estimate, as in solve_jit)
-            Keff = jnp.sqrt(Sigma)[:, None] * K_fwd * jnp.sqrt(T)[None, :]
-            rho = engine.lemma2_margin(
-                lanczos_svd_jit(build_sym_block(Keff),
-                                k_max=opts.lanczos_iters),
-                device.sigma_read)
+            with jax.named_scope(engine.NORM_SCOPE):
+                Keff = (jnp.sqrt(Sigma)[:, None] * K_fwd
+                        * jnp.sqrt(T)[None, :])
+                rho = engine.lemma2_margin(
+                    lanczos_svd_jit(build_sym_block(Keff),
+                                    k_max=opts.lanczos_iters),
+                    device.sigma_read)
         op = (engine.crossbar_operator(g_pos, g_neg, scale, m, n,
                                        sigma_read=device.sigma_read)
               if opts.kernel == "pallas" else None)   # None -> dense decode
@@ -217,9 +236,15 @@ class CrossbarBatchSolver(BatchSolver):
     Sparse instances densify on entry (``supports_sparse = False``): a
     crossbar programs every physical cell of its tiles regardless of the
     operator's sparsity, so there is no memory to save device-side.
+
+    ``last_stream_stats`` adds the call's ledger totals, summed over its
+    reports: ``analog_mvms`` (charged analog reads), ``digital_mvms``
+    (the refinement shell's exact products), ``cells_written``,
+    ``write_energy_j`` and ``read_energy_j``.
     """
 
     supports_sparse = False
+    dense_operator = "crossbar"
 
     def __init__(self, opts: PDHGOptions = PDHGOptions(), *,
                  device: DeviceModel = EPIRAM, mesh=None,
@@ -234,6 +259,17 @@ class CrossbarBatchSolver(BatchSolver):
 
     def _device_signature(self):
         return self.device           # frozen dataclass -> hashable
+
+    def solve_stream(self, lps: Sequence[StandardLP]
+                     ) -> List[CrossbarSolveReport]:
+        reports = super().solve_stream(lps)
+        self.last_stream_stats.update(
+            analog_mvms=sum(r.ledger.mvm_count for r in reports),
+            digital_mvms=sum(r.digital_mvms for r in reports),
+            cells_written=sum(r.ledger.cells_written for r in reports),
+            write_energy_j=sum(r.ledger.write_energy_j for r in reports),
+            read_energy_j=sum(r.ledger.read_energy_j for r in reports))
+        return reports
 
     def _make_pipeline(self):
         return make_crossbar_bucket_pipeline(self.opts, self.device)
@@ -279,14 +315,8 @@ class CrossbarBatchSolver(BatchSolver):
                 jnp.asarray(lp.c), jnp.asarray(lp.b),
                 jnp.asarray(lp.K @ x), jnp.asarray(lp.K.T @ y),
                 lb=jnp.asarray(lp.lb), ub=jnp.asarray(lp.ub))
-            if not np.isfinite(merit):
-                status = "diverged"     # NaN merit: blow-up, not a limit
-            elif merit <= self.opts.tol:
-                status = "optimal"
-            else:
-                status = "iteration_limit"
             result = PDHGResult(
-                status=status,
+                status=answer_status(merit, self.opts),
                 x=x, y=y, obj=float(lp.c @ x), iterations=it,
                 residuals=res, sigma_max=float(rhos[k]),
                 lanczos_iters=lanczos_mvms,

@@ -31,7 +31,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from ..core.symblock import MVM_PRECISION
+from ..core import symblock
 from .interpret import resolve_interpret
 from .spec import block_spec
 
@@ -54,7 +54,7 @@ def _mvm_kernel(gp_ref, gn_ref, v_ref, gain_ref, out_ref, *, n_col_tiles):
     part = jax.lax.dot_general(
         g, v_ref[...],
         dimension_numbers=(((1,), (0,)), ((), ())),
-        precision=MVM_PRECISION,
+        precision=symblock.kernel_precision(),
         preferred_element_type=jnp.promote_types(g.dtype, jnp.float32),
     )                                                  # (TR, 1)
     out_ref[...] += part.astype(out_ref.dtype)

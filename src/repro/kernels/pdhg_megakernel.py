@@ -42,7 +42,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from ..core.symblock import MVM_PRECISION
+from ..core import symblock
 from .interpret import check_kernel_dtype, resolve_interpret
 
 #: VMEM the fused window may plan for.  Calibrated by compiling the
@@ -111,7 +111,7 @@ def _dense_kernel(K_ref, Ka_ref, b_ref, c_ref, lb_ref, ub_ref, T_ref,
     def mv(M, v):
         return jax.lax.dot_general(
             M, v, dimension_numbers=(((1,), (0,)), ((), ())),
-            precision=MVM_PRECISION,
+            precision=symblock.kernel_precision(),
             preferred_element_type=acc_dt).astype(v.dtype)
 
     results = _run_steps(

@@ -139,9 +139,13 @@ def main(argv=None):
                          "cycles), recovering exact-path accuracy from "
                          "noisy analog reads")
     ap.add_argument("--refine-tol", type=float, default=0.0,
-                    help="stop adopting refinement corrections once the "
-                         "exact digital KKT merit reaches this "
-                         "(default 0 = refine for all rounds)")
+                    help="with --refine-rounds: the answer's target, "
+                         "'optimal' only once the exact digital KKT "
+                         "merit reaches it, after which corrections stop "
+                         "being adopted; --tol is then the inner stop of "
+                         "each analog solve, at the read-noise floor "
+                         "(default 0 = refine for all rounds, --tol the "
+                         "target)")
     ap.add_argument("--ecc", type=int, default=1,
                     help="crossbar backends only: k-fold differential-"
                          "pair replication with median decode — tolerates "

@@ -721,7 +721,8 @@ class BatchSolver:
       * ``dense_operator_buckets``: ELL buckets whose program multiplies
         by a dense K scattered on the device (``ell_goes_dense``) instead
         of gathering; the ``repro.stream.dispatch`` span of each bucket
-        names its operator (``dense``, ``ell`` or ``bcoo``);
+        names its operator (``dense``, ``ell``, ``bcoo``, or ``crossbar``
+        on the crossbar subclass);
       * ``compiles``: XLA compilations the call triggered
         (``runtime.sanitize``; a warm pass over a bucket mix served
         before must report 0);
@@ -756,6 +757,9 @@ class BatchSolver:
     """
 
     supports_sparse = True
+    # what a dense bucket's program multiplies by: the ``operator`` arg
+    # of its ``repro.stream.dispatch`` span
+    dense_operator = "dense"
 
     def __init__(self, opts: PDHGOptions = PDHGOptions(), *,
                  mesh=None, batch_axes: Tuple[str, ...] = ("data",),
@@ -1048,7 +1052,7 @@ class BatchSolver:
                 int_arrays = ()
                 exe_fn = functools.partial(self._executable, mb, nb, B,
                                            dtype)
-                operator = "dense"
+                operator = self.dense_operator
             nbytes = sum(a.nbytes for a in stacked)
             stats["sparse_stack_bytes" if sig is not None
                   else "dense_stack_bytes"] += nbytes

@@ -152,3 +152,28 @@ def test_dispatch_spans_name_each_bucket_operator(x64, tmp_path,
     assert {s[3]["bucket"]: s[3]["operator"]
             for s in _named(spans, "dispatch")} == {
         tag: op for tag, (_, op) in expected.items()}
+
+
+def test_crossbar_stream_names_its_operator_and_scopes(x64, tmp_path):
+    """The crossbar subclass: every dispatch span names the ``crossbar``
+    operator, its bucket programs carry the programming and refinement
+    scopes besides the engine's, and the call's ledger totals are in
+    ``last_stream_stats``."""
+    from repro.crossbar import EPIRAM, CrossbarBatchSolver
+
+    opts = PDHGOptions(max_iters=256, tol=1e-3, check_every=64,
+                       lanczos_iters=8, refine_rounds=1, refine_tol=1e-4)
+    solver = CrossbarBatchSolver(opts, device=EPIRAM)
+    _, stats, spans = _traced_call(solver, _lps("dense"), str(tmp_path))
+    assert {s[3]["operator"] for s in _named(spans, "dispatch")} == \
+        {"crossbar"}
+    for text in solver.hlo_texts():
+        scopes = {m.group(1) for m in re.finditer(
+            r'[/(](repro\.[a-z]+)[/)]',
+            " ".join(re.findall(r'op_name="([^"]*)"', text)))}
+        assert scopes == {engine.PREP_SCOPE, engine.NORM_SCOPE,
+                          engine.ENCODE_SCOPE, engine.REFINE_SCOPE,
+                          engine.WINDOW_SCOPE, engine.CHECK_SCOPE}
+    assert stats["digital_mvms"] == 3 * 4
+    assert stats["analog_mvms"] > 0 and stats["cells_written"] > 0
+    assert stats["write_energy_j"] > 0 and stats["read_energy_j"] > 0

@@ -72,6 +72,33 @@ def test_crossbar_mvm_compiles_f32_with_x64(one_chip, x64):
     assert "tpu_custom_call" in exe.as_text()
 
 
+def test_crossbar_mvm_compiles_at_the_control_precision(one_chip, x64,
+                                                        monkeypatch):
+    """``bench/control.py``'s ``high`` control lowers
+    ``symblock.MVM_PRECISION``; the compiled read follows it as the one
+    bfloat16 pass Mosaic offers below float32 (Mosaic refuses ``HIGH``
+    itself), so the control compiles and its kernel body differs."""
+    from repro.core import symblock
+
+    sd = lambda s: jax.ShapeDtypeStruct(s, jnp.float32,  # noqa: E731
+                                        sharding=one_chip)
+
+    def text():
+        jax.clear_caches()
+        return _compile(
+            lambda gp, gn, v, g: xbar.crossbar_mvm_padded(
+                gp, gn, v, g, interpret=False),
+            sd((256, 256)), sd((256, 256)), sd((256, 1)),
+            sd((256, 1))).as_text()
+
+    highest = text()
+    monkeypatch.setattr(symblock, "MVM_PRECISION", jax.lax.Precision.HIGH)
+    lowered = text()
+    assert "tpu_custom_call" in lowered
+    assert lowered != highest
+    jax.clear_caches()
+
+
 @pytest.mark.parametrize("which", ["primal", "dual"])
 def test_update_kernels_compile_f32_with_x64(one_chip, x64, which):
     col = jax.ShapeDtypeStruct((1 << 16, 1), jnp.float32, sharding=one_chip)
@@ -160,3 +187,34 @@ def test_megakernel_refuses_bucket_past_vmem(one_chip):
     assert "tpu_custom_call" in fused(512, 1024).as_text()
     with pytest.raises(ValueError, match="VMEM"):
         fused(1024, 1024)
+
+
+@pytest.mark.parametrize("mb, nb, lanes", [(448, 704, 1), (64, 128, 2)])
+def test_refined_crossbar_bucket_compiles(one_chip, x64, monkeypatch, mb,
+                                          nb, lanes):
+    """The crossbar-t1 cell's bucket program: vmapped over its lanes,
+    three refinement rounds unrolled, every read the compiled Pallas
+    kernel.  (448, 704) programs an M of 1152², (64, 128) one of 192²,
+    which each read pads to the kernel's 128 tile.  The kernels choose
+    compiled or interpreted from the default backend, which here is the
+    CPU, so the test makes them compile."""
+    from repro.core import PDHGOptions
+    from repro.crossbar import EPIRAM
+    from repro.crossbar.solver import make_crossbar_bucket_pipeline
+    from repro.kernels import interpret
+
+    monkeypatch.setattr(interpret, "interpret_default", lambda: False)
+    opts = PDHGOptions(dtype=np.float32, tol=1e-3, max_iters=40000,
+                       check_every=100, kernel="pallas", refine_rounds=3,
+                       refine_tol=1e-4)
+    sd = lambda s, d=jnp.float32: jax.ShapeDtypeStruct(  # noqa: E731
+        s, d, sharding=one_chip)
+    key = jax.random.PRNGKey(0)
+    exe = _compile(make_crossbar_bucket_pipeline(opts, EPIRAM),
+                   sd((lanes, mb, nb)), sd((lanes, mb)), sd((lanes, nb)),
+                   sd((lanes, nb)), sd((lanes, nb)),
+                   sd((lanes, *key.shape), key.dtype))
+    text = exe.as_text()
+    # four analog solves, each with two reads and two updates a step
+    # and four reads a check
+    assert text.count("tpu_custom_call") == 4 * 8

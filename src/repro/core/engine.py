@@ -230,33 +230,46 @@ def accel_operator(accel) -> Operator:
 def crossbar_operator(g_pos, g_neg, scale, m: int, n: int,
                       sigma_read: float = 0.0, interpret=None) -> Operator:
     """Differential-pair Pallas backend against the SINGLE programmed
-    symmetric block M (Algorithm 2): both MVM modes are zero-padded reads
-    of the same (R, C) conductance array, exactly the paper's access
-    pattern.  Read noise is a per-row multiplicative sample folded into
-    the kernel's output gain.  A compiled kernel refuses f64 conductances
-    here, at mount time."""
-    from ..kernels import ops  # deferred: keep core import-light
+    symmetric block M (Algorithm 2): both MVM modes read the same (R, C)
+    conductance array, exactly the paper's access pattern.  The pair is
+    padded to whole kernel tiles once, here at mount time; each product
+    then visits only the tiles of its block (``crossbar_mvm.read_windows``:
+    ``fwd`` rows [0, m) from columns [m, m+n), ``adj`` rows [m, m+n) from
+    columns [0, m)), which equals the zero-padded read of the whole pair
+    bit for bit.  Read noise is a per-row multiplicative sample over all
+    R rows, folded into the kernel's output gain.  A compiled kernel
+    refuses f64 conductances here, at mount time."""
+    from ..kernels import crossbar_mvm as xbar  # deferred: keep core light
     from ..kernels.interpret import check_kernel_dtype
+    from ..kernels.ops import pad_to
 
     check_kernel_dtype(g_pos.dtype, interpret, "the crossbar MVM kernel")
     R, C = g_pos.shape
+    gp, gn = (pad_to(pad_to(g, xbar.TILE_R, 0), xbar.TILE_C, 1)
+              for g in (g_pos, g_neg))
+    Cp = gp.shape[1]
+    fwd_window, adj_window = xbar.read_windows(m, n)
 
-    def _mvm(v_full, key):
+    def _read(v, key, window):
         if sigma_read > 0.0:
             noise = sigma_read * jnp.clip(
-                jax.random.normal(key, (R,), v_full.dtype), -4.0, 4.0)
+                jax.random.normal(key, (R,), v.dtype), -4.0, 4.0)
         else:
-            noise = jnp.zeros((R,), v_full.dtype)
-        return ops.crossbar_mvm(g_pos, g_neg, v_full, scale, noise,
-                                interpret=interpret)
+            noise = jnp.zeros((R,), v.dtype)
+        gain = pad_to((scale * (1.0 + noise)).reshape(-1, 1), xbar.TILE_R, 0)
+        rows, cols = window
+        return xbar.crossbar_mvm_padded(gp, gn, v.reshape(-1, 1), gain,
+                                        rows=rows, cols=cols,
+                                        interpret=interpret)[:, 0]
 
     def fwd(x, key=None):
-        v = jnp.zeros((C,), x.dtype).at[m:m + n].set(x)
-        return _mvm(v, key)[:m]
+        v = jnp.zeros((Cp,), x.dtype).at[m:m + n].set(x)
+        return _read(v, key, fwd_window)[:m]   # the fwd window starts at row 0
 
     def adj(y, key=None):
-        v = jnp.zeros((C,), y.dtype).at[:m].set(y)
-        return _mvm(v, key)[m:m + n]
+        v = jnp.zeros((Cp,), y.dtype).at[:m].set(y)
+        r0 = m - adj_window[0][0] * xbar.TILE_R     # row m within the window
+        return _read(v, key, adj_window)[r0:r0 + n]
 
     return Operator(fwd, adj, "crossbar")
 

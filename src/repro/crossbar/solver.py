@@ -146,6 +146,21 @@ def _array_dims(mb: int, nb: int, device: DeviceModel) -> Tuple[int, int]:
             _ceil_to(d, device.crossbar_cols))
 
 
+def read_tiles(mb: int, nb: int, device: DeviceModel) -> Tuple[int, int]:
+    """Kernel tiles that one ``fwd`` and one ``adj`` read of an (mb, nb)
+    bucket visit together (``crossbar_mvm.read_windows``), and the tiles
+    of the whole tile-padded array, which one read of the whole pair
+    visits."""
+    from ..kernels import crossbar_mvm as xbar  # deferred, as in engine
+
+    R, C = _array_dims(mb, nb, device)
+    pair = sum(rows[1] * cols[1]
+               for rows, cols in xbar.read_windows(mb, nb))
+    whole = (xbar.tiles(0, R, xbar.TILE_R)[1]
+             * xbar.tiles(0, C, xbar.TILE_C)[1])
+    return pair, whole
+
+
 def make_crossbar_bucket_pipeline(opts: PDHGOptions, device: DeviceModel):
     """vmapped prep + encode + solve over a stacked (B, m, n) bucket.
 
@@ -240,7 +255,12 @@ class CrossbarBatchSolver(BatchSolver):
     ``last_stream_stats`` adds the call's ledger totals, summed over its
     reports: ``analog_mvms`` (charged analog reads), ``digital_mvms``
     (the refinement shell's exact products), ``cells_written``,
-    ``write_energy_j`` and ``read_energy_j``.
+    ``write_energy_j`` and ``read_energy_j``; and the 128x128 kernel
+    tiles of the PDHG products (the ledger's ``pdhg_mvms``, half of
+    them ``fwd``, half ``adj``): ``xbar_tiles_read``, those the windowed
+    reads visit, and ``xbar_tiles_array``, those reads of the whole
+    array would visit.  The norm estimate runs on the decoded blocks,
+    not through the read, so its reads are in neither.
     """
 
     supports_sparse = False
@@ -263,7 +283,14 @@ class CrossbarBatchSolver(BatchSolver):
     def solve_stream(self, lps: Sequence[StandardLP]
                      ) -> List[CrossbarSolveReport]:
         reports = super().solve_stream(lps)
+        tiles_read = tiles_array = 0
+        for lp, r in zip(lps, reports):
+            pair, whole = read_tiles(*self._bucket(*lp.K.shape),
+                                     self.device)
+            tiles_read += r.pdhg_mvms // 2 * pair
+            tiles_array += r.pdhg_mvms * whole
         self.last_stream_stats.update(
+            xbar_tiles_read=tiles_read, xbar_tiles_array=tiles_array,
             analog_mvms=sum(r.ledger.mvm_count for r in reports),
             digital_mvms=sum(r.digital_mvms for r in reports),
             cells_written=sum(r.ledger.cells_written for r in reports),

@@ -22,10 +22,15 @@ Grid iteration order on TPU is row-major with the LAST axis innermost, so
 for grid (i, j) all column tiles j of a row-block i run back-to-back and
 the output block stays resident in VMEM across the accumulation — no
 HBM round-trips for partial sums.
+
+A PDHG product needs one off-diagonal block of the programmed
+M = [[0, K], [K^T, 0]]: ``read_windows`` names the row and column tiles
+each product reads, and ``crossbar_mvm_padded`` walks only those.
 """
 from __future__ import annotations
 
 import functools
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
@@ -64,29 +69,53 @@ def _mvm_kernel(gp_ref, gn_ref, v_ref, gain_ref, out_ref, *, n_col_tiles):
         out_ref[...] *= gain_ref[...]
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def crossbar_mvm_padded(g_pos, g_neg, v, gain, *,
+def tiles(lo: int, hi: int, tile: int) -> Tuple[int, int]:
+    """``(first, count)`` of the ``tile``-wide tiles that cover
+    ``[lo, hi)``."""
+    first = lo // tile
+    return first, -(-hi // tile) - first
+
+
+def read_windows(m: int, n: int):
+    """Tile windows ``(rows, cols)`` of the two products against the
+    programmed M = [[0, K], [K^T, 0]] of an (m, n) K: the forward read
+    returns rows ``[0, m)`` from an input supported on columns
+    ``[m, m+n)``, the adjoint rows ``[m, m+n)`` from columns ``[0, m)``.
+    Every other tile adds an exact zero or lands in rows nobody keeps."""
+    fwd = (tiles(0, m, TILE_R), tiles(m, m + n, TILE_C))
+    adj = (tiles(m, m + n, TILE_R), tiles(0, m, TILE_C))
+    return fwd, adj
+
+
+@functools.partial(jax.jit, static_argnames=("rows", "cols", "interpret"))
+def crossbar_mvm_padded(g_pos, g_neg, v, gain, *, rows=None, cols=None,
                         interpret: bool | None = None):
     """MVM on tile-aligned inputs: R, C multiples of (TILE_R, TILE_C).
 
-    v: (C, 1); gain: (R, 1).  Returns (R, 1).  ``interpret=None``
-    auto-detects the backend via ``kernels.interpret``.
+    v: (C, 1); gain: (R, 1).  ``rows`` and ``cols`` are static
+    ``(first, count)`` tile windows (``None``: all of them): the grid
+    visits only those tiles of the full arrays, through offsets in the
+    index maps, so nothing outside the window is copied or read.
+    Returns the window's rows, (rows count * TILE_R, 1).
+    ``interpret=None`` auto-detects the backend via
+    ``kernels.interpret``.
     """
     R, C = g_pos.shape
     assert R % TILE_R == 0 and C % TILE_C == 0, (R, C)
-    n_row_tiles = R // TILE_R
-    n_col_tiles = C // TILE_C
+    r0, n_row_tiles = rows or (0, R // TILE_R)
+    c0, n_col_tiles = cols or (0, C // TILE_C)
     kernel = functools.partial(_mvm_kernel, n_col_tiles=n_col_tiles)
     return pl.pallas_call(
         kernel,
         grid=(n_row_tiles, n_col_tiles),
         in_specs=[
-            block_spec((TILE_R, TILE_C), lambda i, j: (i, j)),   # G+
-            block_spec((TILE_R, TILE_C), lambda i, j: (i, j)),   # G-
-            block_spec((TILE_C, 1), lambda i, j: (j, 0)),        # v
-            block_spec((TILE_R, 1), lambda i, j: (i, 0)),        # gain
+            block_spec((TILE_R, TILE_C), lambda i, j: (r0 + i, c0 + j)),
+            block_spec((TILE_R, TILE_C), lambda i, j: (r0 + i, c0 + j)),
+            block_spec((TILE_C, 1), lambda i, j: (c0 + j, 0)),     # v
+            block_spec((TILE_R, 1), lambda i, j: (r0 + i, 0)),     # gain
         ],
         out_specs=block_spec((TILE_R, 1), lambda i, j: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((R, 1), g_pos.dtype),
+        out_shape=jax.ShapeDtypeStruct((n_row_tiles * TILE_R, 1),
+                                       g_pos.dtype),
         interpret=resolve_interpret(interpret),
     )(g_pos, g_neg, v, gain)

@@ -13,7 +13,8 @@ from . import crossbar_mvm as _xbar
 from . import pdhg_update as _upd
 
 
-def _pad_to(a, mult, axis):
+def pad_to(a, mult, axis):
+    """``a`` zero-padded along ``axis`` to a multiple of ``mult``."""
     size = a.shape[axis]
     target = ((size + mult - 1) // mult) * mult
     if target == size:
@@ -29,18 +30,18 @@ def crossbar_mvm(g_pos, g_neg, v, scale, noise, interpret=None):
     noise: per-row multiplicative read-noise sample, shape (R,).
     """
     R, C = g_pos.shape
-    gp = _pad_to(_pad_to(g_pos, _xbar.TILE_R, 0), _xbar.TILE_C, 1)
-    gn = _pad_to(_pad_to(g_neg, _xbar.TILE_R, 0), _xbar.TILE_C, 1)
-    vp = _pad_to(v.reshape(-1, 1), _xbar.TILE_C, 0)
+    gp = pad_to(pad_to(g_pos, _xbar.TILE_R, 0), _xbar.TILE_C, 1)
+    gn = pad_to(pad_to(g_neg, _xbar.TILE_R, 0), _xbar.TILE_C, 1)
+    vp = pad_to(v.reshape(-1, 1), _xbar.TILE_C, 0)
     gain = scale * (1.0 + noise)
-    gainp = _pad_to(gain.reshape(-1, 1), _xbar.TILE_R, 0)
+    gainp = pad_to(gain.reshape(-1, 1), _xbar.TILE_R, 0)
     out = _xbar.crossbar_mvm_padded(gp, gn, vp, gainp, interpret=interpret)
     return out[:R, 0]
 
 
 def primal_update(x, kty, c, T, lb, ub, tau, theta, interpret=None):
     n = x.shape[0]
-    cols = [_pad_to(a.reshape(-1, 1), _upd.BLOCK, 0)
+    cols = [pad_to(a.reshape(-1, 1), _upd.BLOCK, 0)
             for a in (x, kty, c, T, lb, ub)]
     tau2 = jnp.asarray(tau, x.dtype).reshape(1, 1)
     theta2 = jnp.asarray(theta, x.dtype).reshape(1, 1)
@@ -52,7 +53,7 @@ def primal_update(x, kty, c, T, lb, ub, tau, theta, interpret=None):
 
 def dual_update(y, kxbar, b, Sigma, sigma, interpret=None):
     m = y.shape[0]
-    cols = [_pad_to(a.reshape(-1, 1), _upd.BLOCK, 0)
+    cols = [pad_to(a.reshape(-1, 1), _upd.BLOCK, 0)
             for a in (y, kxbar, b, Sigma)]
     sig2 = jnp.asarray(sigma, y.dtype).reshape(1, 1)
     out = _upd.dual_update_padded(*cols, sig2, interpret=interpret)

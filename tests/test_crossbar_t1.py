@@ -11,7 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import PDHGOptions
+from repro.core import PDHGOptions, engine
 from repro.crossbar import EPIRAM, CrossbarBatchSolver, encode_core
 from repro.crossbar.refine import solve_crossbar_refined
 from repro.crossbar.solver import _array_dims
@@ -87,6 +87,57 @@ def test_read_precision_follows_the_programs_setting():
     with control.lower_precision("high"):
         assert precisions() == {"DEFAULT"}
     assert precisions() == {"HIGHEST"}
+
+
+# the Table-1 tile buckets, and one whose m starts its K^T block
+# mid-tile and whose 380-wide M leaves padding inside the array
+WINDOWS = [(64, 64), (64, 128), (192, 320), (448, 704), (130, 250)]
+
+
+def _operator_reads(m, n):
+    """Both windowed products of ``engine.crossbar_operator``, vmapped
+    over 3 lanes of planted conductances (nonzero in the zero blocks of
+    M and in the array's padding too), each beside its input, the
+    columns that input fills and the rows the product keeps."""
+    R, C = _array_dims(m, n, EPIRAM)
+    rng = np.random.default_rng([m, n])
+    gp, gn = _conductances(rng, 3, R)
+    scale = rng.uniform(0.5, 2.0, size=3).astype(np.float32)
+    keys = jax.random.split(jax.random.PRNGKey(m + n), 3)
+    for mode, cols, rows in (("fwd", slice(m, m + n), slice(0, m)),
+                             ("adj", slice(0, m), slice(m, m + n))):
+        x = rng.normal(size=(3, cols.stop - cols.start)).astype(np.float32)
+        got = jax.vmap(lambda a, b, s, v, k: getattr(
+            engine.crossbar_operator(a, b, s, m, n,
+                                     sigma_read=EPIRAM.sigma_read,
+                                     interpret=True), mode)(v, k))(
+            gp, gn, scale, x, keys)
+        noise = jax.vmap(lambda k: EPIRAM.sigma_read * jnp.clip(
+            jax.random.normal(k, (R,), jnp.float32), -4.0, 4.0))(keys)
+        v = np.zeros((3, C), np.float32)
+        v[:, cols] = x
+        yield (gp, gn, scale, noise, v, rows), np.asarray(got)
+
+
+@pytest.mark.parametrize("m, n", WINDOWS)
+def test_windowed_read_equals_the_whole_pair_read(m, n, x64):
+    """The operator's products visit only their block's tiles; each
+    equals the read of the whole pair, sliced, bit for bit, whatever the
+    skipped tiles hold."""
+    for (gp, gn, scale, noise, v, rows), got in _operator_reads(m, n):
+        whole = jax.vmap(lambda a, b, s, z, x: ops.crossbar_mvm(
+            a, b, x, s, z, interpret=True))(gp, gn, scale, noise, v)
+        np.testing.assert_array_equal(got, np.asarray(whole)[:, rows])
+
+
+@pytest.mark.parametrize("m, n", WINDOWS)
+def test_windowed_read_matches_reference(m, n, x64):
+    for (gp, gn, scale, noise, v, rows), got in _operator_reads(m, n):
+        want = xbar_reference.read(gp, gn, scale, np.asarray(noise),
+                                   v)[:, rows]
+        err = np.linalg.norm(got.astype(np.float64) - want) / \
+            np.linalg.norm(want)
+        assert err <= 1e-6, err
 
 
 # ------------------------------------------------------------ programming ---
@@ -199,6 +250,26 @@ def test_refined_answer_above_refine_tol_is_not_optimal(x64):
         _program(insts[0]), dataclasses.replace(opts, refine_tol=0.0),
         EPIRAM)
     assert eager.result.status == "optimal"
+
+
+@pytest.mark.parametrize("name, m, n, bucket, share", [
+    ("neos5", 402, 253, (448, 704), 24 / 81),
+    ("gen-ip016", 24, 28, (64, 64), 1.0)])
+def test_tile_counters(name, m, n, bucket, share, x64):
+    """``xbar_tiles_read`` counts the tiles the windowed products visit,
+    ``xbar_tiles_array`` those whole-pair reads would: 24 of 81 a read
+    at M 1152, and the single tile at M 128."""
+    inst = gen.table1_lp(name, m, n, 1.0, 10.0, np.random.default_rng(0))
+    opts = dataclasses.replace(OPTS, max_iters=100, refine_rounds=0)
+    solver = CrossbarBatchSolver(opts, device=EPIRAM, kernel="pallas")
+    reports = solver.solve_stream([_program(inst)])
+    assert solver._bucket(*inst.K.shape) == bucket
+    stats = solver.last_stream_stats
+    R, C = _array_dims(*bucket, EPIRAM)
+    whole = -(-R // xbar_kernel.TILE_R) * -(-C // xbar_kernel.TILE_C)
+    assert stats["xbar_tiles_array"] == reports[0].pdhg_mvms * whole
+    assert stats["xbar_tiles_read"] == \
+        pytest.approx(share * stats["xbar_tiles_array"], rel=1e-12)
 
 
 def _programmed_pairs(lp, bucket):

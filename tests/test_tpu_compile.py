@@ -99,6 +99,25 @@ def test_crossbar_mvm_compiles_at_the_control_precision(one_chip, x64,
     jax.clear_caches()
 
 
+@pytest.mark.parametrize("mode", ["fwd", "adj"])
+@pytest.mark.parametrize("m, n", [(448, 704), (64, 128)])
+def test_windowed_crossbar_read_compiles_f32_with_x64(one_chip, x64, m,
+                                                       n, mode):
+    """The operator's windowed products over the mounted pair, at M 1152
+    (24 of 81 tiles) and M 192 (padded to 256 at mount, 2 of 4)."""
+    side = m + n
+    sd = lambda s: jax.ShapeDtypeStruct(s, jnp.float32,  # noqa: E731
+                                        sharding=one_chip)
+    key = jax.random.PRNGKey(0)
+    exe = _compile(
+        lambda gp, gn, x, k: getattr(engine.crossbar_operator(
+            gp, gn, 1.0, m, n, sigma_read=1e-3, interpret=False), mode)(x, k),
+        sd((side, side)), sd((side, side)),
+        sd((n if mode == "fwd" else m,)),
+        jax.ShapeDtypeStruct(key.shape, key.dtype, sharding=one_chip))
+    assert "tpu_custom_call" in exe.as_text()
+
+
 @pytest.mark.parametrize("which", ["primal", "dual"])
 def test_update_kernels_compile_f32_with_x64(one_chip, x64, which):
     col = jax.ShapeDtypeStruct((1 << 16, 1), jnp.float32, sharding=one_chip)

@@ -7,10 +7,12 @@ For one call of the ``crossbar-t1`` cell's pool (``BENCHMARK.json``):
 
 * reads: each tile bucket's instances are scaled and programmed as the
   bucket program does it (``prep_scale``, then ``encode_core`` on the
-  tile-padded M), and one compiled, vmapped Pallas read
-  (``kernels.ops.crossbar_mvm``) of those conductances with a read-noise
-  draw is compared with ``bench/xbar_reference.read`` in float64 on the
-  host (relative error in the 2-norm over the bucket's lanes);
+  tile-padded M), and compiled, vmapped Pallas reads of those
+  conductances with a read-noise draw are compared with
+  ``bench/xbar_reference.read`` in float64 on the host (relative error
+  in the 2-norm over the bucket's lanes): the read of the whole pair
+  (``kernels.ops.crossbar_mvm``), and the windowed ``fwd`` and ``adj``
+  products of ``engine.crossbar_operator``, as the cell runs them;
 * ledgers: the call is solved through the cell's entry, and every
   answer's ledger is compared with ``bench/xbar_reference.ledger``, fed
   the programmed pairs the reference counts on the same scaled M and
@@ -113,6 +115,7 @@ def check(call: int, trace_root: str) -> list:
     import numpy as np
 
     from bench import devtrace, spec, traffic, xbar_reference
+    from repro.core import engine
     from repro.core.symblock import build_sym_block
     from repro.crossbar import encode_core
     from repro.crossbar.solver import _array_dims
@@ -165,9 +168,21 @@ def check(call: int, trace_root: str) -> list:
             -4.0, 4.0)
         got = jax.jit(jax.vmap(lambda a, b, s, z, x: ops.crossbar_mvm(
             a, b, x, s, z)))(gp, gn, scale, noise, v)
-        read_err = _rel(got, xbar_reference.read(
-            np.asarray(gp), np.asarray(gn), np.asarray(scale),
-            np.asarray(noise), np.asarray(v)))
+        gp_, gn_, scale_, noise_, v_ = (np.asarray(a) for a in (
+            gp, gn, scale, noise, v))
+        read_err = {"read_rel_err": _rel(got, xbar_reference.read(
+            gp_, gn_, scale_, noise_, v_))}
+        for mode, cols, rows in (("fwd", slice(mb, mb + nb), slice(0, mb)),
+                                 ("adj", slice(0, mb), slice(mb, mb + nb))):
+            got = jax.jit(jax.vmap(
+                lambda a, b, s, x, k, mode=mode: getattr(
+                    engine.crossbar_operator(
+                        a, b, s, mb, nb, sigma_read=device.sigma_read),
+                    mode)(x, k)))(gp, gn, scale, v[:, cols], keys[2 * B:])
+            vm = np.zeros_like(v_)
+            vm[:, cols] = v_[:, cols]
+            read_err[f"{mode}_rel_err"] = _rel(got, xbar_reference.read(
+                gp_, gn_, scale_, noise_, vm)[:, rows])
 
         worst = 0.0
         for k, i in enumerate(idxs):
@@ -208,7 +223,7 @@ def check(call: int, trace_root: str) -> list:
             "iterations": [answers[i]["iterations"] for i in idxs],
             "executed_iterations": answers[idxs[0]]["executed_iterations"],
             "status": [answers[i]["status"] for i in idxs],
-            "read_rel_err": read_err, "ledger_max_rel_diff": worst,
+            **read_err, "ledger_max_rel_diff": worst,
             "alone_wall_s": wall, "alone_busy_s": busy,
             "scope_self_s": by_scope(dev, scopes),
             "hlo_scopes": sorted(set(scopes.values())),
@@ -220,7 +235,8 @@ def check(call: int, trace_root: str) -> list:
                "platform": d.platform, "call": call,
                "first_call_s": first_s, "call_s": call_s,
                "stream_stats": stats,
-               "read_rel_err_max": max(x["read_rel_err"] for x in lines),
+               "read_rel_err_max": max(x[k] for x in lines for k in (
+                   "read_rel_err", "fwd_rel_err", "adj_rel_err")),
                "ledger_max_rel_diff": max(x["ledger_max_rel_diff"]
                                           for x in lines)}
     print(json.dumps(summary), flush=True)

@@ -5,9 +5,8 @@ CI runs this right after the smoke stream benchmark:
   1. **Schema validation** — the candidate record must be
      ``bench_stream/v7``: every serving path (dense batched /
      per-instance, crossbar batched / per-instance, the
-     mixed-precision refined crossbar solve, the two sparse
-     backends — default ELL and nnz-bucketed BCOO — and the densified
-     sparse baseline,
+     mixed-precision refined crossbar solve, the ELL sparse backend
+     and the densified sparse baseline,
      async + sync dispatch, per-pod routed cluster serving, the
      adaptive step rule on the imbalanced acceptance stream, and the
      norm-reuse seeded second pass) present with finite numeric
@@ -72,7 +71,6 @@ REQUIRED_PATHS = (
     "sparse_batched",
     "sparse_batched_dense",
     "sparse_ell",
-    "sparse_bcoo",
     "exact_batched_async",
     "exact_batched_sync",
     "exact_routed",
@@ -93,7 +91,7 @@ REFINEMENT_FIELDS = ("merit_exact", "merit_unrefined", "merit_refined",
                      "digital_mvms", "rounds", "sigma_read", "tol")
 SPARSE_FIELDS = ("density", "host_stack_bytes_dense",
                  "host_stack_bytes_sparse", "host_mem_improvement",
-                 "speedup_warm", "speedup_warm_bcoo")
+                 "speedup_warm")
 CLUSTER_FIELDS = ("n_pods", "routing", "per_pod", "rerouted_buckets",
                   "max_rel_disagreement_vs_unrouted")
 PER_POD_FIELDS = ("n_buckets", "n_instances", "flops_cost", "flops_share",

@@ -22,9 +22,8 @@ steady-state serving cost (the number that matters for throughput).
 Besides the full record, every run emits ``BENCH_stream.json`` at the
 repo root (schema ``bench_stream/v7``: per-path warm/cold seconds +
 device-MVM totals + per-instance ``iterations_to_tol`` distributions
-(median/p90) — including the two sparse backends (``sparse_ell``
-= the default ELL pipeline, ``sparse_bcoo`` = nnz-bucketed
-COO), the async-vs-sync dispatch split, the per-pod ROUTED
+(median/p90) — including the ELL sparse pipeline
+(``sparse_ell``), the async-vs-sync dispatch split, the per-pod ROUTED
 cluster path, the ``exact_adaptive`` step-rule path on a scale-
 imbalanced acceptance stream, the ``exact_norm_reuse`` seeded
 second pass and the ``crossbar_refined`` mixed-precision refinement
@@ -165,15 +164,9 @@ def bench_sparse(lps, opts):
     The dense baseline pads every instance into its (B, m_pad, n_pad)
     bucket stack — exactly what serving sparse traffic without the
     sparse path costs; ``host_stack_bytes`` records what each path
-    actually materialized on the host.  Two sparse variants run:
-
-      - ``sparse_*``      the default pipeline (= the ELL backend; the
-                          steady-state serving number the guard gates)
-      - ``bcoo_*``        the nnz-bucketed BCOO backend (memory-optimal,
-                          scatter-bound on CPU)
+    actually materialized on the host.  ``sparse_*`` is the ELL
+    pipeline, the steady-state serving number the guard gates.
     """
-    import dataclasses
-
     from repro.runtime import BatchSolver
 
     dense_lps = [lp.densified() for lp in lps]
@@ -190,13 +183,10 @@ def bench_sparse(lps, opts):
     dense_results = timed(solver_d, dense_lps, "dense", timings)
     dense_stats = dict(solver_d.last_stream_stats)
 
-    assert opts.sparse_kernel == "ell"      # default pipeline == ELL
     solver_s = BatchSolver(opts)
     results = timed(solver_s, lps, "sparse", timings)
     sparse_stats = dict(solver_s.last_stream_stats)
 
-    solver_b = BatchSolver(dataclasses.replace(opts, sparse_kernel="bcoo"))
-    bcoo_results = timed(solver_b, lps, "bcoo", timings)
 
     gaps = [abs(r.obj - lp.obj_opt) / max(abs(lp.obj_opt), 1e-12)
             for lp, r in zip(lps, results)]
@@ -206,8 +196,6 @@ def bench_sparse(lps, opts):
         **timings,
         "speedup_warm": timings["dense_warm_s"]
         / max(timings["sparse_warm_s"], 1e-12),
-        "speedup_warm_bcoo": timings["dense_warm_s"]
-        / max(timings["bcoo_warm_s"], 1e-12),
         "density": float(np.mean([lp.K.density for lp in lps])),
         "nnz_total": int(sum(lp.K.nnz for lp in lps)),
         "host_stack_bytes_dense": int(mem_dense),
@@ -219,15 +207,10 @@ def bench_sparse(lps, opts):
         "max_rel_disagreement_vs_dense": float(max(
             abs(r.obj - dr.obj) / max(abs(dr.obj), 1e-12)
             for r, dr in zip(results, dense_results))),
-        "max_rel_disagreement_bcoo_vs_ell": float(max(
-            abs(br.obj - r.obj) / max(abs(r.obj), 1e-12)
-            for br, r in zip(bcoo_results, results))),
         "mvm_total_sparse": int(sum(r.mvm_calls for r in results)),
         "mvm_total_dense": int(sum(r.mvm_calls for r in dense_results)),
-        "mvm_total_bcoo": int(sum(r.mvm_calls for r in bcoo_results)),
         "iters_sparse": _iter_stats(results),
         "iters_dense": _iter_stats(dense_results),
-        "iters_bcoo": _iter_stats(bcoo_results),
     }
 
 
@@ -674,11 +657,6 @@ def main(argv=None):
                 "warm_s": record["sparse"]["sparse_warm_s"],
                 "mvm_total": record["sparse"]["mvm_total_sparse"],
             },
-            "sparse_bcoo": {
-                "cold_s": record["sparse"]["bcoo_cold_s"],
-                "warm_s": record["sparse"]["bcoo_warm_s"],
-                "mvm_total": record["sparse"]["mvm_total_bcoo"],
-            },
             "exact_batched_async": {
                 "cold_s": record["async"]["async_cold_s"],
                 "warm_s": record["async"]["async_warm_s"],
@@ -768,7 +746,6 @@ def main(argv=None):
             "host_mem_improvement":
                 record["sparse"]["host_mem_improvement"],
             "speedup_warm": record["sparse"]["speedup_warm"],
-            "speedup_warm_bcoo": record["sparse"]["speedup_warm_bcoo"],
         },
     }
     # v6: every path entry carries its iterations-to-tol distribution
@@ -780,7 +757,6 @@ def main(argv=None):
         "sparse_batched": record["sparse"]["iters_sparse"],
         "sparse_batched_dense": record["sparse"]["iters_dense"],
         "sparse_ell": record["sparse"]["iters_sparse"],
-        "sparse_bcoo": record["sparse"]["iters_bcoo"],
         "exact_batched_async": record["async"]["iters_async"],
         "exact_batched_sync": record["async"]["iters_sync"],
         "exact_routed": record["cluster"]["iters_routed"],
@@ -812,8 +788,6 @@ def main(argv=None):
     print(f"[sparse] dense warm {r['dense_warm_s']:.3f}s"
           f" | ell warm {r['sparse_warm_s']:.3f}s"
           f" ({r['speedup_warm']:.2f}x)"
-          f" | bcoo warm {r['bcoo_warm_s']:.3f}s"
-          f" ({r['speedup_warm_bcoo']:.2f}x)"
           f" | host stack {r['host_stack_bytes_dense']}B ->"
           f" {r['host_stack_bytes_sparse']}B"
           f" ({r['host_mem_improvement']:.1f}x smaller)"

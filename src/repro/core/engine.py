@@ -8,16 +8,16 @@ solver path in the repo:
 
     core.pdhg.solve        host loop      accel_operator   (Accel handles)
     core.pdhg.solve_jit    while_loop     dense_operator
-    runtime.batch          vmapped        dense_operator
+    runtime.batch          vmapped        dense_operator | sparse_ell_operator
     crossbar.solver        vmapped        dense_operator | crossbar_operator
     distributed.pdhg_dist  shard_map      sharded_operator
 
 Two orthogonal backend axes parameterize the engine:
 
   * **operator backend** (``Operator``): where the two device MVMs run —
-    dense ``jnp`` matmuls with optional multiplicative read noise, sparse
-    BCOO/BCSR contractions over the stored nonzeros (same noise hooks;
-    the paper-scale sparse workload class), the differential-pair Pallas
+    dense ``jnp`` matmuls with optional multiplicative read noise, ELL
+    gathers over the stored nonzeros (same noise hooks; the paper-scale
+    sparse workload class), the differential-pair Pallas
     crossbar kernel (``kernels.ops.crossbar_mvm`` against the single
     programmed symmetric block M), a shard_map psum-tiled operator over
     a device mesh, or a host-side ``Accel`` handle (crossbar simulation
@@ -37,6 +37,7 @@ depends only on tau_{k-1}, which is known when iteration k-1 retires.
 """
 from __future__ import annotations
 
+import dataclasses
 from typing import Callable, NamedTuple, Optional
 
 import jax
@@ -46,7 +47,6 @@ from .residuals import kkt_residuals
 from .symblock import MODE_AX, MODE_ATY, MVM_PRECISION, matmul_accel, mv
 
 KERNELS = ("jnp", "pallas")
-SPARSE_KERNELS = ("ell", "bcoo")
 STEP_RULES = ("fixed", "adaptive", "strongly_convex")
 
 # Adaptive step-rule tuning (``step_rule="adaptive"``): log-space
@@ -157,37 +157,6 @@ def dense_operator(K_fwd, K_adj, sigma_read: float = 0.0) -> Operator:
         return w
 
     return Operator(fwd, adj, "dense")
-
-
-def sparse_operator(K_sp, sigma_read: float = 0.0) -> Operator:
-    """Sparse jnp backend over a ``jax.experimental.sparse`` matrix
-    (BCOO or BCSR): the two MVMs contract only the stored nonzeros, so
-    paper-scale sparse LPs never materialize a dense K on device.  The
-    read-noise hook matches ``dense_operator`` exactly — a crossbar only
-    programs the nonzero conductances, and cycle-to-cycle noise rides on
-    the accumulated currents either way.
-
-    The adjoint is a transpose VIEW taken once at trace time (BCSR drops
-    to BCOO for it — BCSR has no native transpose); no index shuffling
-    happens inside the iteration.
-    """
-    from jax.experimental import sparse as jsparse  # deferred
-
-    K_adj = (K_sp.to_bcoo() if isinstance(K_sp, jsparse.BCSR) else K_sp).T
-
-    def fwd(v, key=None):
-        w = K_sp @ v
-        if sigma_read > 0.0:
-            w = _read_noise(w, key, sigma_read)
-        return w
-
-    def adj(v, key=None):
-        w = K_adj @ v
-        if sigma_read > 0.0:
-            w = _read_noise(w, key, sigma_read)
-        return w
-
-    return Operator(fwd, adj, "sparse")
 
 
 def sparse_ell_operator(data_f, cols_f, data_a, cols_a,
@@ -681,48 +650,57 @@ def pdhg_loop(op: Operator, upd: Updates, b, c, lb, ub, T, Sigma,
 
 # ----------------------------------------------------- jit core + ledger ---
 
-def solve_core(K_fwd, K_adj, b, c, lb, ub, T, Sigma, rho, key, static, *,
-               operator: Optional[Operator] = None, x0=None, y0=None):
-    """The jitted solve core (formerly ``pdhg._solve_jit_core``).
+@dataclasses.dataclass(frozen=True)
+class SolveStatic:
+    """The solve options a compiled solve is specialized on, built by
+    ``pdhg.opts_static``.  Frozen and hashable: it is ``jax.jit``'s
+    static argument of ``solve_core`` and part of every compiled-bucket
+    cache key, so two executables share a trace only if every field
+    agrees.  ``sigma_read`` > 0 adds multiplicative cycle-to-cycle read
+    noise to every MVM, residual checks included; ``kernel`` selects the
+    update backend (one of ``KERNELS``); ``restart`` gates the restart
+    check; ``megakernel`` fuses each check window into one launch
+    (mounted on the noiseless dense operator, refused on every other);
+    ``step_rule`` is one of ``STEP_RULES`` (see ``pdhg_loop``).
+    ``refine_rounds``/``refine_tol`` drive the digital refinement shell
+    around this core (``crossbar.refine``); ``solve_core`` ignores
+    them."""
 
-    ``static`` is the hashable tuple from ``pdhg.opts_static``:
-    (max_iters, tol, eta, omega, gamma, check_every, restart_beta,
-    sigma_read, kernel).  ``sigma_read`` > 0 adds multiplicative
-    cycle-to-cycle read noise per MVM — residual checks included —
-    and ``kernel`` selects the update backend (jnp | pallas).
+    max_iters: int
+    tol: float
+    eta: float
+    omega: float
+    gamma: float
+    check_every: int
+    restart_beta: float
+    sigma_read: float
+    kernel: str
+    restart: bool
+    megakernel: bool
+    step_rule: str
+    refine_rounds: int
+    refine_tol: float
+
+
+def solve_core(K_fwd, K_adj, b, c, lb, ub, T, Sigma, rho, key,
+               static: SolveStatic, *,
+               operator: Optional[Operator] = None, x0=None, y0=None):
+    """The jitted solve core, specialized on ``static``
+    (``SolveStatic``).
 
     ``operator`` swaps the MVM backend (e.g. the differential-pair
-    crossbar kernel or the ELL operator) in place of the
-    default dense one — ``K_fwd``/``K_adj`` may then be ``None``; the
+    crossbar kernel or the ELL operator) in place of the default dense
+    one over ``K_fwd``/``K_adj``, which may then be ``None``; the
     step-size initialization, init draws, and option plumbing stay HERE
-    either way (problem dims come from ``b``/``c``).  ``K_fwd`` may be
-    a ``jax.experimental.sparse`` matrix (BCOO/BCSR): the default
-    operator is then ``sparse_operator`` and ``K_adj`` is ignored (the
-    adjoint is a transpose view of the same nonzeros).
-
-    Trailing static entries past the original 9 are optional (older
-    9-tuples keep their exact semantics): ``restart`` (explicit restart
-    gate, default True), ``sparse_kernel`` (executable-cache
-    discriminator for the sparse backend — the stacking layer picks the
-    operator), ``megakernel`` (fuse each check window into one launch;
-    mounted on the noiseless dense backend and refused on every other),
-    ``step_rule`` (one of ``STEP_RULES``, default ``"fixed"`` — see
-    ``pdhg_loop``).  Entries 13/14 (``refine_rounds``/``refine_tol``)
-    belong to the digital refinement shell around this core
-    (``crossbar.refine``) and are ignored here.
+    either way (problem dims come from ``b``/``c``).
 
     ``x0``/``y0`` warm-start the loop (both or neither); by default the
     paper's projected-Gaussian init is drawn from ``key``.  The
     refinement shell passes zeros — the correction LP's origin IS the
     previous outer iterate in shifted coordinates.
     """
-    (max_iters, tol, eta, omega, gamma, check_every, restart_beta,
-     sigma_read, kernel) = static[:9]
-    # ``static`` is the jit static-arg tuple — plain Python values at
-    # trace time, so these bool() calls never touch the device
-    restart = bool(static[9]) if len(static) > 9 else True  # jaxlint: disable=R5
-    megakernel = bool(static[11]) if len(static) > 11 else False  # jaxlint: disable=R5
-    step_rule = str(static[12]) if len(static) > 12 else "fixed"
+    eta, omega, gamma = static.eta, static.omega, static.gamma
+    sigma_read = static.sigma_read
     m, n = b.shape[0], c.shape[0]
     # an all-zero operator (degenerate but legal: the optimum is just the
     # box projection of -c's direction) has rho = 0; unguarded it makes
@@ -733,11 +711,8 @@ def solve_core(K_fwd, K_adj, b, c, lb, ub, T, Sigma, rho, key, static, *,
     if x0 is None:
         key, x0, y0 = draw_init(key, m, n, lb, ub, b.dtype)
     if operator is None:
-        if hasattr(K_fwd, "todense"):   # JAXSparse (BCOO/BCSR), not ndarray
-            operator = sparse_operator(K_fwd, sigma_read)
-        else:
-            operator = dense_operator(K_fwd, K_adj, sigma_read)
-    if megakernel and operator.fuse is None:
+        operator = dense_operator(K_fwd, K_adj, sigma_read)
+    if static.megakernel and operator.fuse is None:
         if operator.name != "dense" or sigma_read > 0.0:
             raise ValueError(
                 f"megakernel=True fuses the noiseless dense operator only; "
@@ -747,11 +722,11 @@ def solve_core(K_fwd, K_adj, b, c, lb, ub, T, Sigma, rho, key, static, *,
         operator = operator._replace(fuse=make_fused_dense(
             K_fwd, K_adj, b, c, lb, ub, T, Sigma, gamma))
     return pdhg_loop(
-        operator, make_updates(kernel, b.dtype),
+        operator, make_updates(static.kernel, b.dtype),
         b, c, lb, ub, T, Sigma, x0, y0, tau0, sigma0, key,
-        max_iters=max_iters, tol=tol, gamma=gamma, check_every=check_every,
-        restart_beta=restart_beta, restart=restart,
-        step_rule=step_rule, eta=eta,
+        max_iters=static.max_iters, tol=static.tol, gamma=gamma,
+        check_every=static.check_every, restart_beta=static.restart_beta,
+        restart=static.restart, step_rule=static.step_rule, eta=eta,
     )
 
 

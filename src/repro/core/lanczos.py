@@ -119,9 +119,9 @@ def lanczos_svd_jit_mv(matvec, dim: int, dtype, k_max: int = 32,
                        key=None) -> jnp.ndarray:
     """Jitted fixed-iteration Lanczos on an arbitrary symmetric matvec.
 
-    The operator enters only through ``matvec(v) -> M v`` — sparse
-    pipelines pass a BCOO/COO contraction over the symmetric block M
-    here and never build M densely.  Returns the largest |Ritz value| of
+    The operator enters only through ``matvec(v) -> M v`` — the ELL
+    bucket pipeline passes the forward and adjoint products of Keff
+    here and never builds M.  Returns the largest |Ritz value| of
     the k_max-step tridiagonalization; no early exit (fixed cost).
     """
     if key is None:

@@ -71,10 +71,6 @@ class PDHGOptions:
     track_history: bool = False
     norm_override: Optional[float] = None  # skip Lanczos (reuse across runs)
     kernel: str = "jnp"            # update backend: "jnp" | "pallas" (fused)
-    sparse_kernel: str = "ell"     # sparse operator backend: "ell"
-    #                                (ELL XLA gather; the
-    #                                wall-clock path) | "bcoo" (COO/BCOO
-    #                                scatter; the memory-optimal path)
     megakernel: bool = False       # fuse each check_every window into ONE
     #                                kernel launch (noiseless paths only)
     step_rule: str = "fixed"       # step-size schedule: "fixed" (constant
@@ -382,7 +378,7 @@ def solve(
 
 # PDHGOptions fields that deliberately stay OUT of the compiled-executable
 # cache key (``tools.jaxlint`` rule R1 cross-checks this allowlist against
-# the dataclass fields and the ``opts_static`` tuple below — adding an
+# the dataclass fields and the fields ``opts_static`` reads — adding an
 # option without deciding its cache-key fate is a lint error).
 # ``ruiz_iters``/``lanczos_iters``/``norm_override``/``norm_backend``
 # ride in ``runtime.batch``'s separate prep-signature tuple (the norm
@@ -398,29 +394,23 @@ DYNAMIC_FIELDS = (
 )
 
 
-def opts_static(opts: PDHGOptions, sigma_read: float = 0.0) -> tuple:
-    """The hashable option tuple ``engine.solve_core`` consumes
-    (positional unpack — keep in sync with the head of that function, and
-    nowhere else: ``solve_jit``, ``runtime.batch`` and
-    ``crossbar.solver`` all build it through here; fields that
-    deliberately stay out of the tuple are declared in
-    ``DYNAMIC_FIELDS`` and the pairing is machine-checked by jaxlint
-    rule R1).  ``opts.kernel``,
-    ``opts.restart``, ``opts.sparse_kernel``, ``opts.megakernel`` and
-    ``opts.step_rule`` are
-    part of the tuple, so compiled-executable caches keyed on it never
-    serve one backend's executable to another (a step-rule change is a
-    different trace and must never reuse an executable compiled for
-    another rule).  ``opts.restart`` rides
-    as an explicit static boolean — the old encoding (restart off ==
-    ``restart_beta 0.0``) only worked because ``0.0 * inf`` is NaN and
-    NaN comparisons are false inside the jitted body."""
+def opts_static(opts: PDHGOptions,
+                sigma_read: float = 0.0) -> engine.SolveStatic:
+    """Validate ``opts`` and return the ``engine.SolveStatic`` that
+    ``engine.solve_core`` and ``crossbar.refine.refined_core`` are
+    specialized on.  ``solve_jit``, ``runtime.batch`` and
+    ``crossbar.solver`` all build it through here; the fields that
+    deliberately stay out of it are declared in ``DYNAMIC_FIELDS``, and
+    jaxlint rule R1 checks that every option is one or the other.  It is
+    part of every compiled-executable cache key, so an executable is
+    never served to another update kernel, step rule, restart gate or
+    refinement round count.  ``restart`` is an explicit static boolean
+    — the old encoding (restart off == ``restart_beta 0.0``) only worked
+    because ``0.0 * inf`` is NaN and NaN comparisons are false inside
+    the jitted body."""
     if opts.kernel not in engine.KERNELS:
         raise ValueError(f"unknown update kernel {opts.kernel!r}; "
                          f"expected one of {engine.KERNELS}")
-    if opts.sparse_kernel not in engine.SPARSE_KERNELS:
-        raise ValueError(f"unknown sparse kernel {opts.sparse_kernel!r}; "
-                         f"expected one of {engine.SPARSE_KERNELS}")
     if opts.megakernel and float(sigma_read) > 0.0:
         raise ValueError("megakernel mode is noiseless-only: per-MVM "
                          "read-noise keys cannot be split inside a fused "
@@ -439,15 +429,14 @@ def opts_static(opts: PDHGOptions, sigma_read: float = 0.0) -> tuple:
     if opts.refine_rounds < 0:
         raise ValueError(f"refine_rounds must be >= 0 "
                          f"(got {opts.refine_rounds})")
-    # refine_rounds/refine_tol ride in the static tuple (entries 13/14):
-    # the refinement shell unrolls one analog solve per round, so a
-    # different round count is a different trace and must never reuse an
-    # executable compiled for another.  solve_core itself ignores them.
-    return (opts.max_iters, opts.tol, opts.eta, opts.omega, opts.gamma,
-            opts.check_every, opts.restart_beta, float(sigma_read),
-            opts.kernel, bool(opts.restart), opts.sparse_kernel,
-            bool(opts.megakernel), opts.step_rule,
-            int(opts.refine_rounds), float(opts.refine_tol))
+    return engine.SolveStatic(
+        max_iters=opts.max_iters, tol=opts.tol, eta=opts.eta,
+        omega=opts.omega, gamma=opts.gamma, check_every=opts.check_every,
+        restart_beta=opts.restart_beta, sigma_read=float(sigma_read),
+        kernel=opts.kernel, restart=bool(opts.restart),
+        megakernel=bool(opts.megakernel), step_rule=opts.step_rule,
+        refine_rounds=int(opts.refine_rounds),
+        refine_tol=float(opts.refine_tol))
 
 
 # Backwards-compatible alias: the dense jit core now lives in the engine.

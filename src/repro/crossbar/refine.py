@@ -79,9 +79,9 @@ def refined_core(K_dig_fwd, K_dig_adj, K_fwd, K_adj, b, c, lb, ub, T,
     operator blocks used only for the digital residual/merit MVMs;
     ``K_fwd``/``K_adj`` (or ``operator``) is the programmed analog
     operator every inner solve runs on — identical in every round, never
-    rewritten.  ``static`` is the ``pdhg.opts_static`` tuple; entries 13
-    (``refine_rounds``) and 14 (``refine_tol``) drive the shell, the rest
-    is passed straight into ``engine.solve_core``.
+    rewritten.  ``static`` is the ``engine.SolveStatic`` from
+    ``pdhg.opts_static``: its ``refine_rounds`` and ``refine_tol`` drive
+    the shell, and it is passed whole into ``engine.solve_core``.
 
     Returns ``(x, y, its, merit)`` where ``its`` is the per-round
     iteration-count vector (length ``refine_rounds + 1``; callers charge
@@ -89,8 +89,7 @@ def refined_core(K_dig_fwd, K_dig_adj, K_fwd, K_adj, b, c, lb, ub, T,
     exact digital KKT merit after refinement (``refine_rounds == 0``
     degenerates to ``solve_core`` with its in-loop analog merit).
     """
-    rounds = int(static[13]) if len(static) > 13 else 0
-    refine_tol = float(static[14]) if len(static) > 14 else 0.0
+    rounds, refine_tol = static.refine_rounds, static.refine_tol
 
     x, y, it0, merit0 = engine.solve_core(
         K_fwd, K_adj, b, c, lb, ub, T, Sigma, rho, key, static,

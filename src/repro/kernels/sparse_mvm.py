@@ -1,9 +1,8 @@
 """ELL sparse MVM (an XLA gather), host-side COO->ELL conversion, and
 the rule that turns a small ELL bucket into a dense operator.
 
-The sparse COO path is memory-optimal but loses on wall clock: every
-MVM is a scatter-add over (nnz,) gathers, which XLA CPU serializes, and
-every Ruiz/Pock-Chambolle reduction is another scatter.  ELL
+A COO product is a scatter-add over (nnz,) gathers, which XLA CPU
+serializes, and so is every Ruiz/Pock-Chambolle reduction on COO.  ELL
 (ELLPACK) trades a bounded amount of padding for fully vectorized
 row-major access:
 
@@ -15,9 +14,7 @@ so one MVM is a dense gather + axis-1 reduction,
     w[i] = sum_j data[i, j] * v[cols[i, j]]
 
 with no scatter anywhere.  Padding entries carry data == 0, so whatever
-``cols`` says for them (index 0 by convention) contributes nothing —
-exactly the inertness contract of ``stack_problems_sparse``'s (0, 0)
-padding.
+``cols`` says for them (index 0 by convention) contributes nothing.
 
 ``ell_matvec`` is a plain XLA gather and axis-1 sum on every backend.
 (A row-blocked Pallas form of the same gather does not compile for TPU:
@@ -36,7 +33,7 @@ from typing import Optional, Tuple
 import jax.numpy as jnp
 import numpy as np
 
-# Smallest ELL width bucket (power-of-two bucketing, like nnz_bucket).
+# Smallest ELL width bucket (widths bucket to powers of two).
 MIN_ELL_WIDTH = 4
 #: Dense elements one ELL slot is worth at equal time, by operand
 #: itemsize: a bucket's dense form wins while m*n <= R * (m*wf + n*wa).
@@ -54,8 +51,7 @@ DENSE_OPERATOR_MAX_BYTES = 512 << 20
 
 def ell_width_bucket(width: int, min_size: int = MIN_ELL_WIDTH) -> int:
     """Round an ELL width up to its power-of-two bucket so repeat sparse
-    traffic with drifting row occupancy reuses compiled executables
-    (the ELL twin of ``runtime.batch.nnz_bucket``)."""
+    traffic with drifting row occupancy reuses compiled executables."""
     return max(min_size, 1 << (max(int(width), 1) - 1).bit_length())
 
 

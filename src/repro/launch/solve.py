@@ -79,12 +79,11 @@ def main(argv=None):
                          "the device-tile-aware crossbar simulator")
     ap.add_argument("--sparse", action="store_true",
                     help="with --backend batch: serve the stream through "
-                         "the sparse COO pipeline (instances loaded as "
+                         "the sparse ELL pipeline (instances loaded as "
                          "sprand: specs are sparse already; dense specs "
-                         "are converted).  Host memory is proportional "
-                         "to nonzeros — no dense (B, m, n) stack exists "
-                         "on the host; small buckets multiply by a dense "
-                         "K built on the device")
+                         "are converted).  No dense (B, m, n) stack "
+                         "exists on the host; small buckets multiply by "
+                         "a dense K built on the device")
     ap.add_argument("--sync", action="store_true",
                     help="with --backend batch: block per bucket instead "
                          "of the default submit-all-then-collect async "

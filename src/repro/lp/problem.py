@@ -106,10 +106,10 @@ class SparseCOO:
         return out
 
     def coalesce(self) -> "SparseCOO":
-        """Sum duplicate (row, col) entries into one.  The batch
-        pipeline's scatter preconditioners reduce over STORED entries,
-        so duplicates must be merged before stacking for sparse/dense
-        parity to hold."""
+        """Sum duplicate (row, col) entries into one.  The ELL stacking
+        keeps one slot per stored entry and its preconditioners reduce
+        over them, so duplicates must be merged before stacking for
+        sparse/dense parity to hold."""
         flat = self.row.astype(np.int64) * self.shape[1] + self.col
         uniq, inv = np.unique(flat, return_inverse=True)
         if uniq.size == self.data.size:

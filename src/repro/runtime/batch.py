@@ -28,19 +28,16 @@ are decorrelated across a bucket.
 Past toy sizes, two more concerns take over (ROADMAP item 2):
 
   * **Sparse streams.**  A ``StandardLP`` whose K is a ``SparseCOO``
-    routes through a dedicated sparse bucket pipeline selected by
-    ``PDHGOptions.sparse_kernel``.  The default ``"ell"`` backend
-    converts COO to ELL — forward (B, m, Wf) AND adjoint
-    (B, n, Wa) layouts, widths power-of-two bucketed like ``nnz_bucket``
-    — so Ruiz equilibration and Pock–Chambolle diagonals are axis-1
-    reductions with no scatter; a bucket whose dense form is cheap
-    (``kernels.sparse_mvm.ell_goes_dense``) is scattered once per solve
-    into a dense K on the device and multiplied on the MXU, any other
-    runs Lanczos and both solve MVMs as ELL gathers (the wall-clock
-    path).  ``"bcoo"`` keeps the nnz-proportional COO stacking
-    ((B, nnz) data + (B, nnz, 2) indices, ``engine.sparse_operator``
-    scatter contractions) — the memory-optimal path.  Neither ever
-    materializes a dense (B, m_pad, n_pad) stack on the host.
+    always stacks as ELL: forward (B, m, Wf) AND adjoint (B, n, Wa)
+    layouts, widths power-of-two bucketed
+    (``kernels.sparse_mvm.ell_width_bucket``), so Ruiz equilibration
+    and Pock–Chambolle diagonals are axis-1 reductions with no scatter,
+    and no dense (B, m_pad, n_pad) stack ever exists on the host.
+    ``kernels.sparse_mvm.ell_goes_dense`` picks each bucket's operator
+    from its shapes: a bucket whose dense form is cheap is scattered
+    once per solve into a dense K on the device and multiplied on the
+    MXU, any other runs the norm estimate and both solve MVMs as ELL
+    gathers.
   * **Async serving.**  ``solve_stream`` submits EVERY bucket to its
     compiled executable first (JAX dispatch is asynchronous; the host
     never blocks between buckets) and only then collects results,
@@ -61,7 +58,6 @@ from typing import List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import sparse as jsparse
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
@@ -85,7 +81,6 @@ from ..lp.problem import SparseCOO, StandardLP
 from . import sanitize
 
 MIN_BUCKET = 8
-MIN_NNZ_BUCKET = 16
 # donate the stacked operator buffer to the executable past this size
 # (on backends that implement donation; CPU silently ignores it)
 DONATE_MIN_BYTES = 32 << 20
@@ -122,12 +117,6 @@ def bucket_dims(m: int, n: int, min_size: int = MIN_BUCKET,
         return _ceil_to(max(int(m), 1), tr), _ceil_to(max(int(n), 1), tc)
     up = lambda v: max(min_size, 1 << (int(v) - 1).bit_length())  # noqa: E731
     return up(m), up(n)
-
-
-def nnz_bucket(nnz: int, min_size: int = MIN_NNZ_BUCKET) -> int:
-    """Round a nonzero count up to its power-of-two bucket (so repeat
-    sparse traffic with drifting nnz reuses compiled executables)."""
-    return max(min_size, 1 << (max(int(nnz), 1) - 1).bit_length())
 
 
 def pad_problem(lp: StandardLP, m_pad: int, n_pad: int) -> StandardLP:
@@ -171,8 +160,7 @@ def stack_problems(lps: Sequence[StandardLP], m: Optional[int] = None,
     Target dims default to the max over the list (the legacy
     ``distributed.batch_solve`` behaviour); buckets pass them explicitly.
     Sparse members are densified — sparse streams should go through
-    ``stack_problems_sparse`` instead, which never materializes
-    (B, m, n).
+    ``stack_problems_ell`` instead, which never materializes (B, m, n).
     """
     lps = [lp.densified() for lp in lps]
     m = m if m is not None else max(lp.K.shape[0] for lp in lps)
@@ -181,44 +169,6 @@ def stack_problems(lps: Sequence[StandardLP], m: Optional[int] = None,
     return tuple(
         np.stack([getattr(p, f) for p in padded])
         for f in ("K", "b", "c", "lb", "ub"))
-
-
-def stack_problems_sparse(lps: Sequence[StandardLP],
-                          m: Optional[int] = None,
-                          n: Optional[int] = None,
-                          nnz: Optional[int] = None) -> tuple:
-    """Stack sparse StandardLPs WITHOUT densifying K.
-
-    Returns ``(data (B, nnz), idx (B, nnz, 2) int32, b, c, lb, ub)``.
-    Shape padding is purely logical (zero rows / pinned variables, as in
-    ``pad_problem``); nnz padding appends explicit zero entries at
-    (0, 0), which contribute nothing to any contraction or scaling.
-    """
-    assert lps and all(isinstance(lp.K, SparseCOO) for lp in lps), \
-        "stack_problems_sparse needs SparseCOO operators"
-    m = m if m is not None else max(lp.K.shape[0] for lp in lps)
-    n = n if n is not None else max(lp.K.shape[1] for lp in lps)
-    nnz = nnz if nnz is not None else max(lp.K.nnz for lp in lps)
-    B = len(lps)
-    dt = lps[0].K.dtype
-    data = np.zeros((B, nnz), dt)
-    idx = np.zeros((B, nnz, 2), np.int32)
-    vecs = {f: np.zeros((B, dim), dt)
-            for f, dim in (("b", m), ("c", n), ("lb", n), ("ub", n))}
-    for k, lp in enumerate(lps):
-        # coalesce duplicates: the pipeline's scatter preconditioners
-        # reduce over stored entries, so parity with the densified
-        # problem requires one entry per (row, col)
-        K = lp.K.coalesce()
-        assert K.shape[0] <= m and K.shape[1] <= n and K.nnz <= nnz, \
-            (K.shape, K.nnz, (m, n, nnz))
-        data[k, :K.nnz] = K.data
-        idx[k, :K.nnz, 0] = K.row
-        idx[k, :K.nnz, 1] = K.col
-        for f, arr in vecs.items():
-            v = getattr(lp, f)
-            arr[k, :v.shape[0]] = v
-    return (data, idx, vecs["b"], vecs["c"], vecs["lb"], vecs["ub"])
 
 
 def stack_problems_ell(lps: Sequence[StandardLP],
@@ -234,10 +184,9 @@ def stack_problems_ell(lps: Sequence[StandardLP],
     form of K^T — storing both keeps every pipeline reduction and both
     solve MVMs scatter-free.  ``wf``/``wa`` default to the exact max
     row/column occupancy over the list (buckets pass their power-of-two
-    widths explicitly).  ELL padding slots carry (data 0, col 0), the
-    same inertness contract as ``stack_problems_sparse``'s (0, 0)
-    entries; explicit zero nonzeros are dropped during conversion, so
-    they never widen a row.
+    widths explicitly).  ELL padding slots carry (data 0, col 0), which
+    contribute nothing to any product or scaling; explicit zero
+    nonzeros are dropped during conversion, so they never widen a row.
     """
     assert lps and all(isinstance(lp.K, SparseCOO) for lp in lps), \
         "stack_problems_ell needs SparseCOO operators"
@@ -275,8 +224,8 @@ def stack_problems_ell(lps: Sequence[StandardLP],
 # -------------------------------------------------------------- pipeline ---
 
 def _single_solve(K, b, c, lb, ub, T, Sigma, rho, key, static):
-    # The iteration core is core.engine's; ``static[-1]`` (opts.kernel)
-    # selects the jnp vs fused-Pallas update backend per executable.
+    # The iteration core is core.engine's; ``static.kernel`` selects the
+    # jnp vs fused-Pallas update backend per executable.
     return engine.solve_core(
         K, K.T, b, c, lb, ub, T, Sigma, rho, key, static)
 
@@ -305,177 +254,72 @@ def _check_norm_backend(opts: PDHGOptions) -> None:
                          f"expected one of {NORM_BACKENDS}")
 
 
-def _estimate_norm_mv(mv, dim: int, dtype, opts: PDHGOptions,
-                      rho_seed=None):
-    """RAW operator-norm estimate (no Lemma-2 margin) on a symmetric
-    matvec, per ``opts.norm_backend``.  With a ``rho_seed`` (the
-    norm-reuse serving path: a cached estimate for this sparsity
-    fingerprint) only a short power refinement runs and the result is
-    floored at the seed — same-pattern instances share spectra, so the
-    cached maximum is already the safe bet and the refinement just
-    catches genuinely hotter coefficient draws."""
-    if rho_seed is not None:
-        est = power_iteration_mv(mv, dim, dtype, iters=NORM_REFINE_ITERS)
-        return jnp.maximum(est, jnp.asarray(rho_seed, est.dtype))
-    if opts.norm_backend == "power":
-        return power_iteration_mv(mv, dim, dtype,
-                                  iters=opts.lanczos_iters)
-    return lanczos_svd_jit_mv(mv, dim, dtype, k_max=opts.lanczos_iters)
+def _norm_step(make_mv, dim: int, dtype, opts: PDHGOptions,
+               sigma_read: float, rho_seed=None):
+    """One instance's operator-norm step.  Returns ``(rho_used,
+    rho_raw)``: the norm the solve steps with, and the RAW estimate (no
+    Lemma-2 margin) the norm-reuse cache records.
 
-
-def _prep_one(K, b, c, lb, ub, rho_seed=None, *, opts: PDHGOptions):
-    from ..core.symblock import build_sym_block, mv
-
-    (Ks, bs, cs, lbs, ubs, T, Sigma, D1, D2) = prep_scale(
-        K, b, c, lb, ub, opts)
+    ``opts.norm_override`` is trusted as given (= solve_jit).  Otherwise
+    the estimate runs under ``engine.NORM_SCOPE`` on the symmetric
+    matvec ``make_mv()`` builds, per ``opts.norm_backend``, and is
+    widened by the Lemma-2 margin for ``sigma_read``.  With a
+    ``rho_seed`` (the norm-reuse serving path: a cached estimate for this
+    sparsity fingerprint) only a short power refinement runs and the
+    result is floored at the seed — same-pattern instances share
+    spectra, so the cached maximum is already the safe bet and the
+    refinement just catches genuinely hotter coefficient draws."""
     if opts.norm_override is not None:
-        rho = jnp.asarray(opts.norm_override, Ks.dtype)
-    else:
-        with jax.named_scope(engine.NORM_SCOPE):
-            Keff = jnp.sqrt(Sigma)[:, None] * Ks * jnp.sqrt(T)[None, :]
-            M = build_sym_block(Keff)
-            rho = _estimate_norm_mv(lambda v: mv(M, v), M.shape[0],
-                                    M.dtype, opts, rho_seed)
-    return (Ks, bs, cs, lbs, ubs, T, Sigma, rho, D1, D2)
+        rho = jnp.asarray(opts.norm_override, dtype)
+        return rho, rho
+    with jax.named_scope(engine.NORM_SCOPE):
+        mv = make_mv()
+        if rho_seed is not None:
+            est = power_iteration_mv(mv, dim, dtype, iters=NORM_REFINE_ITERS)
+            rho_raw = jnp.maximum(est, jnp.asarray(rho_seed, est.dtype))
+        elif opts.norm_backend == "power":
+            rho_raw = power_iteration_mv(mv, dim, dtype,
+                                         iters=opts.lanczos_iters)
+        else:
+            rho_raw = lanczos_svd_jit_mv(mv, dim, dtype,
+                                         k_max=opts.lanczos_iters)
+        return engine.lemma2_margin(rho_raw, sigma_read), rho_raw
 
 
-def make_bucket_pipeline(opts: PDHGOptions, sigma_read: float = 0.0,
-                         norm_seeded: bool = False):
+def make_bucket_pipeline(opts: PDHGOptions, sigma_read: float = 0.0):
     """vmapped prep + solve over a stacked (B, m, n) bucket.
 
-    ``keys`` carries one PRNG key per instance (iterate init + read-noise
-    streams).  Returns (xs, ys, iterations, merits, rhos) in the ORIGINAL
+    Arguments are the ``stack_problems`` layout, one PRNG key per
+    instance (iterate init + read-noise streams) and, for a norm-seeded
+    bucket, the per-instance ``rho_seeds``, which swap the full estimate
+    for the short refinement (``_norm_step``); unseeded, ``rho_seeds``
+    is None and vmaps to no argument.
+    Returns (xs, ys, iterations, merits, rhos) in the ORIGINAL
     (unscaled) coordinates — ``rhos`` is the per-instance RAW norm
-    estimate (pre-margin), which the norm-reuse cache records.  With
-    ``norm_seeded`` the pipeline takes an extra per-instance
-    ``rho_seeds`` argument and runs the short refinement instead of the
-    full estimate (see ``_estimate_norm_mv``).  Pure function of the
-    stacked arrays — safe to jit/AOT.
+    estimate (pre-margin), which the norm-reuse cache records.  Pure
+    function of the stacked arrays — safe to jit/AOT.
     """
+    from ..core.symblock import build_sym_block, mv
+
     static = opts_static(opts, sigma_read)
     _check_norm_backend(opts)
 
-    def _run(Ks, bs, cs, lbs, ubs, keys, rho_seeds=None):
-        prep = functools.partial(_prep_one, opts=opts)
-        if rho_seeds is None:
-            prepped = jax.vmap(prep)(Ks, bs, cs, lbs, ubs)
-        else:
-            prepped = jax.vmap(prep)(Ks, bs, cs, lbs, ubs, rho_seeds)
-        (Ks2, bs2, cs2, lbs2, ubs2, Ts, Sigs, rhos, D1s, D2s) = prepped
-        rhos_used = rhos
-        if opts.norm_override is None:
-            # only the (noisy) estimate gets the Lemma-2 margin;
-            # an explicit norm_override is trusted as-is (= solve_jit)
-            rhos_used = engine.lemma2_margin(rhos, sigma_read)
-        solver = functools.partial(_single_solve, static=static)
-        xs, ys, its, merits = jax.vmap(solver)(
-            Ks2, bs2, cs2, lbs2, ubs2, Ts, Sigs, rhos_used, keys)
-        return D2s * xs, D1s * ys, its, merits, rhos
+    def one(K, b, c, lb, ub, key, rho_seed=None):
+        (Ks, bs, cs, lbs, ubs, T, Sigma, D1, D2) = prep_scale(
+            K, b, c, lb, ub, opts)
 
-    if norm_seeded:
-        def pipeline(Ks, bs, cs, lbs, ubs, keys, rho_seeds):
-            return _run(Ks, bs, cs, lbs, ubs, keys, rho_seeds)
-    else:
-        def pipeline(Ks, bs, cs, lbs, ubs, keys):
-            return _run(Ks, bs, cs, lbs, ubs, keys)
+        def sym_mv():
+            Keff = jnp.sqrt(Sigma)[:, None] * Ks * jnp.sqrt(T)[None, :]
+            return functools.partial(mv, build_sym_block(Keff))
 
-    return pipeline
-
-
-# ------------------------------------------------------- sparse pipeline ---
-
-def _coo_matvec(data, row, col, v, out_dim: int):
-    """COO contraction ``out[row] += data * v[col]`` (scatter-add); the
-    sparse twin of one dense MVM, vmappable and while_loop-safe."""
-    return jnp.zeros(out_dim, v.dtype).at[row].add(data * v[col])
-
-
-@jax.named_scope(engine.PREP_SCOPE)
-def _prep_one_sparse(data, idx, b, c, lb, ub, opts: PDHGOptions):
-    """Sparse Ruiz + Pock–Chambolle diagonals on COO nonzeros.
-
-    Mirrors ``precondition.apply_ruiz`` / ``diagonal_precondition``
-    exactly (same eps, same sqrt-of-inf-norm update), but every row/col
-    reduction is a scatter over the stored entries — padded zero entries
-    at (0, 0) contribute nothing.  Returns the scaled nonzeros plus the
-    same tuple layout as the dense ``prep_scale``.
-    """
-    dt = data.dtype
-    m, n = b.shape[0], c.shape[0]
-    row, col = idx[:, 0], idx[:, 1]
-    eps = 1e-12
-    D1 = jnp.ones(m, dt)
-    D2 = jnp.ones(n, dt)
-    d = data
-    for _ in range(opts.ruiz_iters):
-        ad = jnp.abs(d)
-        r = jnp.sqrt(jnp.zeros(m, dt).at[row].max(ad))
-        cc = jnp.sqrt(jnp.zeros(n, dt).at[col].max(ad))
-        r = jnp.where(r < eps, 1.0, r)
-        cc = jnp.where(cc < eps, 1.0, cc)
-        D1 = D1 / r
-        D2 = D2 / cc
-        d = data * D1[row] * D2[col]
-    bs = D1 * b
-    cs = D2 * c
-    lbs = jnp.where(jnp.isfinite(lb), lb / D2, lb)
-    ubs = jnp.where(jnp.isfinite(ub), ub / D2, ub)
-    ad = jnp.abs(d)
-    T = 1.0 / jnp.maximum(jnp.zeros(n, dt).at[col].add(ad), eps)
-    Sigma = 1.0 / jnp.maximum(jnp.zeros(m, dt).at[row].add(ad), eps)
-    return d, bs, cs, lbs, ubs, T, Sigma, D1, D2
-
-
-def make_sparse_bucket_pipeline(opts: PDHGOptions, sigma_read: float = 0.0,
-                                norm_seeded: bool = False):
-    """vmapped sparse prep + solve over a stacked COO bucket.
-
-    Inputs are the ``stack_problems_sparse`` layout: (B, nnz) data,
-    (B, nnz, 2) indices, plus the dense vectors and per-instance keys.
-    The operator-norm estimate runs a matvec-only Lanczos (or power
-    iteration, per ``opts.norm_backend``; a short seeded refinement
-    with ``norm_seeded``) on the symmetric block of
-    Sigma^{1/2} K T^{1/2} (two COO contractions per iteration); the
-    solve itself mounts ``engine.sparse_operator`` on a BCOO built from
-    the scaled nonzeros.  No dense (m, n) array ever exists on host or
-    device.  Returns an extra trailing ``rhos`` (raw per-instance norm
-    estimates) like ``make_bucket_pipeline``.
-    """
-    static = opts_static(opts, sigma_read)
-    _check_norm_backend(opts)
-
-    def one(kd, ki, b, c, lb, ub, key, rho_seed=None):
-        m, n = b.shape[0], c.shape[0]
-        (d, bs, cs, lbs, ubs, T, Sigma, D1, D2) = _prep_one_sparse(
-            kd, ki, b, c, lb, ub, opts)
-        if opts.norm_override is not None:
-            rho_raw = jnp.asarray(opts.norm_override, kd.dtype)
-            rho = rho_raw
-        else:
-            with jax.named_scope(engine.NORM_SCOPE):
-                row, col = ki[:, 0], ki[:, 1]
-                deff = d * jnp.sqrt(Sigma)[row] * jnp.sqrt(T)[col]
-
-                def mv(v):     # symmetric block M' of Keff, matvec-only
-                    top = _coo_matvec(deff, row, col, v[m:], m)
-                    bot = _coo_matvec(deff, col, row, v[:m], n)
-                    return jnp.concatenate([top, bot])
-
-                rho_raw = _estimate_norm_mv(mv, m + n, kd.dtype, opts,
-                                            rho_seed)
-                rho = engine.lemma2_margin(rho_raw, sigma_read)
-        K_sp = jsparse.BCOO((d, ki), shape=(m, n))
-        x, y, it, merit = engine.solve_core(
-            K_sp, None, bs, cs, lbs, ubs, T, Sigma, rho, key, static)
+        rho, rho_raw = _norm_step(sym_mv, Ks.shape[0] + Ks.shape[1],
+                                  Ks.dtype, opts, sigma_read, rho_seed)
+        x, y, it, merit = _single_solve(Ks, bs, cs, lbs, ubs, T, Sigma,
+                                        rho, key, static)
         return D2 * x, D1 * y, it, merit, rho_raw
 
-    if norm_seeded:
-        def pipeline(Kdata, Kidx, bs, cs, lbs, ubs, keys, rho_seeds):
-            return jax.vmap(one)(Kdata, Kidx, bs, cs, lbs, ubs, keys,
-                                 rho_seeds)
-    else:
-        def pipeline(Kdata, Kidx, bs, cs, lbs, ubs, keys):
-            return jax.vmap(one)(Kdata, Kidx, bs, cs, lbs, ubs, keys)
+    def pipeline(Ks, bs, cs, lbs, ubs, keys, rho_seeds=None):
+        return jax.vmap(one)(Ks, bs, cs, lbs, ubs, keys, rho_seeds)
 
     return pipeline
 
@@ -494,12 +338,12 @@ def _row_reduce(a, reduce_fn):
 def _prep_one_ell(df, cf, da, ca, b, c, lb, ub, opts: PDHGOptions):
     """Sparse Ruiz + Pock–Chambolle diagonals on ELL nonzeros.
 
-    Mirrors ``_prep_one_sparse`` (same eps, same guard, same update
-    order — the scaling diagonals come out bit-identical), but every
-    row/column reduction is a vectorized axis-1 max/sum on the layout
-    that already has it contiguous: row stats on the forward ELL,
-    column stats on the adjoint ELL.  No scatter anywhere.  Padding
-    slots (data 0, col 0) scale to 0 and never move a max or a sum.
+    Mirrors ``precondition.apply_ruiz`` / ``diagonal_precondition``
+    (same eps, same guard, same update order), but every row/column
+    reduction is a vectorized axis-1 max/sum on the layout that already
+    has it contiguous: row stats on the forward ELL, column stats on the
+    adjoint ELL.  No scatter anywhere.  Padding slots (data 0, col 0)
+    scale to 0 and never move a max or a sum.
     """
     dt = df.dtype
     eps = 1e-12
@@ -525,14 +369,15 @@ def _prep_one_ell(df, cf, da, ca, b, c, lb, ub, opts: PDHGOptions):
     return sf, sa, bs, cs, lbs, ubs, T, Sigma, D1, D2
 
 
-def make_ell_bucket_pipeline(opts: PDHGOptions, sigma_read: float = 0.0,
-                             norm_seeded: bool = False):
+def make_ell_bucket_pipeline(opts: PDHGOptions, sigma_read: float = 0.0):
     """vmapped ELL prep + solve over a stacked ELL bucket.
 
-    Inputs are the ``stack_problems_ell`` layout plus per-instance keys;
-    prep (Ruiz, Pock-Chambolle diagonals) runs on the ELL values.  The
-    operator the solve multiplies by follows ``ell_goes_dense``, from
-    the bucket's static shapes:
+    Arguments are the ``stack_problems_ell`` layout followed by what
+    ``make_bucket_pipeline`` takes after its stack (keys, and
+    ``rho_seeds`` for a norm-seeded bucket); prep (Ruiz, Pock-Chambolle
+    diagonals) runs on the ELL values.  The operator the solve
+    multiplies by follows ``ell_goes_dense``, from the bucket's static
+    shapes:
 
       * dense: the scaled forward ELL is scattered into an (m, n) K on
         the device, once per solve, and every product after it (two per
@@ -544,10 +389,10 @@ def make_ell_bucket_pipeline(opts: PDHGOptions, sigma_read: float = 0.0,
 
     Either way no dense array exists on the host, and no iteration-path
     op is a scatter.  ``opts.megakernel`` is refused on both.  Returns
-    an extra trailing ``rhos`` (raw per-instance norm estimates) like
-    ``make_bucket_pipeline``; ``norm_seeded`` swaps the full estimate for
-    the short cached-seed refinement.
+    what ``make_bucket_pipeline`` returns.
     """
+    from ..core.symblock import mv
+
     if opts.megakernel:
         raise ValueError(
             "megakernel=True fuses the noiseless dense operator only; "
@@ -556,36 +401,28 @@ def make_ell_bucket_pipeline(opts: PDHGOptions, sigma_read: float = 0.0,
     _check_norm_backend(opts)
 
     def one(df, cf, da, ca, b, c, lb, ub, key, rho_seed=None, *, dense):
-        from ..core.symblock import mv
-
         m, n = b.shape[0], c.shape[0]
         (sf, sa, bs, cs, lbs, ubs, T, Sigma, D1, D2) = _prep_one_ell(
             df, cf, da, ca, b, c, lb, ub, opts)
         if dense:
             with jax.named_scope(engine.PREP_SCOPE):
                 Ks = ell_to_dense(sf, cf, n)
-        if opts.norm_override is not None:
-            rho_raw = jnp.asarray(opts.norm_override, df.dtype)
-            rho = rho_raw
-        else:
-            with jax.named_scope(engine.NORM_SCOPE):
-                rtS, rtT = jnp.sqrt(Sigma), jnp.sqrt(T)
-                if dense:
-                    Keff = rtS[:, None] * Ks * rtT[None, :]
-                    fwd = functools.partial(mv, Keff)
-                    adj = functools.partial(mv, Keff.T)
-                else:
-                    deff_f = sf * rtS[:, None] * rtT[cf]
-                    deff_a = sa * rtT[:, None] * rtS[ca]
-                    fwd = functools.partial(ell_matvec, deff_f, cf)
-                    adj = functools.partial(ell_matvec, deff_a, ca)
 
-                def sym_mv(v):  # symmetric block M' of Keff, matvec-only
-                    return jnp.concatenate([fwd(v[m:]), adj(v[:m])])
+        def sym_mv():   # symmetric block M' of Keff, matvec-only
+            rtS, rtT = jnp.sqrt(Sigma), jnp.sqrt(T)
+            if dense:
+                Keff = rtS[:, None] * Ks * rtT[None, :]
+                fwd = functools.partial(mv, Keff)
+                adj = functools.partial(mv, Keff.T)
+            else:
+                deff_f = sf * rtS[:, None] * rtT[cf]
+                deff_a = sa * rtT[:, None] * rtS[ca]
+                fwd = functools.partial(ell_matvec, deff_f, cf)
+                adj = functools.partial(ell_matvec, deff_a, ca)
+            return lambda v: jnp.concatenate([fwd(v[m:]), adj(v[:m])])
 
-                rho_raw = _estimate_norm_mv(sym_mv, m + n, df.dtype, opts,
-                                            rho_seed)
-                rho = engine.lemma2_margin(rho_raw, sigma_read)
+        rho, rho_raw = _norm_step(sym_mv, m + n, df.dtype, opts,
+                                  sigma_read, rho_seed)
         if dense:
             x, y, it, merit = _single_solve(Ks, bs, cs, lbs, ubs, T, Sigma,
                                             rho, key, static)
@@ -596,18 +433,11 @@ def make_ell_bucket_pipeline(opts: PDHGOptions, sigma_read: float = 0.0,
                 operator=op)
         return D2 * x, D1 * y, it, merit, rho_raw
 
-    def _run(df, cf, da, ca, *rest):
+    def pipeline(df, cf, da, ca, bs, cs, lbs, ubs, keys, rho_seeds=None):
         (B, m, wf), (n, wa) = df.shape, da.shape[1:]
         dense = ell_goes_dense(B, m, n, wf, wa, df.dtype.itemsize)
         return jax.vmap(functools.partial(one, dense=dense))(
-            df, cf, da, ca, *rest)
-
-    if norm_seeded:
-        def pipeline(df, cf, da, ca, bs, cs, lbs, ubs, keys, rho_seeds):
-            return _run(df, cf, da, ca, bs, cs, lbs, ubs, keys, rho_seeds)
-    else:
-        def pipeline(df, cf, da, ca, bs, cs, lbs, ubs, keys):
-            return _run(df, cf, da, ca, bs, cs, lbs, ubs, keys)
+            df, cf, da, ca, bs, cs, lbs, ubs, keys, rho_seeds)
 
     return pipeline
 
@@ -627,7 +457,7 @@ class BatchItemResult:
     converged: bool
     bucket: Tuple[int, int]
     mvm_calls: int = 0          # device MVMs (engine.mvm_accounting)
-    sparse: bool = False        # served by a sparse (ELL/COO) pipeline
+    sparse: bool = False        # served by the ELL pipeline
 
     @property
     def status(self) -> str:
@@ -668,12 +498,7 @@ def bucket_tag(key) -> str:
     """Stable string id of a bucket key ``((m_pad, n_pad), sparse sig)``
     (span metadata, filenames, routing tables)."""
     (mb, nb), sig = key
-    if sig is None:
-        kind = "dense"
-    elif isinstance(sig, tuple):            # ("ell", wf, wa)
-        kind = f"ell{sig[1]}x{sig[2]}"
-    else:                                   # bare int nnz bucket
-        kind = f"nnz{sig}"
+    kind = "dense" if sig is None else f"ell{sig[1]}x{sig[2]}"
     return f"{mb}x{nb}-{kind}"
 
 
@@ -703,9 +528,9 @@ class BatchSolver:
     device physics in the same bucketed harness.
 
     Sparse instances (``lp.is_sparse``) are bucketed separately (shape
-    bucket + power-of-two nnz bucket) and served by the COO pipeline
-    when the solver ``supports_sparse`` (the crossbar subclass programs
-    every physical cell, so it densifies instead).  ``async_dispatch``
+    bucket + the pair of power-of-two ELL width buckets) and served by
+    the ELL pipeline when the solver ``supports_sparse`` (the crossbar
+    subclass programs every physical cell, so it densifies instead).  ``async_dispatch``
     submits all buckets before collecting any result (set False for
     blocking per-bucket dispatch, e.g. to bound device memory on tiny
     hosts); ``donate_min_bytes`` is the stacked-operator size beyond
@@ -721,8 +546,8 @@ class BatchSolver:
       * ``dense_operator_buckets``: ELL buckets whose program multiplies
         by a dense K scattered on the device (``ell_goes_dense``) instead
         of gathering; the ``repro.stream.dispatch`` span of each bucket
-        names its operator (``dense``, ``ell``, ``bcoo``, or ``crossbar``
-        on the crossbar subclass);
+        names its operator (``dense``, ``ell``, or ``crossbar`` on the
+        crossbar subclass);
       * ``compiles``: XLA compilations the call triggered
         (``runtime.sanitize``; a warm pass over a bucket mix served
         before must report 0);
@@ -749,7 +574,7 @@ class BatchSolver:
     instances ALL have cached estimates is served by a seeded executable
     that replaces the full ``lanczos_iters``-step estimate with a
     ``NORM_REFINE_ITERS``-step power refinement floored at the cached
-    value (``_estimate_norm_mv``).  The seeded twin executable is
+    value (``_norm_step``).  The seeded twin executable is
     compiled EAGERLY on the cold pass, so warm streams stay at zero
     compiles; the cache changes step sizes (a refined estimate instead
     of the full one), so it is opt-in — the default ``False`` path is
@@ -797,17 +622,9 @@ class BatchSolver:
     def _bucket(self, m: int, n: int) -> Tuple[int, int]:
         return bucket_dims(m, n, min_size=self.min_bucket, tile=self.tile)
 
-    def _make_pipeline(self, norm_seeded: bool = False):
-        return make_bucket_pipeline(self.opts, self.sigma_read,
-                                    norm_seeded=norm_seeded)
-
-    def _make_sparse_pipeline(self, norm_seeded: bool = False):
-        return make_sparse_bucket_pipeline(self.opts, self.sigma_read,
-                                           norm_seeded=norm_seeded)
-
-    def _make_ell_pipeline(self, norm_seeded: bool = False):
-        return make_ell_bucket_pipeline(self.opts, self.sigma_read,
-                                        norm_seeded=norm_seeded)
+    def _make_pipeline(self):
+        """The program of a dense bucket."""
+        return make_bucket_pipeline(self.opts, self.sigma_read)
 
     def _device_signature(self):
         """Hashable device component of the executable cache key."""
@@ -833,7 +650,7 @@ class BatchSolver:
         return (shape_sig, B, jnp.dtype(dtype).name, bool(donate),
                 opts_static(self.opts, self.sigma_read),
                 # prep-stage options that shape the pipeline but live
-                # outside the solve-core static tuple
+                # outside the solve core's static options
                 (self.opts.ruiz_iters, self.opts.lanczos_iters,
                  self.opts.norm_override, self.opts.norm_backend),
                 self.tile,
@@ -869,57 +686,24 @@ class BatchSolver:
         """
         return jax.random.PRNGKey(0)  # jaxlint: disable=R2
 
-    def _executable(self, mb: int, nb: int, B: int, dtype, *, stats: dict,
-                    bucket: str, donate: bool = False, seeded: bool = False):
-        sig = ("dense", mb, nb) + (("normseed",) if seeded else ())
-        key = self._cache_key(sig, B, dtype, donate)
+    def _executable(self, sig: tuple, arg_specs, make_pipeline, dtype, *,
+                    stats: dict, bucket: str, donate: bool = False,
+                    seeded: bool = False):
+        """The compiled program of a bucket with signature ``sig``,
+        lowered from ``make_pipeline()`` on abstract arguments:
+        ``arg_specs`` holds the ``(shape, dtype)`` of each stacked array
+        (batch axis first), and the per-instance PRNG keys and, for a
+        ``seeded`` bucket, its ``rho_seeds`` follow them."""
+        B = arg_specs[0][0][0]
+        key = self._cache_key(sig + (("normseed",) if seeded else ()), B,
+                              dtype, donate)
         k0 = self._key_template()
-        args = (self._sds((B, mb, nb), dtype), self._sds((B, mb), dtype),
-                self._sds((B, nb), dtype), self._sds((B, nb), dtype),
-                self._sds((B, nb), dtype), self._sds((B, *k0.shape),
-                                                     k0.dtype))
+        args = [self._sds(shape, dt) for shape, dt in arg_specs]
+        args.append(self._sds((B, *k0.shape), k0.dtype))
         if seeded:
-            args = args + (self._sds((B,), dtype),)
-        return self._compile(key, self._make_pipeline(norm_seeded=seeded)
-                             if seeded else self._make_pipeline(),
-                             args, donate, stats, bucket)
-
-    def _executable_sparse(self, mb: int, nb: int, nnz: int, B: int,
-                           dtype, *, stats: dict, bucket: str,
-                           donate: bool = False, seeded: bool = False):
-        sig = ("sparse", mb, nb, nnz) + (("normseed",) if seeded else ())
-        key = self._cache_key(sig, B, dtype, donate)
-        k0 = self._key_template()
-        args = (self._sds((B, nnz), dtype),
-                self._sds((B, nnz, 2), jnp.int32),
-                self._sds((B, mb), dtype), self._sds((B, nb), dtype),
-                self._sds((B, nb), dtype), self._sds((B, nb), dtype),
-                self._sds((B, *k0.shape), k0.dtype))
-        if seeded:
-            args = args + (self._sds((B,), dtype),)
-        return self._compile(key,
-                             self._make_sparse_pipeline(norm_seeded=seeded)
-                             if seeded else self._make_sparse_pipeline(),
-                             args, donate, stats, bucket)
-
-    def _executable_ell(self, mb: int, nb: int, wf: int, wa: int, B: int,
-                        dtype, *, stats: dict, bucket: str,
-                        donate: bool = False, seeded: bool = False):
-        sig = ("ell", mb, nb, wf, wa) + (("normseed",) if seeded else ())
-        key = self._cache_key(sig, B, dtype, donate)
-        k0 = self._key_template()
-        args = (self._sds((B, mb, wf), dtype),
-                self._sds((B, mb, wf), jnp.int32),
-                self._sds((B, nb, wa), dtype),
-                self._sds((B, nb, wa), jnp.int32),
-                self._sds((B, mb), dtype), self._sds((B, nb), dtype),
-                self._sds((B, nb), dtype), self._sds((B, nb), dtype),
-                self._sds((B, *k0.shape), k0.dtype))
-        if seeded:
-            args = args + (self._sds((B,), dtype),)
-        return self._compile(key, self._make_ell_pipeline(norm_seeded=seeded)
-                             if seeded else self._make_ell_pipeline(),
-                             args, donate, stats, bucket)
+            args.append(self._sds((B,), dtype))
+        return self._compile(key, make_pipeline(), tuple(args), donate,
+                             stats, bucket)
 
     def cache_info(self) -> dict:
         return {"hits": self.cache_hits, "misses": self.cache_misses,
@@ -1015,8 +799,7 @@ class BatchSolver:
         """Stack one bucket and submit it to its compiled executable.
 
         ``sig`` is the group's sparse signature: None for dense serving,
-        a bare int nnz bucket for the COO/BCOO backend, or
-        ``("ell", wf, wa)`` width buckets for the ELL backend.  Returns
+        or the ``("ell", wf, wa)`` width buckets of ELL serving.  Returns
         the (asynchronously dispatched) device outputs — the call never
         blocks on the solve itself.  Runs the ``stack``, ``upload``,
         ``compile`` (cache misses only) and ``dispatch`` phases.
@@ -1027,40 +810,35 @@ class BatchSolver:
         n_fill = B - len(group)
         with _phase(stats, "stack", bucket=tag, lanes=B,
                     instances=len(group)) as span:
-            if isinstance(sig, tuple):                   # ("ell", wf, wa)
+            if sig is not None:                          # ("ell", wf, wa)
                 _, wf, wa = sig
                 stacked = stack_problems_ell(group + [group[0]] * n_fill,
                                              m=mb, n=nb, wf=wf, wa=wa)
-                int_arrays = (1, 3)
-                exe_fn = functools.partial(self._executable_ell, mb, nb, wf,
-                                           wa, B, dtype)
+                exe_sig = ("ell", mb, nb, wf, wa)
+                make_pipeline = functools.partial(
+                    make_ell_bucket_pipeline, self.opts, self.sigma_read)
                 # the ELL program's own choice (make_ell_bucket_pipeline)
                 operator = ("dense" if ell_goes_dense(
                     B, mb, nb, wf, wa, jnp.dtype(dtype).itemsize) else "ell")
                 stats["dense_operator_buckets"] += int(operator == "dense")
-            elif sig is not None:                        # bare int nnz
-                stacked = stack_problems_sparse(group + [group[0]] * n_fill,
-                                                m=mb, n=nb, nnz=sig)
-                int_arrays = (1,)
-                exe_fn = functools.partial(self._executable_sparse, mb, nb,
-                                           sig, B, dtype)
-                operator = "bcoo"
             else:
                 dense = [lp.densified() for lp in group]
                 stacked = stack_problems(dense + [dense[0]] * n_fill,
                                          m=mb, n=nb)
-                int_arrays = ()
-                exe_fn = functools.partial(self._executable, mb, nb, B,
-                                           dtype)
+                exe_sig = ("dense", mb, nb)
+                make_pipeline = self._make_pipeline
                 operator = self.dense_operator
             nbytes = sum(a.nbytes for a in stacked)
             stats["sparse_stack_bytes" if sig is not None
                   else "dense_stack_bytes"] += nbytes
             span.set_metadata(bytes=nbytes)
+        # the ELL column indices go up as int32, every value as dtype
+        arg_specs = [(a.shape, jnp.int32 if a.dtype.kind in "iu" else dtype)
+                     for a in stacked]
         with _phase(stats, "upload", bucket=tag):
             keys = self._instance_keys(idxs, n_total, B)
-            arrays = [jnp.asarray(a, jnp.int32 if i in int_arrays else dtype)
-                      for i, a in enumerate(stacked)]
+            arrays = [jnp.asarray(a, dt)
+                      for a, (_, dt) in zip(stacked, arg_specs)]
             # norm-reuse serving: a bucket is seeded only when EVERY
             # member's fingerprint already has a cached estimate (filler
             # slots reuse the first member's seed — their results are
@@ -1085,8 +863,9 @@ class BatchSolver:
                     rho_seeds = jax.device_put(rho_seeds, sh)
         seeded = rho_seeds is not None
         donate = self._donate(arrays[0].nbytes)
-        exe_fn = functools.partial(exe_fn, stats=stats, bucket=tag,
-                                   donate=donate)
+        exe_fn = functools.partial(self._executable, exe_sig, arg_specs,
+                                   make_pipeline, dtype, stats=stats,
+                                   bucket=tag, donate=donate)
         exe = exe_fn(seeded=seeded)
         if self.norm_reuse and self.opts.norm_override is None \
                 and not seeded:
@@ -1110,15 +889,11 @@ class BatchSolver:
             return exe(*call_args)
 
     def _sparse_signature(self, lp: StandardLP):
-        """Sparse component of an instance's bucket key: the nnz bucket
-        (bare int — the COO/BCOO stacking axis) or the pair of ELL width
-        buckets.  Either way, one occupancy outlier never inflates (and
+        """Sparse component of an instance's bucket key: the pair of ELL
+        width buckets, so one occupancy outlier never inflates (and
         never recompiles) the whole shape bucket's stack."""
-        if self.opts.sparse_kernel == "ell":
-            wf, wa = coo_row_widths(lp.K.row, lp.K.col, lp.K.data,
-                                    lp.K.shape)
-            return ("ell", ell_width_bucket(wf), ell_width_bucket(wa))
-        return nnz_bucket(lp.K.nnz)
+        wf, wa = coo_row_widths(lp.K.row, lp.K.col, lp.K.data, lp.K.shape)
+        return ("ell", ell_width_bucket(wf), ell_width_bucket(wa))
 
     def _group_buckets(self, lps: Sequence[StandardLP]) -> dict:
         """Group stream positions by ((m_bucket, n_bucket), sparse sig).

@@ -50,7 +50,6 @@ import numpy as np
 from ..distributed.fault import SolverCheckpoint, load_checkpoint, \
     save_checkpoint
 from .batch import BatchSolver, bucket_tag
-from .batch import nnz_bucket  # noqa: F401  (re-export)
 
 # env vars describing the cluster (REPRO_* preferred; the JAX_* spellings
 # some launchers export are honored as fallbacks)
@@ -63,7 +62,9 @@ _FALLBACK_ENV = {
     ENV_PROCESS_ID: "JAX_PROCESS_ID",
 }
 
-BucketKey = Tuple[Tuple[int, int], Optional[int]]
+# ((m_pad, n_pad), None) for a dense bucket, ((m_pad, n_pad),
+# ("ell", wf, wa)) for an ELL one
+BucketKey = Tuple[Tuple[int, int], Optional[Tuple[str, int, int]]]
 
 
 # ---------------------------------------------------------------- init ---
@@ -176,20 +177,16 @@ def _reset_for_tests() -> None:
 def bucket_cost(key: BucketKey, queue_depth: int) -> int:
     """Deterministic serving cost: padded FLOPs per MVM x queue depth.
 
-    Dense buckets move 2*mb*nb FLOPs per MVM; COO sparse buckets
-    2*nnz_bucket (scatter contractions touch stored entries only); ELL
-    buckets mb*wf + nb*wa (the two gather contractions of one fwd+adj
-    MVM pair, padding slots included).  ``queue_depth`` is the padded
-    batch the executable will actually run — filler slots cost real
-    FLOPs, so they count.
+    Dense buckets move 2*mb*nb FLOPs per MVM; ELL buckets mb*wf + nb*wa
+    (the two gather contractions of one fwd+adj MVM pair, padding slots
+    included).  ``queue_depth`` is the padded batch the executable will
+    actually run — filler slots cost real FLOPs, so they count.
     """
     (mb, nb), sig = key
     if sig is None:
         flops_per_mvm = 2 * mb * nb
-    elif isinstance(sig, tuple):            # ("ell", wf, wa)
+    else:                                   # ("ell", wf, wa)
         flops_per_mvm = mb * sig[1] + nb * sig[2]
-    else:                                   # bare int nnz bucket
-        flops_per_mvm = 2 * sig
     return int(flops_per_mvm) * int(queue_depth)
 
 

@@ -9,7 +9,7 @@ Invariants:
     multiple — never below the logical dims, never skipping a tile.
   * default ``bucket_dims`` is the enclosing power of two (floored at
     ``min_size``), idempotent on its own outputs.
-  * ``nnz_bucket`` is a monotone power-of-two step function.
+  * ``ell_width_bucket`` is a monotone power-of-two step function.
   * sparse and dense buckets can never share an executable-cache key,
     whatever their dims.
 """
@@ -18,17 +18,16 @@ import numpy as np
 from _hypothesis_compat import given, settings, st
 
 from repro.core import PDHGOptions
+from repro.kernels.sparse_mvm import MIN_ELL_WIDTH, ell_width_bucket
 from repro.runtime.batch import (
     MIN_BUCKET,
-    MIN_NNZ_BUCKET,
     BatchSolver,
     bucket_dims,
-    nnz_bucket,
 )
 
 DIMS = st.integers(min_value=1, max_value=4096)
 TILES = st.integers(min_value=1, max_value=512)
-NNZ = st.integers(min_value=1, max_value=1 << 20)
+WIDTHS = st.integers(min_value=0, max_value=1 << 16)
 
 
 def _is_pow2(v: int) -> bool:
@@ -62,29 +61,30 @@ def test_default_mode_is_minimal_enclosing_power_of_two(m, n):
 
 
 @settings(max_examples=200)
-@given(a=NNZ, b=NNZ)
-def test_nnz_bucket_monotone_power_of_two(a, b):
-    ba, bb = nnz_bucket(a), nnz_bucket(b)
+@given(a=WIDTHS, b=WIDTHS)
+def test_ell_width_bucket_monotone_power_of_two(a, b):
+    ba, bb = ell_width_bucket(a), ell_width_bucket(b)
     assert _is_pow2(ba) and _is_pow2(bb)
-    assert ba >= max(a, MIN_NNZ_BUCKET) and ba // 2 < max(a, MIN_NNZ_BUCKET)
+    assert ba >= max(a, MIN_ELL_WIDTH) and ba // 2 < max(a, MIN_ELL_WIDTH)
     if a <= b:                       # monotone step function
         assert ba <= bb
-    assert nnz_bucket(ba) == ba      # idempotent on bucket values
+    assert ell_width_bucket(ba) == ba    # idempotent on bucket values
 
 
 @settings(max_examples=100)
-@given(m=DIMS, n=DIMS, nnz=NNZ, B=st.integers(min_value=1, max_value=64))
-def test_sparse_and_dense_buckets_never_share_cache_keys(m, n, nnz, B):
-    """Whatever the dims, a sparse signature can never collide with a
+@given(m=DIMS, n=DIMS, wf=WIDTHS, wa=WIDTHS,
+       B=st.integers(min_value=1, max_value=64))
+def test_sparse_and_dense_buckets_never_share_cache_keys(m, n, wf, wa, B):
+    """Whatever the dims, an ELL signature can never collide with a
     dense one (the executables take different argument layouts)."""
     solver = BatchSolver(PDHGOptions())
     mb, nb = bucket_dims(m, n)
     kd = solver._cache_key(("dense", mb, nb), B, np.float64, False)
-    ks = solver._cache_key(("sparse", mb, nb, nnz_bucket(nnz)), B,
-                           np.float64, False)
+    ks = solver._cache_key(("ell", mb, nb, ell_width_bucket(wf),
+                            ell_width_bucket(wa)), B, np.float64, False)
     assert kd != ks
-    # and the tags stay distinct even if nnz numerically equals a dim
-    ks2 = solver._cache_key(("sparse", mb, nb, nb), B, np.float64, False)
+    # and the tags stay distinct even if the widths equal the dims
+    ks2 = solver._cache_key(("ell", mb, nb, mb, nb), B, np.float64, False)
     assert kd != ks2
 
 

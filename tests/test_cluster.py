@@ -47,14 +47,14 @@ def _stream():
 # ------------------------------------------------------------- routing ---
 
 def test_bucket_cost_model():
-    """Cost = padded FLOPs per MVM x queue depth; sparse buckets pay for
-    stored entries, not the logical dense rectangle."""
+    """Cost = padded FLOPs per MVM x queue depth; ELL buckets pay for
+    their gather slots, not the logical dense rectangle."""
     assert bucket_cost(((16, 32), None), 4) == 2 * 16 * 32 * 4
-    assert bucket_cost(((128, 256), 512), 4) == 2 * 512 * 4
-    # a sparse bucket is cheaper than its dense twin whenever nnz is
-    # below the dense cell count
-    assert bucket_cost(((128, 256), 512), 4) < \
-        bucket_cost(((128, 256), None), 4)
+    ell = ((128, 256), ("ell", 8, 4))
+    assert bucket_cost(ell, 4) == (128 * 8 + 256 * 4) * 4
+    # an ELL bucket is cheaper than its dense twin whenever its slots
+    # are fewer than the dense cell count
+    assert bucket_cost(ell, 4) < bucket_cost(((128, 256), None), 4)
 
 
 def test_route_buckets_lpt_and_determinism():
@@ -76,9 +76,10 @@ def test_route_buckets_lpt_and_determinism():
 
 
 def test_bucket_tag_distinguishes_sparse_and_dense():
-    assert bucket_tag(((16, 32), None)) != bucket_tag(((16, 32), 256))
+    ell = ((16, 32), ("ell", 8, 4))
+    assert bucket_tag(((16, 32), None)) != bucket_tag(ell)
     assert bucket_tag(((16, 32), None)) == "16x32-dense"
-    assert bucket_tag(((16, 32), 256)) == "16x32-nnz256"
+    assert bucket_tag(ell) == "16x32-ell8x4"
 
 
 # ------------------------------------------------------- cluster init ---

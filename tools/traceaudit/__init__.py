@@ -2,7 +2,7 @@
 
 ``tools/jaxlint`` reads source; this tool reads what the source
 actually becomes.  It traces every supported solver path combo
-(operator backend x update kernel x step_rule x sparse_kernel x
+(operator backend x update kernel x step_rule x
 megakernel on/off, plus the mixed-precision refinement shells) via
 ``jax.make_jaxpr`` on tiny shapes, then runs
 four analyzers over each jaxpr:
@@ -11,8 +11,7 @@ budget       The primitive-budget checker walks the jaxpr into
              ``while``/``scan``/``cond``/``pjit``/``shard_map``/
              ``pallas_call`` bodies (trip-count scaling shared with
              ``launch/hlo.py``'s HLO walker), counts MVM-bearing
-             primitives (rank>=2 ``dot_general``, ``bcoo_dot_general``,
-             the ELL row-gather), and asserts the count per check
+             primitives (rank>=2 ``dot_general``, the ELL row-gather), and asserts the count per check
              window equals ``core.engine.mvm_window_budget`` — and that
              NOTHING MVM-shaped runs outside the loop.  This is the
              energy ledger's formula re-derived from the actual trace:
@@ -81,7 +80,7 @@ def _ensure_import_paths() -> None:
 class PathSpec:
     """One supported solver path combo; ``name`` is the stable id used
     in TRACE_BASELINE.json and in findings."""
-    backend: str          # dense | ell | bcoo | crossbar | sharded
+    backend: str          # dense | ell | crossbar | sharded
     kernel: str           # jnp | pallas  (update kernel)
     step_rule: str        # fixed | adaptive | strongly_convex
     megakernel: bool
@@ -109,7 +108,7 @@ def supported_paths() -> List[PathSpec]:
     is orthogonal to kernel/step_rule in the trace — it only toggles
     the averaged-iterate check block)."""
     paths: List[PathSpec] = []
-    for backend in ("dense", "ell", "bcoo", "crossbar", "sharded"):
+    for backend in ("dense", "ell", "crossbar", "sharded"):
         kernels = ("jnp",) if backend == "sharded" else ("jnp", "pallas")
         megas = (False, True) if backend == "dense" else (False,)
         for kernel in kernels:
@@ -176,17 +175,16 @@ def _make_operator(spec: PathSpec, prob, engine):
         gnf = jnp.zeros((R, C), dt).at[:m, m:].set(gn)
         return engine.crossbar_operator(gpf, gnf, jnp.asarray(1.0, dt),
                                         m, n)
-    return None                  # dense / bcoo: solve_core self-mounts
+    return None                  # dense: solve_core self-mounts
 
 
-def _static_tuple(spec: PathSpec):
+def _static(spec: PathSpec):
     from repro.core.pdhg import PDHGOptions, opts_static
     opts = PDHGOptions(
         max_iters=MAX_ITERS, check_every=CHECK_EVERY,
         kernel=spec.kernel, step_rule=spec.step_rule,
         megakernel=spec.megakernel, restart=spec.restart,
-        gamma=spec.gamma, refine_rounds=spec.refine,
-        sparse_kernel="bcoo" if spec.backend == "bcoo" else "ell")
+        gamma=spec.gamma, refine_rounds=spec.refine)
     return opts_static(opts)
 
 
@@ -202,7 +200,7 @@ def _trace_refined(spec: PathSpec, prob, engine, operator_override=None):
 
     from repro.crossbar.refine import refined_core
 
-    static = _static_tuple(spec)
+    static = _static(spec)
     key = jax.random.PRNGKey(0)
     operator = (operator_override if operator_override is not None
                 else _make_operator(spec, prob, engine))
@@ -276,14 +274,11 @@ def trace_path(spec: PathSpec, operator_override=None):
         elif spec.refine > 0:
             jaxpr = _trace_refined(spec, prob, engine, operator_override)
         else:
-            static = _static_tuple(spec)
+            static = _static(spec)
             key = jax.random.PRNGKey(0)
             operator = (operator_override if operator_override is not None
                         else _make_operator(spec, prob, engine))
-            if spec.backend == "bcoo" and operator_override is None:
-                from jax.experimental import sparse as jsparse
-                K_fwd, K_adj = jsparse.BCOO.fromdense(prob["K"]), None
-            elif operator is None:
+            if operator is None:
                 K_fwd, K_adj = prob["K"], prob["K"].T
             else:
                 K_fwd = K_adj = None
@@ -355,7 +350,6 @@ def classify_mvm(eqn) -> Optional[str]:
 
     dot     ``dot_general`` with an operand of rank >= 2 (rank-1 pairs
             are the residual/merit vdots — NOT operator applications)
-    bcoo    any ``bcoo_dot_general`` variant (BCOO SpMV)
     gather  the ELL row gather: rank-1 source indexed to a rank-2
             (rows x width) block — ``ell_matvec``'s take expression
     """
@@ -363,8 +357,6 @@ def classify_mvm(eqn) -> Optional[str]:
     if name == "dot_general":
         if max(v.aval.ndim for v in eqn.invars) >= 2:
             return "dot"
-    if name.startswith("bcoo_dot_general"):
-        return "bcoo"
     if name == "gather":
         if (eqn.invars[0].aval.ndim == 1
                 and eqn.outvars[0].aval.ndim == 2):
